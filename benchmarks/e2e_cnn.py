@@ -355,6 +355,9 @@ def main() -> None:
                          "wall-clock timing; what the committed baseline "
                          "and benchmarks.check_regression gate on")
     args = ap.parse_args()
+    from repro.launch.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     run(
         model=args.model,
         input_hw=(args.hw, args.hw) if args.hw else None,

@@ -224,13 +224,14 @@ def window_violations(
             return [], False
         points = list(itertools.product(*[range(int(g)) for g in grid]))
         evaluate = lambda pt: eval_index_map(op.index_map_jaxpr, pt)  # noqa: E731
+    element = tuple(op.element_dims) or (False,) * len(op.block_shape)
     violations: List[WindowViolation] = []
     for pt in points:
         idx = evaluate(pt)
-        for d, (i, bs, n) in enumerate(
-            zip(idx, op.block_shape, op.array_shape)
+        for d, (i, bs, n, elem) in enumerate(
+            zip(idx, op.block_shape, op.array_shape, element)
         ):
-            start = int(i) * int(bs)
+            start = int(i) if elem else int(i) * int(bs)
             if start < 0 or start + int(bs) > int(n):
                 violations.append(WindowViolation(
                     point=tuple(pt), dim=d,

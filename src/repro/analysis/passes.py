@@ -8,9 +8,8 @@ so a disagreement is a real contract violation, never a tautology.
 
 Tolerance policy (documented in docs/architecture.md): VMEM model drift is
 gated at ``max(32 KiB, 2%)`` — the slack covers sub-block constants the
-cost model deliberately ignores (Winograd's BT/AT matrices, epilogue row
-double-buffering) while still catching any real block-sizing error, which
-moves footprints by whole block multiples (hundreds of KiB).  The VMEM
+cost model deliberately ignores (epilogue row double-buffering) while still
+catching any real block-sizing error, which moves footprints by whole block multiples (hundreds of KiB).  The VMEM
 *budget* check is exact: one byte over is an error.  Traffic is gated at
 ``max(4 KiB, 2%)``; the ideal-reuse ratio (actual / cost-model bytes on
 logical shapes) is reported as a metric but never gated, because physical

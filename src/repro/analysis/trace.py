@@ -99,6 +99,9 @@ class OperandInfo:
     dtype: str
     itemsize: int
     dep_axes: Tuple[int, ...]     # grid axes the index map depends on
+    #: Per dim: True where the index map yields an element offset
+    #: (``pl.Element``) rather than a block index.
+    element_dims: Tuple[bool, ...] = ()
     #: The operand's index-map ClosedJaxpr, kept so the kernel-interior
     #: passes (analysis.grid) can evaluate the map at concrete grid points.
     #: None for hand-built records in tests; excluded from equality.
@@ -118,8 +121,7 @@ class OperandInfo:
         the copy when consecutive steps map to the same block, so an operand
         whose index map depends on grid axes up to ``a`` is re-fetched once
         per step of the sub-grid ``grid[:a+1]`` — the BLIS panel-re-read
-        count.  A constant index map (e.g. the Winograd BT/AT matrices)
-        fetches exactly once.
+        count.  A constant index map fetches exactly once.
         """
         if not self.dep_axes:
             return 1
@@ -163,10 +165,17 @@ class PallasCallRecord:
 
     def vmem_bytes(self) -> int:
         """True per-program footprint: every streamed block double-buffered
-        (Pallas revolving windows) plus the scratch allocations."""
+        (Pallas revolving windows) plus the scratch allocations, each laid
+        out on Mosaic's (sublane, lane) tiles (``vmem_model.tiled_bytes``)."""
+        import numpy as np
+
+        from repro.core.vmem_model import tiled_bytes
+
         return (
-            2 * sum(op.block_bytes for op in self.operands)
-            + sum(s.nbytes for s in self.scratch)
+            2 * sum(tiled_bytes(op.block_shape, op.itemsize)
+                    for op in self.operands)
+            + sum(tiled_bytes(s.shape, np.dtype(s.dtype).itemsize)
+                  for s in self.scratch)
         )
 
     def traffic_bytes(self) -> int:
@@ -188,29 +197,44 @@ def _index_map_deps(index_map_jaxpr, n_axes: int) -> Tuple[int, ...]:
     )
 
 
+def _block_dim(bd) -> Tuple[int, bool]:
+    """(window size, element-indexed) of one ``BlockMapping`` dim."""
+    from jax.experimental import pallas as pl
+
+    if isinstance(bd, pl.Element):
+        return int(bd.block_size), True
+    if bd is None or isinstance(bd, pl.Squeezed):
+        return 1, False
+    if isinstance(bd, pl.Blocked):
+        return int(bd.block_size), False
+    return int(bd), False
+
+
 def _record_from_eqn(eqn) -> PallasCallRecord:
+    import numpy as np
+
     gm = eqn.params["grid_mapping"]
     grid = tuple(int(g) for g in gm.grid)
     n_axes = len(grid)
     ops: List[OperandInfo] = []
     for pos, bm in enumerate(gm.block_mappings):
-        asd = bm.array_shape_dtype
-        import numpy as np
-
+        aval = bm.array_aval
+        dims = [_block_dim(bd) for bd in bm.block_shape]
         ops.append(
             OperandInfo(
                 kind="in" if pos < gm.num_inputs else "out",
-                block_shape=tuple(int(d) for d in bm.block_shape),
-                array_shape=tuple(int(d) for d in asd.shape),
-                dtype=str(asd.dtype),
-                itemsize=int(np.dtype(asd.dtype).itemsize),
+                block_shape=tuple(size for size, _ in dims),
+                array_shape=tuple(int(d) for d in aval.shape),
+                dtype=str(aval.dtype),
+                itemsize=int(np.dtype(aval.dtype).itemsize),
                 dep_axes=_index_map_deps(bm.index_map_jaxpr, n_axes),
+                element_dims=tuple(elem for _, elem in dims),
                 index_map_jaxpr=bm.index_map_jaxpr,
             )
         )
     kernel_jaxpr = eqn.params["jaxpr"]
-    mosaic = (eqn.params.get("compiler_params") or {}).get("mosaic") or {}
-    semantics = mosaic.get("dimension_semantics")
+    mosaic = (eqn.params.get("compiler_params") or {}).get("mosaic_tpu")
+    semantics = getattr(mosaic, "dimension_semantics", None)
     if semantics is not None:
         semantics = tuple(str(s) for s in semantics)
     n_scratch = int(gm.num_scratch_operands)
@@ -224,7 +248,7 @@ def _record_from_eqn(eqn) -> PallasCallRecord:
                 )
             )
     return PallasCallRecord(
-        name=eqn.params["name_and_src_info"].name,
+        name=eqn.params.get("name") or kernel_jaxpr.debug_info.func_name,
         grid=grid,
         inputs=tuple(op for op in ops if op.kind == "in"),
         outputs=tuple(op for op in ops if op.kind == "out"),

@@ -59,6 +59,7 @@ from repro.core.conv_spec import (
     select_algorithm,
 )
 from repro.core.planner import ConvPlan, Planner
+from repro.kernels import mxu_precision
 from repro.util import ceil_to
 
 
@@ -1139,7 +1140,10 @@ def run_network(
             p = params[s.index - start]
             if cur.ndim == 4:
                 cur = cur.mean(axis=(1, 2))
-            cur = apply_activation(cur @ p["w"] + p["b"], l.activation)
+            cur = apply_activation(
+                jnp.dot(cur, p["w"], precision=mxu_precision(cur.dtype))
+                + p["b"], l.activation,
+            )
         outputs.append(cur)
     exit_layout = netplan.exit_layout
     if stop == n_steps and exit_layout.pad_c:
@@ -1262,6 +1266,7 @@ class NetworkExecutor:
         if devices is None:
             devices = jax.devices()
         self.mesh = None
+        self._placed = None
 
         def fwd(prms, xx):
             return run_network(netplan, prms, xx, interpret=interpret,
@@ -1269,14 +1274,13 @@ class NetworkExecutor:
 
         if len(devices) > 1 and netplan.batch % len(devices) == 0:
             import numpy as np
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import Mesh, PartitionSpec as P
 
             self.mesh = Mesh(np.array(devices), ("batch",))
-            fwd = shard_map(
+            fwd = jax.shard_map(
                 fwd, mesh=self.mesh,
                 in_specs=(P(), P("batch")), out_specs=P("batch"),
-                check_rep=False,
+                check_vma=False,
             )
         self._fn = jax.jit(fwd)
 
@@ -1286,4 +1290,17 @@ class NetworkExecutor:
             f"executor planned for batch {self.netplan.batch} at "
             f"{self.netplan.input_hw}, got {x.shape}"
         )
-        return self._fn(self.params, x)
+        if self._placed is None:
+            self._placed = self._place_params()
+        return self._fn(self._placed, x)
+
+    def _place_params(self):
+        """The prepared params where the forward reads them: replicated over
+        the batch mesh once, rather than broadcast from one device on every
+        call.  Placed on first call, so an executor over devices that are
+        only described (an ahead-of-time compile) can still be built."""
+        if self.mesh is None:
+            return self.params
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        return jax.device_put(self.params, NamedSharding(self.mesh, P()))

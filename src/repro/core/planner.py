@@ -57,8 +57,10 @@ from repro.util import ceil_to
 # v4 added the "networks" section — whole-network entries (written by
 # core/netplan.plan_network) recording per-layer plans after network-level
 # adjustment plus the inter-layer layout-elision decisions, so a warm
-# process rebuilds a NetworkPlan with zero re-tunes.
-PLAN_CACHE_VERSION = 6
+# process rebuilds a NetworkPlan with zero re-tunes.  v7: the VMEM model
+# counts Mosaic's (8, 128) tile padding and internal scratch and the im2col
+# kernel tiles rows (not whole images), so v6 kernel blocks are invalid.
+PLAN_CACHE_VERSION = 7
 
 # Default on-disk location (overridable per Planner and via environment).
 DEFAULT_CACHE_PATH = os.environ.get(
@@ -615,10 +617,9 @@ class Planner:
         elif algo is ConvAlgorithm.IM2COL_GEMM:
             from repro.kernels.im2col_gemm.ops import pick_blocks
 
-            ph, pw = spec.padding
             kernel_blocks = pick_blocks(
-                h + 2 * ph, w + 2 * pw, cin, cout, oh, ow, dtype_bytes,
-                vmem_budget=self.vmem_budget, kh=spec.kh, kw=spec.kw,
+                oh, ow, cin, cout, spec.kh, spec.kw, *spec.stride,
+                dtype_bytes, vmem_budget=self.vmem_budget,
             )
         else:
             kernel_blocks = (cfg.bm, cfg.bn, cfg.bk)

@@ -63,6 +63,35 @@ def itemsize(dtype) -> int:
 ACC_BYTES = itemsize("float32")
 
 
+def tiled_bytes(shape: Tuple[int, ...], dtype_bytes: int = 4) -> int:
+    """VMEM bytes of one buffer as Mosaic lays it out.
+
+    The two minor dims are padded to the native (sublane, lane) tile: 128
+    lanes, and 8 sublanes of 32-bit words — 16 rows for 2-byte and 32 rows
+    for 1-byte types, which pack along the sublanes.  A (1, 226, 8) fp32
+    slab therefore holds 16x its element bytes; counting elements is what
+    let the planner pick blocks the compiler then refused.
+    """
+    *major, sub, lane = (1,) * max(0, 2 - len(shape)) + tuple(shape)
+    sub_tile = 8 * max(1, 4 // dtype_bytes)
+    n = 1
+    for d in major:
+        n *= d
+    return n * ceil_to(sub, sub_tile) * ceil_to(lane, V5E.lane_width) * dtype_bytes
+
+
+#: Mosaic's internal scratch, fitted (as an upper bound) against the
+#: compiler's scoped-VMEM requirement on v5e — the least
+#: ``vmem_limit_bytes`` at which each kernel compiles, bisected to 64 KiB
+#: (tests/test_tpu_compile.py checks the bound).  A fixed allowance per
+#: kernel plus fp32 planes of the matmul-row x lane shape: the im2col patch
+#: and matmul result, and the Winograd transforms' eight row planes plus
+#: the per-position V and product.
+INTERNAL_BYTES = 256 * 1024
+IM2COL_INTERNAL_PLANES = 4
+WINOGRAD_INTERNAL_PLANES = 12
+
+
 @dataclasses.dataclass(frozen=True)
 class GemmShape:
     m: int
@@ -81,10 +110,12 @@ class BlockConfig:
     bk: int
 
     def vmem_bytes(self, dtype_bytes: int = 4, double_buffer: bool = True) -> int:
-        buf = 2 if double_buffer else 1
-        return (
-            buf * (self.bm * self.bk + self.bk * self.bn) * dtype_bytes
-            + self.bm * self.bn * ACC_BYTES
+        """The blocked GEMM kernel's full footprint at this blocking (the
+        quantity the autotuner budgets): ``gemm_kernel_vmem_bytes`` with the
+        int8 epilogue's two rows, the larger case."""
+        return gemm_kernel_vmem_bytes(
+            self.bm, self.bn, self.bk, dtype_bytes,
+            double_buffer=double_buffer, epilogue_rows=2,
         )
 
 
@@ -204,15 +235,17 @@ def autotune_gemm(
 def gemm_kernel_vmem_bytes(
     bm: int, bn: int, bk: int, dtype_bytes: int = 4,
     out_dtype_bytes: Optional[int] = None, double_buffer: bool = True,
-    epilogue_rows: int = 0, three_loop: bool = False,
+    epilogue_rows: int = 0, three_loop: bool = False, internal: bool = True,
 ) -> int:
     """Full per-program VMEM footprint of the blocked GEMM kernels.
 
-    Unlike ``BlockConfig.vmem_bytes`` (the quantity the autotuner *budgets*:
-    A/B blocks + accumulator), this is the complete footprint the compiled
-    kernel actually holds — including the streamed output block and the
-    fused epilogue's (1, bn) bias/scale rows — which is what the static
-    verifier (repro.analysis) checks the jaxpr-recovered footprint against.
+    The complete footprint the compiled kernel holds — the A/B blocks, the
+    streamed output block, the fused epilogue's (1, bn) bias/scale rows and
+    the accumulator scratch, each as ``tiled_bytes`` lays it out — plus,
+    with ``internal``, Mosaic's internal-scratch allowance.  The autotuner
+    budgets the full figure; the static verifier (repro.analysis) compares
+    the buffers alone (``internal=False``) with the jaxpr-recovered
+    footprint.
 
     ``epilogue_rows`` counts the (1, bn) fp32 rows the epilogue streams:
     one for a fused bias, two for int8's scale + bias.  ``three_loop``
@@ -222,12 +255,13 @@ def gemm_kernel_vmem_bytes(
     if out_dtype_bytes is None:
         out_dtype_bytes = ACC_BYTES if dtype_bytes == 1 else dtype_bytes
     buf = 2 if double_buffer else 1
-    total = buf * (bm * bk + bk * bn) * dtype_bytes      # A / B blocks
-    total += buf * bm * bn * out_dtype_bytes             # output block
-    total += buf * epilogue_rows * bn * ACC_BYTES        # bias / scale rows
+    total = buf * (tiled_bytes((bm, bk), dtype_bytes)         # A block
+                   + tiled_bytes((bk, bn), dtype_bytes))      # B block
+    total += buf * tiled_bytes((bm, bn), out_dtype_bytes)     # output block
+    total += buf * epilogue_rows * tiled_bytes((1, bn), ACC_BYTES)
     if not three_loop:
-        total += bm * bn * ACC_BYTES                     # accumulator scratch
-    return total
+        total += tiled_bytes((bm, bn), ACC_BYTES)             # accumulator
+    return total + (INTERNAL_BYTES if internal else 0)
 
 
 def winograd_traffic_bytes(
@@ -284,23 +318,35 @@ def im2col_gemm_traffic_bytes(
     )
 
 
+def im2col_window(
+    toh: int, ow: int, kh: int = 3, kw: int = 3, sh: int = 1, sw: int = 1,
+) -> Tuple[int, int, int]:
+    """(owp, rows_in, wq) of the im2col kernel's output width and window.
+
+    The kernel emits ``owp`` = OW rounded up to the 8-row sublane tile, and
+    reads, per stride phase, ``rows_in`` = toh + (kh-1)//sh rows of
+    ``wq`` = owp + (kw-1)//sw columns (kernels/im2col_gemm).
+    """
+    owp = ceil_to(ow, V5E.sublanes)
+    return owp, toh + (kh - 1) // sh, owp + (kw - 1) // sw
+
+
 def im2col_kernel_vmem_bytes(
-    hp: int, wp: int, toh: int, ow: int, bc: int, bo: int,
-    kh: int = 3, kw: int = 3, dtype_bytes: int = 4,
+    toh: int, ow: int, bc: int, bo: int,
+    kh: int = 3, kw: int = 3, sh: int = 1, sw: int = 1, dtype_bytes: int = 4,
     double_buffer: bool = True, bias: bool = True,
-    out_dtype_bytes: Optional[int] = None,
+    out_dtype_bytes: Optional[int] = None, internal: bool = True,
 ) -> int:
     """Per-program VMEM footprint of the fused im2col+GEMM conv kernel.
 
     The kernel (kernels/im2col_gemm/kernel.py) keeps live at once: the
-    (1, Hp, Wp, bc) input channel slab and the (kh, kw, bc, bo) weight block
-    (both double-buffered across the in-channel grid axis), the optional
-    (1, bo) bias row, the (1, toh, OW, bo) output block and the
-    (toh, OW, bo) fp32 accumulator scratch.  The old pick_blocks heuristic
-    budgeted only the input slab and the accumulator — the weight block
-    (quadratic in the channel blocks) and the bias row silently overflowed
-    the budget for deep layers, exactly the bug the Winograd pick_blocks
-    had before PR 3.
+    (sh, rows_in, sw*wq, bc) input window of its row tile and the
+    (kh, kw, bc, bo) weight block (both double-buffered across the
+    in-channel grid axis), the optional (1, bo) bias/scale rows, the
+    (toh, OWp, bo) output block and the (toh*OWp, bo) fp32/int32
+    accumulator scratch, plus (``internal``) Mosaic's internal scratch for
+    the patch and the matmul result.  Every buffer is counted as
+    ``tiled_bytes`` lays it out.
 
     ``out_dtype_bytes`` sizes the output block separately from the operands:
     an int8 conv reads int8 slabs/weights but writes fp32 (dequant
@@ -310,46 +356,65 @@ def im2col_kernel_vmem_bytes(
     if out_dtype_bytes is None:
         out_dtype_bytes = ACC_BYTES if dtype_bytes == 1 else dtype_bytes
     buf = 2 if double_buffer else 1
+    owp, rows_in, wq = im2col_window(toh, ow, kh, kw, sh, sw)
+    rows = toh * owp
     return (
-        buf * hp * wp * bc * dtype_bytes            # input channel slab
-        + buf * kh * kw * bc * bo * dtype_bytes     # weight block
-        + (bo * ACC_BYTES if bias else 0)           # fp32 bias/scale row
-        + buf * toh * ow * bo * out_dtype_bytes     # output block
-        + toh * ow * bo * ACC_BYTES                 # fp32/int32 acc scratch
+        buf * tiled_bytes((sh, rows_in, sw * wq, bc), dtype_bytes)
+        + buf * tiled_bytes((kh, kw, bc, bo), dtype_bytes)
+        + (2 * buf * tiled_bytes((1, bo), ACC_BYTES) if bias else 0)
+        + buf * tiled_bytes((toh, owp, bo), out_dtype_bytes)
+        + tiled_bytes((rows, bo), ACC_BYTES)
+        + (IM2COL_INTERNAL_PLANES * tiled_bytes((rows, max(bc, bo)), ACC_BYTES)
+           + INTERNAL_BYTES if internal else 0)
     )
 
 
 def winograd_kernel_vmem_bytes(
     bt: int, bc: int, bo: int, fused: bool = True, dtype_bytes: int = 4,
-    double_buffer: bool = True,
+    double_buffer: bool = True, internal: bool = True,
 ) -> int:
     """Per-program VMEM footprint of the Winograd Pallas kernels.
 
-    ``fused=True``: the single-pass megakernel holds the (bt, 8, 8, bc) tile
+    ``fused=True``: the single-pass megakernel holds the (8, 8, bt, bc) tile
     block and the (8, 8, bc, bo) weight block (both double-buffered across
-    the Cin grid axis), the (8, 8, bt, bo) fp32 M accumulator scratch, and
-    the (bt, 6, 6, bo) output block.
+    the Cin grid axis), the (8, 8, bt, bo) fp32 M accumulator scratch, the
+    (6, 6, bt, bo) output block, the (1, bo) bias row, and (``internal``)
+    Mosaic's internal scratch for the transform planes.
 
     ``fused=False``: the 3-pass pipeline's footprint is the max over its
     three kernels — each one's in/out blocks are live simultaneously (plus
     the tuple-multiply's fp32 accumulator scratch).
     """
     buf = 2 if double_buffer else 1
+    d = dtype_bytes
+    planes = WINOGRAD_INTERNAL_PLANES if internal else 0
+    extra = INTERNAL_BYTES if internal else 0
     if fused:
         return (
-            buf * bt * 64 * bc * dtype_bytes        # input tile block
-            + buf * 64 * bc * bo * dtype_bytes      # transformed weight block
-            + 64 * bt * bo * ACC_BYTES              # M accumulator scratch
-            + buf * bt * 36 * bo * dtype_bytes      # output block
+            buf * tiled_bytes((8, 8, bt, bc), d)         # input tile block
+            + buf * tiled_bytes((8, 8, bc, bo), d)       # transformed weights
+            + tiled_bytes((8, 8, bt, bo), ACC_BYTES)     # M accumulator
+            + buf * tiled_bytes((6, 6, bt, bo), d)       # output block
+            + buf * tiled_bytes((1, bo), ACC_BYTES)      # bias row
+            + planes * tiled_bytes((bt, bc + bo), ACC_BYTES)
+            + extra
         )
-    input_tf = buf * bt * 64 * bc * dtype_bytes + buf * 64 * bt * bc * dtype_bytes
-    tuple_mul = (
-        buf * (bt * bc + bc * bo) * dtype_bytes
-        + buf * bt * bo * dtype_bytes
-        + bt * bo * ACC_BYTES
+    input_tf = (
+        buf * 2 * tiled_bytes((8, 8, bt, bc), d)
+        + planes * tiled_bytes((bt, bc), ACC_BYTES)
     )
-    output_tf = buf * 64 * bt * bo * dtype_bytes + buf * bt * 36 * bo * dtype_bytes
-    return max(input_tf, tuple_mul, output_tf)
+    tuple_mul = (
+        buf * (tiled_bytes((bt, bc), d) + tiled_bytes((bc, bo), d))
+        + buf * tiled_bytes((bt, bo), d)
+        + tiled_bytes((bt, bo), ACC_BYTES)
+    )
+    output_tf = (
+        buf * tiled_bytes((8, 8, bt, bo), d)
+        + buf * tiled_bytes((6, 6, bt, bo), d)
+        + buf * tiled_bytes((1, bo), ACC_BYTES)
+        + planes * tiled_bytes((bt, bo), ACC_BYTES)
+    )
+    return max(input_tf, tuple_mul, output_tf) + extra
 
 
 # Candidate (bt, bc, bo) grids for the Winograd kernels: tiles on sublanes,

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 from typing import Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -84,8 +85,10 @@ def transform_weights(w: jnp.ndarray, dtype=jnp.float32) -> jnp.ndarray:
       (8, 8, Cin, Cout) transformed weights.
     """
     g = jnp.asarray(G, dtype)
-    # U[a,b,c,o] = sum_{i,j} G[a,i] w[i,j,c,o] G[b,j]
-    return jnp.einsum("ai,bj,ijco->abco", g, g, w.astype(dtype))
+    # U[a,b,c,o] = sum_{i,j} G[a,i] w[i,j,c,o] G[b,j], at full fp32 precision
+    # (a TPU's default would round G's fractional entries to bf16).
+    return jnp.einsum("ai,bj,ijco->abco", g, g, w.astype(dtype),
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def _tile_input(x: jnp.ndarray, oh: int, ow: int) -> Tuple[jnp.ndarray, int, int]:

@@ -30,12 +30,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
-# jax >= 0.5 requires carries that differ per device to be marked
-# device-varying over the mesh axis (vma tracking); older versions have no
-# pvary and no tracking — the identity is exactly right there.
-_pvary = getattr(jax.lax, "pvary", lambda x, axes: x)
 
 
 def gpipe_spmd(stage_fn: Callable, axis_name: str, n_stages: int,
@@ -94,10 +89,13 @@ def gpipe_spmd(stage_fn: Callable, axis_name: str, n_stages: int,
 
         # Mark the carries as device-varying over the stage axis (each stage
         # holds different values), required under shard_map's vma tracking.
-        outs0 = _pvary(
-            jnp.zeros((n_micro,) + mb_shape, micro.dtype), (axis_name,)
+        outs0 = jax.lax.pcast(
+            jnp.zeros((n_micro,) + mb_shape, micro.dtype), (axis_name,),
+            to="varying",
         )
-        recv0 = _pvary(jnp.zeros(mb_shape, micro.dtype), (axis_name,))
+        recv0 = jax.lax.pcast(
+            jnp.zeros(mb_shape, micro.dtype), (axis_name,), to="varying"
+        )
         (_, outs), _ = jax.lax.scan(tick, (recv0, outs0), jnp.arange(total))
         return outs
 
@@ -140,12 +138,12 @@ def pipeline_forward(
         )
         return last
 
-    fn = shard_map(
+    fn = jax.shard_map(
         spmd,
         mesh=mesh,
         in_specs=(P(stage_axis), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     out = fn(stacked_params, micro)
     return out.reshape(x.shape[0], *out.shape[2:])
@@ -316,6 +314,7 @@ class PipelineExecutor:
 
         pbufs, treedefs, metas = _pack_stage_params(per_stage)
         self._pbufs = pbufs
+        self._placed = None
 
         def make_branch(s: int):
             a, z = bounds[s]
@@ -355,12 +354,12 @@ class PipelineExecutor:
                 "stage",
             )
 
-        sharded = shard_map(
+        sharded = jax.shard_map(
             spmd,
             mesh=self.mesh,
             in_specs=(P("stage"), P()),
             out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )
         n_micro_, batch = self.n_micro, netplan.batch
 
@@ -379,4 +378,12 @@ class PipelineExecutor:
             f"pipeline executor planned for batch {self.netplan.batch} at "
             f"{self.netplan.input_hw}, got {x.shape}"
         )
-        return self._fn(self._pbufs, x)
+        if self._placed is None:
+            # Each stage's row of the stacked buffers goes to its own device
+            # once (on first call, so a described-device mesh still builds).
+            from jax.sharding import NamedSharding
+
+            self._placed = jax.device_put(
+                self._pbufs, NamedSharding(self.mesh, P("stage"))
+            )
+        return self._fn(self._placed, x)

@@ -1,9 +1,10 @@
 """Target-hardware constants (TPU v5e) used by the co-design model, the
 roofline analysis, and the benchmarks.
 
-This container is CPU-only; v5e is the *target*.  All performance reporting
-derives from these constants + compiled-artifact statistics (see
-roofline/analysis.py), playing the role gem5 plays in the paper.
+v5e is the target chip.  The cost model plans against these constants
+(playing the role gem5 plays in the paper); a run on a real device looks
+its chip up by ``device_kind`` in ``CHIPS`` (``chip_for``), and a device
+that is not in the table is an error, never a default.
 """
 from __future__ import annotations
 
@@ -28,6 +29,27 @@ class ChipSpec:
 
 
 V5E = ChipSpec()
+
+#: Chips a device run may report against, keyed by ``jax.Device.device_kind``.
+#: Peaks: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 393 TOP/s
+#: int8, 16 GB HBM at 819 GB/s).
+CHIPS = {"TPU v5 lite": V5E}
+
+
+def chip_for(device) -> ChipSpec:
+    """The ``ChipSpec`` of a live TPU device; raises for anything else."""
+    if device.platform != "tpu":
+        raise RuntimeError(
+            f"no TPU: JAX's first device is {device.platform!r} "
+            f"({device.device_kind!r})"
+        )
+    try:
+        return CHIPS[device.device_kind]
+    except KeyError:
+        raise RuntimeError(
+            f"unknown TPU kind {device.device_kind!r}: add its peaks to "
+            f"repro.hw.CHIPS (known: {sorted(CHIPS)})"
+        ) from None
 
 
 @dataclasses.dataclass(frozen=True)

@@ -216,39 +216,25 @@ def _conv2d_pallas_laidout(
 
     from repro.kernels.im2col_gemm.ops import (
         conv2d_im2col_padded_call,
-        padded_input_hw,
         pick_blocks,
     )
 
-    b, h, ww, cp = x.shape
+    _, h, ww, cp = x.shape
     kh, kw, _, o_phys = w.shape
     oh, ow = spec.out_hw(h, ww)
-    ph, pw = spec.padding
     if blocks is None:
         blocks = pick_blocks(
-            h + 2 * ph, ww + 2 * pw, cp, o_phys, oh, ow,
-            jnp.dtype(x.dtype).itemsize, kh=kh, kw=kw,
+            oh, ow, cp, o_phys, kh, kw, *spec.stride,
+            jnp.dtype(x.dtype).itemsize,
         )
-    toh, bc, bo = blocks
-    _, need_h, need_w = padded_input_hw(h, ww, spec, toh)
-    pads = (
-        (0, 0),
-        (ph, max(need_h - h - ph, 0)),
-        (pw, max(need_w - ww - pw, 0)),
-        (0, 0),
-    )
-    x_p = jnp.pad(x, pads) if any(p != (0, 0) for p in pads) else x
-    op = ceil_to(o_phys, bo)
+    op = ceil_to(o_phys, blocks[2])
     w_p = (
         jnp.pad(w, ((0, 0), (0, 0), (0, 0), (0, op - o_phys)))
         if op != o_phys else w
     )
-    bias_p = pad_bias_row(bias, op)
-    scale_p = pad_bias_row(scale, op)
     out = conv2d_im2col_padded_call(
-        x_p, w_p, spec, oh, ow, blocks, interpret=interpret,
-        bias_p=bias_p, activation=activation, scale_p=scale_p,
+        x, w_p, spec, oh, ow, blocks, interpret=interpret,
+        bias_p=pad_bias_row(bias, op), activation=activation,
+        scale_p=pad_bias_row(scale, op),
     )
-    if out.shape[1] != oh:
-        out = out[:, :oh]
     return out[..., :o_keep] if out.shape[-1] != o_keep else out

@@ -173,7 +173,7 @@ def gemm_call_descriptor(
         "grid": grid,
         "model_vmem_bytes": gemm_kernel_vmem_bytes(
             bm, bn, bk, dtype_bytes, epilogue_rows=rows,
-            three_loop=variant == "3loop",
+            three_loop=variant == "3loop", internal=False,
         ),
         "traffic_bytes": traffic,
         "vmem_one_sided": False,

@@ -2,15 +2,24 @@
 
 TPU adaptation of the paper's im2col+GEMM pipeline (§IV.A): instead of
 materializing the (K x N) im2col matrix in HBM (what Darknet does), the
-patch gather happens *inside* the kernel on the VMEM-resident input block —
+patch gather happens *inside* the kernel on the VMEM-resident input window —
 i.e. im2col is fused into the GEMM the way the paper fuses packing into the
 6-loop blocked GEMM.  Data layout is NHWC so channels ride the lane axis.
 
 Grid: (batch, output-row tiles, out-channel blocks, in-channel blocks);
-the in-channel grid axis is the K-reduction, accumulated in a VMEM fp32
-scratch.  For each of the kh*kw taps (static unroll — the paper's loop
-unrolling) the kernel slices a shifted window out of the resident input
-block with `pl.ds` and issues one MXU matmul per tap.
+the in-channel grid axis is the K-reduction, accumulated in a VMEM
+scratch.  The wrapper hands the kernel the input split into its stride
+phases, (B, sh, Hq, sw*Wq, C) with element [b, p, i, q*Wq + j] =
+x[b, i*sh + p, j*sw + q] (a reshape when the stride is 1), so every tap
+reads a unit-stride window: no strided sublane loads, which Mosaic does not
+lower for packed int8.  Each program DMAs only the rows its ``toh`` output
+rows read — an element-offset window of toh + (kh-1)//sh rows per phase,
+overlapping its neighbours by the halo — so the VMEM footprint is bounded
+by the row tile, not the image.  For each of the kh*kw taps (static unroll —
+the paper's loop unrolling) the kernel issues one (toh*OWp, bc) x (bc, bo)
+MXU matmul.  The output width OWp is OW rounded up to the 8-row sublane
+tile so the patch collapses to 2-D without a relayout; the wrapper crops
+the extra columns.
 """
 from __future__ import annotations
 
@@ -22,44 +31,49 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.conv_spec import apply_activation
-from repro.kernels.compat import CompilerParams
+from repro.kernels import mxu_precision
 
 
-def _accumulate_taps(x_ref, w_ref, o_ref, acc_ref, *, kh, kw, sh, sw, toh, ow):
+def _accumulate_taps(x_ref, w_ref, acc_ref, *, kh, kw, sh, sw, toh, ow):
     """Shared K-reduction body: init the accumulator on the first in-channel
     block, then statically unroll over the kh*kw taps (paper's loop
-    unrolling) — each tap is a shifted strided window -> one
-    (toh*OW, bc) x (bc, bo) MXU matmul."""
-    r = pl.program_id(1)
+    unrolling) — each tap is a unit-stride window of one stride phase -> one
+    (toh*OWp, bc) x (bc, bo) MXU matmul accumulated in the (toh*OWp, bo)
+    scratch.  int8 operands accumulate in int32 (the MXU's native quantized
+    accumulation width), float operands in fp32."""
 
     @pl.when(pl.program_id(3) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     bc = x_ref.shape[-1]
-    bo = o_ref.shape[-1]
-    row0 = r * toh * sh
-    acc = acc_ref[...].reshape(toh * ow, bo)
+    wq = x_ref.shape[-2] // sw
+    precision = mxu_precision(x_ref.dtype)
     for di in range(kh):
         for dj in range(kw):
-            slab = x_ref[
-                0,
-                pl.ds(row0 + di, (toh - 1) * sh + 1),
-                pl.ds(dj, (ow - 1) * sw + 1),
+            patch = x_ref[
+                di % sh,
+                pl.ds(di // sh, toh),
+                pl.ds((dj % sw) * wq + dj // sw, ow),
                 :,
-            ]
-            patch = slab[::sh, ::sw, :].reshape(toh * ow, bc)
-            acc += jnp.dot(
-                patch, w_ref[di, dj], preferred_element_type=jnp.float32
+            ].reshape(toh * ow, bc)
+            acc_ref[...] += jnp.dot(
+                patch, w_ref[di, dj], preferred_element_type=acc_ref.dtype,
+                precision=precision,
             )
-    acc_ref[...] = acc.reshape(toh, ow, bo)
+
+
+def _store(o_ref, out, activation):
+    o_ref[...] = apply_activation(out, activation).reshape(o_ref.shape).astype(
+        o_ref.dtype
+    )
 
 
 def _conv_kernel(
-    x_ref,  # (1, Hp, Wp, bc) VMEM-resident input block (one channel slab)
+    x_ref,  # (sh, rows_in, sw*Wq, bc) VMEM phase windows (one channel slab)
     w_ref,  # (kh, kw, bc, bo)
-    o_ref,  # (1, toh, OW, bo)
-    acc_ref,  # (toh, OW, bo) fp32 scratch
+    o_ref,  # (toh, OWp, bo)
+    acc_ref,  # (toh * OWp, bo) fp32 scratch
     *,
     kh: int,
     kw: int,
@@ -69,14 +83,12 @@ def _conv_kernel(
     ow: int,
     activation: str = "linear",
 ):
-    _accumulate_taps(x_ref, w_ref, o_ref, acc_ref,
+    _accumulate_taps(x_ref, w_ref, acc_ref,
                      kh=kh, kw=kw, sh=sh, sw=sw, toh=toh, ow=ow)
 
     @pl.when(pl.program_id(3) == pl.num_programs(3) - 1)
     def _done():
-        o_ref[...] = apply_activation(acc_ref[...], activation).astype(
-            o_ref.dtype
-        )[None]
+        _store(o_ref, acc_ref[...], activation)
 
 
 def _conv_bias_kernel(
@@ -85,43 +97,13 @@ def _conv_bias_kernel(
 ):
     """_conv_kernel plus a fused (1, bo) bias row applied in the output
     stage, on the fp32 accumulator, after the full K reduction."""
-    _accumulate_taps(x_ref, w_ref, o_ref, acc_ref,
+    _accumulate_taps(x_ref, w_ref, acc_ref,
                      kh=kh, kw=kw, sh=sh, sw=sw, toh=toh, ow=ow)
 
     @pl.when(pl.program_id(3) == pl.num_programs(3) - 1)
     def _done():
-        out = acc_ref[...] + bias_ref[...].astype(jnp.float32)
-        o_ref[...] = apply_activation(out, activation).astype(o_ref.dtype)[None]
-
-
-def _accumulate_taps_q8(x_ref, w_ref, o_ref, acc_ref, *, kh, kw, sh, sw,
-                        toh, ow):
-    """int8 K-reduction body: same tap unroll as ``_accumulate_taps`` but
-    int8 patch x int8 weight block products accumulate in an int32 VMEM
-    scratch (the MXU's native quantized accumulation width)."""
-    r = pl.program_id(1)
-
-    @pl.when(pl.program_id(3) == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    bc = x_ref.shape[-1]
-    bo = o_ref.shape[-1]
-    row0 = r * toh * sh
-    acc = acc_ref[...].reshape(toh * ow, bo)
-    for di in range(kh):
-        for dj in range(kw):
-            slab = x_ref[
-                0,
-                pl.ds(row0 + di, (toh - 1) * sh + 1),
-                pl.ds(dj, (ow - 1) * sw + 1),
-                :,
-            ]
-            patch = slab[::sh, ::sw, :].reshape(toh * ow, bc)
-            acc += jnp.dot(
-                patch, w_ref[di, dj], preferred_element_type=jnp.int32
-            )
-    acc_ref[...] = acc.reshape(toh, ow, bo)
+        _store(o_ref, acc_ref[...] + bias_ref[...].astype(jnp.float32),
+               activation)
 
 
 def _conv_q8_kernel(
@@ -131,15 +113,15 @@ def _conv_q8_kernel(
     """int8 conv: fused dequant epilogue act(acc * scale) on the int32
     accumulator; ``scale_ref`` is the (1, bo) per-out-channel row of folded
     activation x weight quantization scales (core/quant.py)."""
-    _accumulate_taps_q8(x_ref, w_ref, o_ref, acc_ref,
-                        kh=kh, kw=kw, sh=sh, sw=sw, toh=toh, ow=ow)
+    _accumulate_taps(x_ref, w_ref, acc_ref,
+                     kh=kh, kw=kw, sh=sh, sw=sw, toh=toh, ow=ow)
 
     @pl.when(pl.program_id(3) == pl.num_programs(3) - 1)
     def _done():
         out = acc_ref[...].astype(jnp.float32) * scale_ref[...].astype(
             jnp.float32
         )
-        o_ref[...] = apply_activation(out, activation).astype(o_ref.dtype)[None]
+        _store(o_ref, out, activation)
 
 
 def _conv_q8_bias_kernel(
@@ -147,8 +129,8 @@ def _conv_q8_bias_kernel(
     kh: int, kw: int, sh: int, sw: int, toh: int, ow: int, activation: str,
 ):
     """int8 conv with the full fused epilogue: act(acc * scale + bias)."""
-    _accumulate_taps_q8(x_ref, w_ref, o_ref, acc_ref,
-                        kh=kh, kw=kw, sh=sh, sw=sw, toh=toh, ow=ow)
+    _accumulate_taps(x_ref, w_ref, acc_ref,
+                     kh=kh, kw=kw, sh=sh, sw=sw, toh=toh, ow=ow)
 
     @pl.when(pl.program_id(3) == pl.num_programs(3) - 1)
     def _done():
@@ -156,11 +138,11 @@ def _conv_q8_bias_kernel(
             jnp.float32
         )
         out = out + bias_ref[...].astype(jnp.float32)
-        o_ref[...] = apply_activation(out, activation).astype(o_ref.dtype)[None]
+        _store(o_ref, out, activation)
 
 
 def conv2d_im2col_gemm_pallas(
-    x: jnp.ndarray,  # (B, Hp, Wp, C) already conv-padded, C % bc == 0
+    x: jnp.ndarray,  # (B, sh, Hq, sw*Wq, C) stride phases, C % bc == 0
     w: jnp.ndarray,  # (kh, kw, C, O), O % bo == 0
     sh: int,
     sw: int,
@@ -177,20 +159,24 @@ def conv2d_im2col_gemm_pallas(
 ) -> jnp.ndarray:
     """Run the fused conv kernel.  Returns (B, OHp, OW, O); caller crops.
 
-    The input must be pre-padded so that every row tile's window is in
-    bounds:  Hp >= (OHp-1)*sh + kh with OHp = ceil(oh/toh)*toh, and
-    Wp >= (OW-1)*sw + kw.  ``bias`` (1, O) and ``activation`` are the fused
-    epilogue, applied once after the full in-channel reduction.
+    ``x`` is the conv-padded input split into its stride phases
+    (``ops.phase_split``): Hq >= OHp + (kh-1)//sh rows per phase with
+    OHp = ceil(oh/toh)*toh, Wq >= ow + (kw-1)//sw columns per phase.  ``ow``
+    is the kernel's output width, a multiple of 8 (``ops.kernel_out_width``).
+    ``bias`` (1, O) and ``activation`` are the fused epilogue, applied once
+    after the full in-channel reduction.
 
     Passing ``scale`` (1, O) selects the int8 path: ``x``/``w`` must be
     int8, the accumulator scratch is int32, and the epilogue dequantizes —
     act(acc * scale + bias) — writing ``out_dtype`` (defaults to fp32).
     """
-    b, hp, wp, c = x.shape
+    b, xsh, hq, wsw, c = x.shape
     kh, kw, _, o = w.shape
     ohp = -(-oh // toh) * toh
-    assert hp >= (ohp - 1) * sh + kh, (hp, ohp, sh, kh)
-    assert wp >= (ow - 1) * sw + kw, (wp, ow, sw, kw)
+    rows_in = toh + (kh - 1) // sh
+    assert xsh == sh and wsw % sw == 0, (x.shape, sh, sw)
+    assert hq >= ohp + (kh - 1) // sh, (hq, ohp, sh, kh)
+    assert wsw // sw >= ow + (kw - 1) // sw, (wsw, ow, sw, kw)
     assert c % bc == 0 and o % bo == 0
     assert bias is None or bias.shape == (1, o), (o, getattr(bias, "shape", None))
     quantized = scale is not None
@@ -202,7 +188,12 @@ def conv2d_im2col_gemm_pallas(
         out_dtype = out_dtype or x.dtype
 
     in_specs = [
-        pl.BlockSpec((1, hp, wp, bc), lambda bi, r, oc, ic: (bi, 0, 0, ic)),
+        # Element-offset row window: phase rows [r*toh, r*toh + rows_in).
+        pl.BlockSpec(
+            (pl.squeezed, pl.Element(sh), pl.Element(rows_in),
+             pl.Element(wsw), pl.Element(bc)),
+            lambda bi, r, oc, ic: (bi, 0, r * toh, 0, ic * bc),
+        ),
         pl.BlockSpec((kh, kw, bc, bo), lambda bi, r, oc, ic: (0, 0, ic, oc)),
     ]
     if quantized:
@@ -223,13 +214,13 @@ def conv2d_im2col_gemm_pallas(
         grid=(b, ohp // toh, o // bo, c // bc),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (1, toh, ow, bo), lambda bi, r, oc, ic: (bi, r, 0, oc)
+            (pl.squeezed, toh, ow, bo), lambda bi, r, oc, ic: (bi, r, 0, oc)
         ),
         out_shape=jax.ShapeDtypeStruct((b, ohp, ow, o), out_dtype),
         scratch_shapes=[
-            pltpu.VMEM((toh, ow, bo), jnp.int32 if quantized else jnp.float32)
+            pltpu.VMEM((toh * ow, bo), jnp.int32 if quantized else jnp.float32)
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

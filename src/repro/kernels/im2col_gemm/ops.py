@@ -6,7 +6,7 @@ input slab, weight block, bias row, output block and accumulator — fits the
 VMEM budget), runs the kernel, crops the output.
 
 The pad/crop bookkeeping is split out of the jitted body
-(`pad_conv_operands` / `conv2d_im2col_padded_call` / the final crop) so the
+(`conv2d_im2col_padded_call` / the final channel crop) so the
 network executor (core/netplan.py) can own the layer boundaries: a planned
 network pads once at entry, flows block-padded activations between layers,
 and crops once at exit.
@@ -20,95 +20,78 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.conv_spec import ConvSpec
-from repro.core.vmem_model import ACC_BYTES, im2col_kernel_vmem_bytes
+from repro.core.vmem_model import (
+    ACC_BYTES,
+    im2col_kernel_vmem_bytes,
+    im2col_window,
+)
 from repro.hw import V5E
 from repro.kernels.im2col_gemm.kernel import conv2d_im2col_gemm_pallas
 from repro.util import ceil_to, pad_bias_row
 
 
 def pick_blocks(
-    hp: int, wp: int, c: int, o: int, oh: int, ow: int, dtype_bytes: int = 4,
-    vmem_budget: Optional[int] = None, kh: int = 3, kw: int = 3,
-    out_dtype_bytes: Optional[int] = None,
+    oh: int, ow: int, c: int, o: int, kh: int = 3, kw: int = 3,
+    sh: int = 1, sw: int = 1, dtype_bytes: int = 4,
+    vmem_budget: Optional[int] = None, out_dtype_bytes: Optional[int] = None,
 ) -> Tuple[int, int, int]:
-    """(toh, bc, bo): biggest channel slab + row tile fitting the VMEM budget.
+    """(toh, bc, bo): the largest row tile + channel blocks fitting VMEM.
 
     This is the conv-kernel instance of the paper's block-size tuning
-    (Table II): the input slab (Hp*Wp*bc) plays the role of the packed B
-    panel, the accumulator (toh*OW*bo) the role of the C block.  Budgets the
-    **full** per-program footprint via
-    ``vmem_model.im2col_kernel_vmem_bytes`` — including the (kh, kw, bc, bo)
-    weight block and the bias row the old heuristic ignored (mirroring the
-    PR 3 fix to the Winograd ``pick_blocks``).  The channel slab shrinks
-    first (it is what the weight block is quadratic in), then the
-    out-channel block, then the row tile; nothing shrinks below the
-    (sublane, lane) granularity floor (8, 128).
+    (Table II): the row window (toh + halo rows x Wp x bc) plays the role of
+    the packed B panel, the accumulator (toh*OWp x bo) the role of the C
+    block.  Budgets the **full** per-program footprint via
+    ``vmem_model.im2col_kernel_vmem_bytes`` — tile-padded blocks, the
+    weight block, the epilogue rows and Mosaic's internal scratch.  The
+    channel blocks are lane-legal multiples of 128 (the wrappers pad
+    narrower channel counts up, as the GEMM path does); the out-channel
+    block shrinks first, then the row tile.
     """
     budget = vmem_budget if vmem_budget is not None else V5E.vmem_bytes
-    bc = min(ceil_to(c, 8), 128)
-    bo = min(ceil_to(o, 128), 256)
+    bc = V5E.lane_width
+    bo = min(ceil_to(o, V5E.lane_width), 256)
     toh = min(oh, 64)
 
     def fits() -> bool:
         return im2col_kernel_vmem_bytes(
-            hp, wp, toh, ow, bc, bo, kh, kw, dtype_bytes,
+            toh, ow, bc, bo, kh, kw, sh, sw, dtype_bytes,
             out_dtype_bytes=out_dtype_bytes,
         ) <= budget
 
-    while not fits() and bc > 8:
-        bc = max(8, bc // 2)
-    while not fits() and bo > 128:
-        bo = max(128, ceil_to(bo // 2, 128))
+    while not fits() and bo > V5E.lane_width:
+        bo = max(V5E.lane_width, ceil_to(bo // 2, V5E.lane_width))
     while not fits() and toh > 1:
         toh = max(1, toh // 2)
-    return max(toh, 1), max(bc, 8), bo
+    return toh, bc, bo
 
 
-def padded_input_hw(
-    h: int, w: int, spec: ConvSpec, toh: int
-) -> Tuple[int, int, int]:
-    """(ohp, need_h, need_w): the kernel's row-tiled output height and the
-    physical input dims every row tile's window needs to stay in bounds."""
-    oh, ow = spec.out_hw(h, w)
-    sh, sw = spec.stride
-    ohp = ceil_to(oh, min(toh, oh))
-    need_h = (ohp - 1) * sh + spec.kh
-    need_w = (ow - 1) * sw + spec.kw
-    return ohp, need_h, need_w
+def phase_split(
+    x: jnp.ndarray, spec: ConvSpec, oh: int, ow: int, toh: int
+) -> jnp.ndarray:
+    """(B, H, W, C) -> the kernel's (B, sh, Hq, sw*Wq, C) stride phases.
 
-
-def pad_conv_operands(
-    x: jnp.ndarray,
-    w: jnp.ndarray,
-    spec: ConvSpec,
-    blocks: Tuple[int, int, int],
-    bias: Optional[jnp.ndarray] = None,
-):
-    """Block-align (x, w, bias) for ``conv2d_im2col_padded_call``.
-
-    Applies the conv's own spatial padding plus the trailing row/column pad
-    the row-tiled grid needs, and pads channels to the (bc, bo) block
-    multiples.  Runs under the caller's jit; the executor skips it entirely
-    when the incoming activation already satisfies the layout.
+    Applies the conv's own spatial padding, pads (or crops) to exactly the
+    rows and columns the row-tiled grid reads, and splits rows and columns
+    into their stride phases: element [b, p, i, q*Wq + j] is padded
+    x[b, i*sh + p, j*sw + q].  For a stride-1 conv the split is a reshape.
+    Spatial axes only — the channel axis passes through untouched.
     """
-    b, h, ww, c = x.shape
-    o = w.shape[-1]
-    toh, bc, bo = blocks
+    b, h, w, c = x.shape
+    sh, sw = spec.stride
     ph, pw = spec.padding
-    _, need_h, need_w = padded_input_hw(h, ww, spec, toh)
-    cp, op = ceil_to(c, bc), ceil_to(o, bo)
-    x_p = jnp.pad(
-        x,
-        (
-            (0, 0),
-            (ph, max(need_h - h - ph, 0)),
-            (pw, max(need_w - ww - pw, 0)),
-            (0, cp - c),
-        ),
-    )
-    w_p = jnp.pad(w, ((0, 0), (0, 0), (0, cp - c), (0, op - o)))
-    bias_p = pad_bias_row(bias, op)
-    return x_p, w_p, bias_p
+    owp, rows_in, wq = im2col_window(toh, ow, spec.kh, spec.kw, sh, sw)
+    hq = ceil_to(oh, toh) + rows_in - toh
+    hn, wn = hq * sh, wq * sw
+    pads = ((0, 0), (ph, max(hn - h - ph, 0)), (pw, max(wn - w - pw, 0)),
+            (0, 0))
+    if any(p != (0, 0) for p in pads):
+        x = jnp.pad(x, pads)
+    if x.shape[1] != hn or x.shape[2] != wn:
+        x = x[:, :hn, :wn]
+    if (sh, sw) == (1, 1):
+        return x.reshape(b, 1, hn, wn, c)
+    x = x.reshape(b, hq, sh, wq, sw, c).transpose(0, 2, 1, 4, 3, 5)
+    return x.reshape(b, sh, hq, sw * wq, c)
 
 
 def conv2d_im2col_padded_call(
@@ -124,22 +107,27 @@ def conv2d_im2col_padded_call(
     activation: str = "linear",
     scale_p: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
-    """The kernel call on pre-padded operands: no padding, no cropping.
+    """The kernel call on channel-padded operands; returns (B, OH, OW, Op).
 
-    ``x_p`` must already carry the conv's spatial padding, the trailing
-    row/col pad from ``padded_input_hw`` and channels padded to the bc
-    multiple; ``w_p``/``bias_p`` must be padded to the same channel blocks.
-    ``scale_p`` (1, Op) selects the int8 dequant path (see kernel.py).
-    Returns the raw (B, OHp, OW, Op) kernel output — the caller (public
-    wrapper or network executor) owns the row/channel crops.
+    ``x_p`` (B, H, W, Cp) carries channels padded to the bc multiple but no
+    spatial padding; ``w_p``/``bias_p`` must be padded to the same channel
+    blocks.  ``scale_p`` (1, Op) selects the int8 dequant path (see
+    kernel.py).  The stride-phase split and the row/column tails of the
+    row-tiled grid are intra-layer movement and stay here; the channel
+    crop belongs to the caller (public wrapper or network executor).
     """
     toh, bc, bo = blocks
+    toh = min(toh, oh)
     sh, sw = spec.stride
-    return conv2d_im2col_gemm_pallas(
-        x_p, w_p, sh, sw, oh, ow, min(toh, oh), bc, bo,
+    out = conv2d_im2col_gemm_pallas(
+        phase_split(x_p, spec, oh, ow, toh), w_p, sh, sw, oh,
+        ceil_to(ow, V5E.sublanes), toh, bc, bo,
         out_dtype=out_dtype, interpret=interpret,
         bias=bias_p, activation=activation, scale=scale_p,
     )
+    if out.shape[1:3] != (oh, ow):
+        out = out[:, :oh, :ow]
+    return out
 
 
 @functools.partial(
@@ -162,23 +150,26 @@ def conv2d_pallas_im2col(
     ``bias`` (O,) and ``activation`` form the fused epilogue, applied inside
     the kernel's output stage (see kernel.py).  ``scale`` (O,) selects the
     int8 dequant path: int8 x/w, int32 accumulation, fp32 output."""
-    b, h, ww, c = x.shape
+    _, h, ww, c = x.shape
     kh, kw, _, o = w.shape
-    ph, pw = spec.padding
     oh, ow = spec.out_hw(h, ww)
+    sh, sw = spec.stride
 
     blocks = blocks or pick_blocks(
-        h + 2 * ph, ww + 2 * pw, c, o, oh, ow, jnp.dtype(x.dtype).itemsize,
-        kh=kh, kw=kw,
+        oh, ow, c, o, kh, kw, sh, sw, jnp.dtype(x.dtype).itemsize,
     )
-    x_p, w_p, bias_p = pad_conv_operands(x, w, spec, blocks, bias=bias)
-    scale_p = pad_bias_row(scale, w_p.shape[-1])
+    _, bc, bo = blocks
+    cp, op = ceil_to(c, bc), ceil_to(o, bo)
+    if cp != c:
+        x = jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, cp - c)))
+    w_p = jnp.pad(w, ((0, 0), (0, 0), (0, cp - c), (0, op - o)))
     out = conv2d_im2col_padded_call(
-        x_p, w_p, spec, oh, ow, blocks,
+        x, w_p, spec, oh, ow, blocks,
         out_dtype=out_dtype, interpret=interpret,
-        bias_p=bias_p, activation=activation, scale_p=scale_p,
+        bias_p=pad_bias_row(bias, op), activation=activation,
+        scale_p=pad_bias_row(scale, op),
     )
-    return out[:, :oh, :, :o]
+    return out[..., :o]
 
 
 def im2col_call_descriptor(
@@ -191,28 +182,30 @@ def im2col_call_descriptor(
     bc multiple, against weights padded to (cp, op).
 
     The verifier's expected side: kernel body name, grid, modeled VMEM
-    footprint (``vmem_model.im2col_kernel_vmem_bytes``) and the modeled HBM
-    traffic from the block/grid fetch algebra — the input slab and weight
-    block re-fetch on every grid step (their index maps touch the innermost
-    in-channel axis), the epilogue rows once per (batch, row, out-channel)
-    step, the output once per block.
+    footprint (``vmem_model.im2col_kernel_vmem_bytes``, buffers only — the
+    compiler-internal allowance is not visible in a trace) and the modeled
+    HBM traffic from the block/grid fetch algebra — the row window and
+    weight block re-fetch on every grid step (their index maps touch the
+    innermost in-channel axis), the epilogue rows once per (batch, row,
+    out-channel) step, the output once per block.
     """
     oh, ow = spec.out_hw(h, w)
-    ph, pw = spec.padding
+    sh, sw = spec.stride
     toh, bc, bo = blocks
     eff_toh = min(toh, oh)
-    ohp, need_h, need_w = padded_input_hw(h, w, spec, eff_toh)
-    hp = max(need_h, h + ph)      # leading pad ph, trailing max(need-h-ph, 0)
-    wp = max(need_w, w + pw)
+    ohp = ceil_to(oh, eff_toh)
+    owp, rows_in, wq = im2col_window(eff_toh, ow, spec.kh, spec.kw, sh, sw)
     grid = (batch, ohp // eff_toh, op // bo, cp // bc)
     nsteps = batch * (ohp // eff_toh) * (op // bo)
     full = nsteps * (cp // bc)
     rows = int(scale) + int(bias)
     out_bytes = ACC_BYTES if dtype_bytes == 1 else dtype_bytes
     traffic = (
-        dtype_bytes * full * (hp * wp * bc + spec.kh * spec.kw * bc * bo)
+        dtype_bytes * full * (
+            sh * rows_in * sw * wq * bc + spec.kh * spec.kw * bc * bo
+        )
         + ACC_BYTES * rows * nsteps * bo          # epilogue rows
-        + out_bytes * nsteps * eff_toh * ow * bo  # output blocks
+        + out_bytes * nsteps * eff_toh * owp * bo  # output blocks
     )
     name = (
         "_conv" + ("_q8" if scale else "") + ("_bias" if bias else "")
@@ -223,8 +216,8 @@ def im2col_call_descriptor(
         "name": name,
         "grid": grid,
         "model_vmem_bytes": im2col_kernel_vmem_bytes(
-            hp, wp, eff_toh, ow, bc, bo, spec.kh, spec.kw, dtype_bytes,
-            bias=bias or scale,
+            eff_toh, ow, bc, bo, spec.kh, spec.kw, sh, sw, dtype_bytes,
+            bias=bias or scale, internal=False,
         ),
         "traffic_bytes": traffic,
         "vmem_one_sided": False,

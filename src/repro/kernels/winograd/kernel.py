@@ -1,10 +1,16 @@
 """Winograd F(6x6,3x3) Pallas kernels with inter-tile channel parallelism.
 
 TPU realization of the paper's §IV.B scheme.  The paper packs one 8x8 tile
-from each of VL/16 channels along the vector register; here every transform
-operand keeps a trailing (tiles, channels) block so the 128-lane axis is
-filled by channels and the 8 sublanes by tiles — the same inter-tile
-parallelization, expressed through BlockSpec tiling instead of `svcntw`.
+from each of VL/16 channels along the vector register; here every operand
+is position-major with a trailing (tiles, channels) block — (8, 8, T, C) in,
+(6, 6, T, O) out — so the 128-lane axis is filled by channels and the 8
+sublanes by tiles: the same inter-tile parallelization, expressed through
+BlockSpec tiling instead of `svcntw`.
+
+Every transform is a sum over whole (tiles, channels) planes with the
+constant B^T / A^T coefficients baked in at trace time, i.e. vector FMAs
+with no relayout, and every tuple multiply is one 2-D MXU matmul per
+transform position — the only matmul form Mosaic lowers.
 
 Two realizations of the same pipeline:
 
@@ -34,18 +40,56 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.conv_spec import apply_activation
-from repro.kernels.compat import CompilerParams
-
 from repro.core.winograd import AT, BT
+from repro.kernels import mxu_precision
+
+_BT = tuple(tuple(float(c) for c in row) for row in BT)
+_AT = tuple(tuple(float(c) for c in row) for row in AT)
 
 
-def _input_transform_kernel(bt_ref, d_ref, v_ref):
-    """d (bt, 8, 8, bc) -> V (8, 8, bt, bc): channels stay minormost."""
-    bt_mat = bt_ref[...]
-    d = d_ref[...].astype(jnp.float32)
-    # V[a,b,t,c] = sum_ij BT[a,i] d[t,i,j,c] BT[b,j]
-    v = jnp.einsum("ai,bj,tijc->abtc", bt_mat, bt_mat, d)
-    v_ref[...] = v.astype(v_ref.dtype)
+def _combine(coeffs, load):
+    """sum_k coeffs[k] * load(k) over the nonzero (static) coefficients."""
+    acc = None
+    for k, c in enumerate(coeffs):
+        if c == 0.0:
+            continue
+        t = load(k)
+        t = t if c == 1.0 else (-t if c == -1.0 else c * t)
+        acc = t if acc is None else acc + t
+    return acc
+
+
+def _input_rows(d_ref, a):
+    """Row pass of V = B^T d B for one position row ``a``: the 8 planes
+    sum_i BT[a, i] d[i, j], each (bt, bc) fp32."""
+    return [
+        _combine(_BT[a], lambda i, j=j: d_ref[i, j].astype(jnp.float32))
+        for j in range(8)
+    ]
+
+
+def _output_tiles(m_ref, bias_ref, activation):
+    """Y = A^T M A on an (8, 8, bt, bo) fp32 ref, as 36 (x, y) planes with
+    the bias + activation epilogue applied."""
+    bias = None if bias_ref is None else bias_ref[...].astype(jnp.float32)
+    for x in range(6):
+        rows = [
+            _combine(_AT[x], lambda a, b=b: m_ref[a, b].astype(jnp.float32))
+            for b in range(8)
+        ]
+        for y in range(6):
+            out = _combine(_AT[y], lambda b: rows[b])
+            if bias is not None:
+                out = out + bias
+            yield x, y, apply_activation(out, activation)
+
+
+def _input_transform_kernel(d_ref, v_ref):
+    """d (8, 8, bt, bc) -> V (8, 8, bt, bc)."""
+    for a in range(8):
+        rows = _input_rows(d_ref, a)
+        for b in range(8):
+            v_ref[a, b] = _combine(_BT[b], lambda j: rows[j]).astype(v_ref.dtype)
 
 
 def _tuple_multiply_kernel(v_ref, u_ref, m_ref, acc_ref):
@@ -56,7 +100,8 @@ def _tuple_multiply_kernel(v_ref, u_ref, m_ref, acc_ref):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(
-        v_ref[0], u_ref[0], preferred_element_type=jnp.float32
+        v_ref[0], u_ref[0], preferred_element_type=jnp.float32,
+        precision=mxu_precision(v_ref.dtype),
     )
 
     @pl.when(pl.program_id(3) == pl.num_programs(3) - 1)
@@ -64,84 +109,70 @@ def _tuple_multiply_kernel(v_ref, u_ref, m_ref, acc_ref):
         m_ref[...] = acc_ref[...].astype(m_ref.dtype)[None]
 
 
-def _output_transform_kernel(at_ref, m_ref, y_ref, *, activation: str = "linear"):
-    """M (8, 8, bt, bo) -> Y (bt, 6, 6, bo)."""
-    at_mat = at_ref[...]
-    m = m_ref[...].astype(jnp.float32)
-    y = jnp.einsum("xa,yb,abto->txyo", at_mat, at_mat, m)
-    y_ref[...] = apply_activation(y, activation).astype(y_ref.dtype)
+def _output_transform_kernel(m_ref, y_ref, *, activation: str = "linear"):
+    """M (8, 8, bt, bo) -> Y (6, 6, bt, bo)."""
+    for x, y, out in _output_tiles(m_ref, None, activation):
+        y_ref[x, y] = out.astype(y_ref.dtype)
 
 
-def _output_transform_bias_kernel(at_ref, m_ref, bias_ref, y_ref, *,
-                                  activation: str):
+def _output_transform_bias_kernel(m_ref, bias_ref, y_ref, *, activation: str):
     """Output transform with the fused epilogue: bias (1, bo) + activation
     applied to the fp32 transform result before the store."""
-    at_mat = at_ref[...]
-    m = m_ref[...].astype(jnp.float32)
-    y = jnp.einsum("xa,yb,abto->txyo", at_mat, at_mat, m)
-    y = y + bias_ref[...].astype(jnp.float32)
-    y_ref[...] = apply_activation(y, activation).astype(y_ref.dtype)
+    for x, y, out in _output_tiles(m_ref, bias_ref, activation):
+        y_ref[x, y] = out.astype(y_ref.dtype)
 
 
-def _fused_accumulate(cstep, bt_ref, d_ref, u_ref, acc_ref):
+def _fused_accumulate(cstep, d_ref, u_ref, acc_ref):
     """Shared megakernel reduction step: V in registers, M into scratch."""
 
     @pl.when(cstep == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    bt_mat = bt_ref[...]
-    d = d_ref[...].astype(jnp.float32)
-    # V[a,b,t,c] = sum_ij BT[a,i] d[t,i,j,c] BT[b,j]   (never stored to HBM)
-    v = jnp.einsum("ai,bj,tijc->abtc", bt_mat, bt_mat, d)
-    u = u_ref[...].astype(jnp.float32)
-    # 64 per-position GEMMs, batched over (a, b): M[a,b] += V[a,b] @ U[a,b].
-    acc_ref[...] += jax.lax.dot_general(
-        v, u,
-        dimension_numbers=(((3,), (2,)), ((0, 1), (0, 1))),
-        preferred_element_type=jnp.float32,
-    )
+    precision = mxu_precision(jnp.float32)
+    for a in range(8):
+        rows = _input_rows(d_ref, a)
+        for b in range(8):
+            # V[a, b] (never stored to HBM) @ U[a, b]: one 2-D MXU matmul.
+            v = _combine(_BT[b], lambda j: rows[j])
+            acc_ref[a, b] += jnp.dot(
+                v, u_ref[a, b].astype(jnp.float32),
+                preferred_element_type=jnp.float32, precision=precision,
+            )
 
 
-def _fused_inverse_transform(at_ref, acc_ref):
-    """Shared megakernel finish: Y = A^T M A on the fp32 accumulator."""
-    at_mat = at_ref[...]
-    return jnp.einsum("xa,yb,abto->txyo", at_mat, at_mat, acc_ref[...])
-
-
-def _fused_winograd_kernel(bt_ref, at_ref, d_ref, u_ref, y_ref, acc_ref, *,
+def _fused_winograd_kernel(d_ref, u_ref, y_ref, acc_ref, *,
                            activation: str = "linear"):
     """Single-pass megakernel body: transform, tuple-GEMM, inverse transform.
 
     Grid (T/bt, O/bo, C/bc) with Cin innermost (the reduction axis).  The M
     accumulator scratch (8, 8, bt, bo) fp32 persists across the Cin steps;
-    V exists only as a register-resident einsum result.
+    V exists only as per-position register values.
     """
     cstep = pl.program_id(2)
-    _fused_accumulate(cstep, bt_ref, d_ref, u_ref, acc_ref)
+    _fused_accumulate(cstep, d_ref, u_ref, acc_ref)
 
     @pl.when(cstep == pl.num_programs(2) - 1)
     def _done():
-        y = _fused_inverse_transform(at_ref, acc_ref)
-        y_ref[...] = apply_activation(y, activation).astype(y_ref.dtype)
+        for x, y, out in _output_tiles(acc_ref, None, activation):
+            y_ref[x, y] = out.astype(y_ref.dtype)
 
 
-def _fused_winograd_bias_kernel(bt_ref, at_ref, d_ref, u_ref, bias_ref,
-                                y_ref, acc_ref, *, activation: str):
+def _fused_winograd_bias_kernel(d_ref, u_ref, bias_ref, y_ref, acc_ref, *,
+                                activation: str):
     """Fused megakernel with the bias (1, bo) + activation epilogue applied
     to the fp32 inverse-transform result before the store."""
     cstep = pl.program_id(2)
-    _fused_accumulate(cstep, bt_ref, d_ref, u_ref, acc_ref)
+    _fused_accumulate(cstep, d_ref, u_ref, acc_ref)
 
     @pl.when(cstep == pl.num_programs(2) - 1)
     def _done():
-        y = _fused_inverse_transform(at_ref, acc_ref)
-        y = y + bias_ref[...].astype(jnp.float32)
-        y_ref[...] = apply_activation(y, activation).astype(y_ref.dtype)
+        for x, y, out in _output_tiles(acc_ref, bias_ref, activation):
+            y_ref[x, y] = out.astype(y_ref.dtype)
 
 
 def fused_winograd_pallas(
-    tiles: jnp.ndarray,  # (T, 8, 8, C)
+    tiles: jnp.ndarray,  # (8, 8, T, C)
     u: jnp.ndarray,      # (8, 8, C, O) pre-transformed weights
     bt: int,
     bc: int,
@@ -150,24 +181,21 @@ def fused_winograd_pallas(
     bias=None,           # (1, O) or None
     activation: str = "linear",
 ) -> jnp.ndarray:
-    """(T, 8, 8, C) x (8, 8, C, O) -> (T, 6, 6, O) in one pallas_call.
+    """(8, 8, T, C) x (8, 8, C, O) -> (6, 6, T, O) in one pallas_call.
 
     T % bt == 0, C % bc == 0, O % bo == 0 (ops.py pads).  Cin is the
     innermost ('arbitrary') grid axis so the per-(tile, out-channel) block's
     M accumulator survives in scratch between reduction steps; the tile and
     weight blocks stream through VMEM double-buffered.
     """
-    t, _, _, c = tiles.shape
+    _, _, t, c = tiles.shape
     o = u.shape[-1]
     assert bias is None or bias.shape == (1, o), (o, getattr(bias, "shape", None))
     in_specs = [
-        pl.BlockSpec((8, 8), lambda i, j, k: (0, 0)),
-        pl.BlockSpec((6, 8), lambda i, j, k: (0, 0)),
-        pl.BlockSpec((bt, 8, 8, bc), lambda i, j, k: (i, 0, 0, k)),
+        pl.BlockSpec((8, 8, bt, bc), lambda i, j, k: (0, 0, i, k)),
         pl.BlockSpec((8, 8, bc, bo), lambda i, j, k: (0, 0, k, j)),
     ]
-    inputs = [jnp.asarray(BT, jnp.float32), jnp.asarray(AT, jnp.float32),
-              tiles, u]
+    inputs = [tiles, u]
     if bias is not None:
         kernel = functools.partial(
             _fused_winograd_bias_kernel, activation=activation
@@ -182,10 +210,10 @@ def fused_winograd_pallas(
         kernel,
         grid=(t // bt, o // bo, c // bc),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((bt, 6, 6, bo), lambda i, j, k: (i, 0, 0, j)),
-        out_shape=jax.ShapeDtypeStruct((t, 6, 6, o), tiles.dtype),
+        out_specs=pl.BlockSpec((6, 6, bt, bo), lambda i, j, k: (0, 0, i, j)),
+        out_shape=jax.ShapeDtypeStruct((6, 6, t, o), tiles.dtype),
         scratch_shapes=[pltpu.VMEM((8, 8, bt, bo), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -195,22 +223,19 @@ def fused_winograd_pallas(
 def input_transform_pallas(
     tiles: jnp.ndarray, bt: int, bc: int, interpret: bool = False
 ) -> jnp.ndarray:
-    """(T, 8, 8, C) -> (8, 8, T, C); T % bt == 0, C % bc == 0."""
-    t, _, _, c = tiles.shape
+    """(8, 8, T, C) -> (8, 8, T, C); T % bt == 0, C % bc == 0."""
+    _, _, t, c = tiles.shape
     return pl.pallas_call(
         _input_transform_kernel,
         grid=(t // bt, c // bc),
-        in_specs=[
-            pl.BlockSpec((8, 8), lambda i, j: (0, 0)),
-            pl.BlockSpec((bt, 8, 8, bc), lambda i, j: (i, 0, 0, j)),
-        ],
+        in_specs=[pl.BlockSpec((8, 8, bt, bc), lambda i, j: (0, 0, i, j))],
         out_specs=pl.BlockSpec((8, 8, bt, bc), lambda i, j: (0, 0, i, j)),
         out_shape=jax.ShapeDtypeStruct((8, 8, t, c), tiles.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")
         ),
         interpret=interpret,
-    )(jnp.asarray(BT, jnp.float32), tiles)
+    )(tiles)
 
 
 def tuple_multiply_pallas(
@@ -234,7 +259,7 @@ def tuple_multiply_pallas(
         out_specs=pl.BlockSpec((1, bt, bo), lambda pp, i, j, k: (pp, i, j)),
         out_shape=jax.ShapeDtypeStruct((p, t, o), v.dtype),
         scratch_shapes=[pltpu.VMEM((bt, bo), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -245,14 +270,11 @@ def output_transform_pallas(
     m: jnp.ndarray, bt: int, bo: int, interpret: bool = False,
     bias=None, activation: str = "linear",
 ) -> jnp.ndarray:
-    """(8, 8, T, O) -> (T, 6, 6, O), with an optional fused bias (1, O) +
+    """(8, 8, T, O) -> (6, 6, T, O), with an optional fused bias (1, O) +
     activation epilogue applied to the fp32 transform output."""
     _, _, t, o = m.shape
     assert bias is None or bias.shape == (1, o), (o, getattr(bias, "shape", None))
-    in_specs = [
-        pl.BlockSpec((6, 8), lambda i, j: (0, 0)),
-        pl.BlockSpec((8, 8, bt, bo), lambda i, j: (0, 0, i, j)),
-    ]
+    in_specs = [pl.BlockSpec((8, 8, bt, bo), lambda i, j: (0, 0, i, j))]
     if bias is not None:
         kernel = functools.partial(
             _output_transform_bias_kernel, activation=activation
@@ -266,10 +288,10 @@ def output_transform_pallas(
         kernel,
         grid=(t // bt, o // bo),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((bt, 6, 6, bo), lambda i, j: (i, 0, 0, j)),
-        out_shape=jax.ShapeDtypeStruct((t, 6, 6, o), m.dtype),
-        compiler_params=CompilerParams(
+        out_specs=pl.BlockSpec((6, 6, bt, bo), lambda i, j: (0, 0, i, j)),
+        out_shape=jax.ShapeDtypeStruct((6, 6, t, o), m.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")
         ),
         interpret=interpret,
-    )(jnp.asarray(AT, jnp.float32), m, *(() if bias is None else (bias,)))
+    )(m, *(() if bias is None else (bias,)))

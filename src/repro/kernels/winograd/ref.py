@@ -7,9 +7,9 @@ from repro.core.winograd import AT, BT, conv2d_winograd
 
 
 def input_transform_ref(tiles: jnp.ndarray) -> jnp.ndarray:
-    """(T, 8, 8, C) -> (8, 8, T, C)."""
+    """(8, 8, T, C) -> (8, 8, T, C)."""
     bt = jnp.asarray(BT, tiles.dtype)
-    return jnp.einsum("ai,bj,tijc->abtc", bt, bt, tiles)
+    return jnp.einsum("ai,bj,ijtc->abtc", bt, bt, tiles)
 
 
 def tuple_multiply_ref(v: jnp.ndarray, u: jnp.ndarray) -> jnp.ndarray:
@@ -18,9 +18,9 @@ def tuple_multiply_ref(v: jnp.ndarray, u: jnp.ndarray) -> jnp.ndarray:
 
 
 def output_transform_ref(m: jnp.ndarray) -> jnp.ndarray:
-    """(8, 8, T, O) -> (T, 6, 6, O)."""
+    """(8, 8, T, O) -> (6, 6, T, O)."""
     at = jnp.asarray(AT, m.dtype)
-    return jnp.einsum("xa,yb,abto->txyo", at, at, m)
+    return jnp.einsum("xa,yb,abto->xyto", at, at, m)
 
 
 winograd_conv_ref = conv2d_winograd

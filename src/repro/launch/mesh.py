@@ -29,8 +29,6 @@ def make_stage_mesh(n_stages: int, devices=None):
     """A 1-D ('stage',) mesh for layer-pipelined execution.
 
     Takes the first ``n_stages`` of ``devices`` (default: all visible).
-    Built from an explicit device array — no ``axis_types`` — so it works
-    on jax versions without ``jax.sharding.AxisType``.
     """
     import numpy as np
 

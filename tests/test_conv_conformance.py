@@ -213,32 +213,30 @@ def test_im2col_pick_blocks_budgets_full_footprint():
     from repro.core.vmem_model import im2col_kernel_vmem_bytes
     from repro.kernels.im2col_gemm.ops import pick_blocks
 
-    for hp, wp, c, o, oh, ow in (
-        (18, 18, 512, 1024, 16, 16),      # deep layer: weight block dominates
-        (226, 226, 64, 64, 224, 224),     # shallow layer: slab dominates
-        (34, 34, 384, 768, 32, 32),
+    for oh, ow, c, o in (
+        (16, 16, 512, 1024),      # deep layer: weight block dominates
+        (224, 224, 64, 64),       # shallow layer: row window dominates
+        (32, 32, 384, 768),
     ):
         for budget in (1 << 20, 3 << 20, 8 << 20, 64 << 20):
-            toh, bc, bo = pick_blocks(
-                hp, wp, c, o, oh, ow, vmem_budget=budget
-            )
-            assert toh >= 1 and bc % 8 == 0 and bo % 128 == 0
-            footprint = im2col_kernel_vmem_bytes(hp, wp, toh, ow, bc, bo)
+            toh, bc, bo = pick_blocks(oh, ow, c, o, vmem_budget=budget)
+            assert toh >= 1 and bc % 128 == 0 and bo % 128 == 0
+            footprint = im2col_kernel_vmem_bytes(toh, ow, bc, bo)
             # Either the full footprint fits, or every knob is at its floor.
-            assert footprint <= budget or (toh, bc, bo) == (1, 8, 128), (
-                (hp, wp, c, o), budget, (toh, bc, bo), footprint
+            assert footprint <= budget or (toh, bc, bo) == (1, 128, 128), (
+                (oh, ow, c, o), budget, (toh, bc, bo), footprint
             )
 
     # The confirmed gap: a config where the old heuristic (input slab +
     # accumulator only) accepts blocks whose *full* footprint overflows.
     budget = 3 << 20
-    toh, bc, bo = pick_blocks(18, 18, 512, 1024, 16, 16, vmem_budget=budget)
-    assert im2col_kernel_vmem_bytes(18, 18, toh, 16, bc, bo) <= budget
+    toh, bc, bo = pick_blocks(16, 16, 512, 1024, vmem_budget=budget)
+    assert im2col_kernel_vmem_bytes(toh, 16, bc, bo) <= budget
     old_slab_only = (
         2 * 18 * 18 * 128 * 4 <= 2 * budget // 3     # old bc check passes
         and 16 * 16 * 256 * 4 <= budget // 3         # old toh check passes
     )
-    overflow = im2col_kernel_vmem_bytes(18, 18, 16, 16, 128, 256) > budget
+    overflow = im2col_kernel_vmem_bytes(16, 16, 128, 256) > budget
     assert old_slab_only and overflow, (
         "test setup: the old heuristic should overflow here"
     )

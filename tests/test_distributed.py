@@ -2,18 +2,7 @@
 pipeline parallelism, and compressed all-reduce (subprocess, forced devices)."""
 import json
 
-import jax
-import pytest
-
 from conftest import run_with_devices
-
-# The multi-device subprocess tests build meshes with explicit axis_types;
-# jax.sharding.AxisType arrived after 0.4.x — skip (not fail) on older jax.
-requires_axis_type = pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="jax.sharding.AxisType not available on this jax "
-           f"({jax.__version__}); needs jax >= 0.5",
-)
 from repro.distributed.ft import (
     ElasticPlanner,
     FailureDetector,
@@ -66,7 +55,6 @@ def test_elastic_planner_no_failures():
 # Multi-device behavior (subprocess with forced host devices)
 
 
-@requires_axis_type
 def test_sharding_rules_on_real_mesh():
     out = run_with_devices(8, """
         import jax, jax.numpy as jnp, json
@@ -96,7 +84,6 @@ def test_sharding_rules_on_real_mesh():
     assert emb and "'model'" in emb[0]
 
 
-@requires_axis_type
 def test_sharded_train_step_runs_and_matches_single_device():
     """The same train step on a (2,2) mesh and on 1 device gives the same
     loss (SPMD correctness end-to-end)."""
@@ -142,7 +129,6 @@ def test_sharded_train_step_runs_and_matches_single_device():
     assert abs(l1 - l2) / max(abs(l1), 1e-9) < 2e-2, (l1, l2)
 
 
-@requires_axis_type
 def test_pipeline_parallelism_matches_serial():
     out = run_with_devices(4, """
         import jax, jax.numpy as jnp, numpy as np
@@ -167,12 +153,10 @@ def test_pipeline_parallelism_matches_serial():
     assert err < 1e-5
 
 
-@requires_axis_type
 def test_compressed_allreduce_and_convergence():
     out = run_with_devices(4, """
         import jax, jax.numpy as jnp, numpy as np
         from functools import partial
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.distributed.compression import (
             compressed_allreduce_mean, compression_ratio)
@@ -182,7 +166,7 @@ def test_compressed_allreduce_and_convergence():
         rng = np.random.default_rng(0)
         g = jnp.asarray(rng.normal(size=(4, 64)), jnp.float32)  # per-dev rows
 
-        @partial(shard_map, mesh=mesh, in_specs=(P("dp"), P("dp")),
+        @partial(jax.shard_map, mesh=mesh, in_specs=(P("dp"), P("dp")),
                  out_specs=(P("dp"), P("dp")))
         def cavg(grad, err):
             m, e = compressed_allreduce_mean(grad[0], err[0], "dp")
@@ -214,7 +198,6 @@ def test_compressed_allreduce_and_convergence():
     assert vals["DIST"] < 0.2          # EF-compressed training converges
 
 
-@requires_axis_type
 def test_zero_spec_adds_dp_axis():
     out = run_with_devices(8, """
         import jax

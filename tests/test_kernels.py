@@ -96,7 +96,7 @@ def test_im2col_gemm_explicit_blocks():
 
 
 def test_winograd_input_transform_kernel():
-    tiles = _rand((16, 8, 8, 8), 21)
+    tiles = _rand((8, 8, 16, 8), 21)
     got = input_transform_pallas(tiles, bt=8, bc=8, interpret=True)
     np.testing.assert_allclose(got, input_transform_ref(tiles), rtol=1e-4,
                                atol=1e-4)

@@ -210,7 +210,7 @@ def test_winograd_plan_records_fused_megakernel(tmp_path):
     assert replan == plan and replan.winograd_fused
 
     data = json.load(open(cache))
-    assert data["version"] == 6   # v6: pipelines section (+v5 per-plan dtype)
+    assert data["version"] == 7   # v7: tile-padded VMEM model (+v6 pipelines)
     (record,) = data["plans"].values()
     assert record["winograd_fused"] is True
 

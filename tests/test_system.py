@@ -1,10 +1,14 @@
 """End-to-end behaviour tests for the full system."""
 import json
 import os
+import subprocess
+import sys
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro import configs
 from repro.configs.base import ShapeSpec
@@ -109,3 +113,40 @@ def test_chunked_vocab_loss_matches_full():
     cfg2 = dataclasses.replace(cfg, loss_vocab_chunk=8)
     l2, _ = loss_fn(cfg2, params, batch)
     assert abs(float(l1) - float(l2)) < 1e-3
+
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def test_chip_smoke_refuses_to_run_without_a_tpu(tmp_path):
+    """chip_smoke.py has no CPU or interpret-mode fallback: off-TPU it exits
+    non-zero, names the missing TPU, and prints no result line."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py")],
+        capture_output=True, text=True, timeout=300, env=env, cwd=tmp_path,
+    )
+    assert proc.returncode != 0, proc.stdout
+    assert "no TPU" in proc.stderr, proc.stderr[-2000:]
+    assert '"ok"' not in proc.stdout
+
+
+def test_chip_table_is_keyed_by_device_kind():
+    """A run reports against the peaks of its own chip; an unknown kind or
+    a non-TPU device is an error, never a default."""
+    from repro.hw import V5E, chip_for
+
+    assert chip_for(SimpleNamespace(platform="tpu",
+                                    device_kind="TPU v5 lite")) is V5E
+    with pytest.raises(RuntimeError, match="unknown TPU kind"):
+        chip_for(SimpleNamespace(platform="tpu", device_kind="TPU v0"))
+    with pytest.raises(RuntimeError, match="no TPU"):
+        chip_for(jax.devices("cpu")[0])
+
+
+def test_compile_cache_default_lives_in_the_checkout():
+    """Without JAX_COMPILATION_CACHE_DIR the compile cache sits at a fixed
+    path inside the checkout (the path is part of the cache key)."""
+    from repro.launch.compile_cache import DEFAULT_DIR
+
+    assert DEFAULT_DIR == os.path.join(REPO, ".cache", "jax_compile")
