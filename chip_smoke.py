@@ -244,6 +244,9 @@ def main() -> None:
                          "executors on four chips")
     args = ap.parse_args()
 
+    # Otherwise the TPU library writes its logs to a fixed directory outside
+    # the checkout.
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
     import jax
 
     from repro.hw import chip_for

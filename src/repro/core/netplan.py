@@ -59,8 +59,7 @@ from repro.core.conv_spec import (
     select_algorithm,
 )
 from repro.core.planner import ConvPlan, Planner
-from repro.kernels import mxu_precision
-from repro.util import ceil_to
+from repro.util import ceil_to, dot_precision
 
 
 # ---------------------------------------------------------------------------
@@ -1141,7 +1140,7 @@ def run_network(
             if cur.ndim == 4:
                 cur = cur.mean(axis=(1, 2))
             cur = apply_activation(
-                jnp.dot(cur, p["w"], precision=mxu_precision(cur.dtype))
+                jnp.dot(cur, p["w"], precision=dot_precision(cur.dtype))
                 + p["b"], l.activation,
             )
         outputs.append(cur)

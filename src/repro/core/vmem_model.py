@@ -83,13 +83,17 @@ def tiled_bytes(shape: Tuple[int, ...], dtype_bytes: int = 4) -> int:
 #: Mosaic's internal scratch, fitted (as an upper bound) against the
 #: compiler's scoped-VMEM requirement on v5e — the least
 #: ``vmem_limit_bytes`` at which each kernel compiles, bisected to 64 KiB
-#: (tests/test_tpu_compile.py checks the bound).  A fixed allowance per
-#: kernel plus fp32 planes of the matmul-row x lane shape: the im2col patch
-#: and matmul result, and the Winograd transforms' eight row planes plus
-#: the per-position V and product.
+#: (tests/test_tpu_compile.py checks the bound on fixed layers and on every
+#: kernel the planner picks for VGG-16, YOLOv3-tiny and YOLOv3-20).  A
+#: fixed allowance per kernel plus fp32 planes of the matmul-row x lane
+#: shape: the im2col patch and matmul result, and the Winograd transforms'
+#: row planes, V and products.  The fused Winograd kernel's need depends on
+#: its grid as well as its blocks — at (32, 128, 128) it ranges from
+#: 9.06 MiB to 13.0 MiB (one Cin step, several Cout steps) — and its planes
+#: cover the largest.
 INTERNAL_BYTES = 256 * 1024
 IM2COL_INTERNAL_PLANES = 4
-WINOGRAD_INTERNAL_PLANES = 12
+WINOGRAD_INTERNAL_PLANES = 24
 
 
 @dataclasses.dataclass(frozen=True)

@@ -19,11 +19,11 @@ from __future__ import annotations
 
 from typing import Optional, Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.conv_spec import ConvSpec, Epilogue, apply_epilogue
+from repro.util import dot_precision
 
 TILE = 8          # input tile (paper's default 8x8)
 OUT_TILE = 6      # output tile of F(6,3)
@@ -85,10 +85,9 @@ def transform_weights(w: jnp.ndarray, dtype=jnp.float32) -> jnp.ndarray:
       (8, 8, Cin, Cout) transformed weights.
     """
     g = jnp.asarray(G, dtype)
-    # U[a,b,c,o] = sum_{i,j} G[a,i] w[i,j,c,o] G[b,j], at full fp32 precision
-    # (a TPU's default would round G's fractional entries to bf16).
+    # U[a,b,c,o] = sum_{i,j} G[a,i] w[i,j,c,o] G[b,j]
     return jnp.einsum("ai,bj,ijco->abco", g, g, w.astype(dtype),
-                      precision=jax.lax.Precision.HIGHEST)
+                      precision=dot_precision(dtype))
 
 
 def _tile_input(x: jnp.ndarray, oh: int, ow: int) -> Tuple[jnp.ndarray, int, int]:

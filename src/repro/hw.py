@@ -15,8 +15,8 @@ import dataclasses
 class ChipSpec:
     name: str = "tpu_v5e"
     peak_flops_bf16: float = 197e12      # FLOP/s per chip (given)
-    peak_flops_fp32: float = 98.5e12     # MXU fp32 ~ half of bf16
-    peak_flops_int8: float = 394e12      # int8 MAC rate ~ 2x bf16
+    peak_flops_fp32: float = 98.5e12     # assumed 1/2 bf16 (no published figure)
+    peak_flops_int8: float = 394e12      # assumed 2x bf16 (published: 393e12)
     hbm_bandwidth: float = 819e9         # B/s per chip (given)
     hbm_bytes: int = 16 * 1024**3        # 16 GiB HBM
     ici_link_bandwidth: float = 50e9     # B/s per link (given)
@@ -31,8 +31,10 @@ class ChipSpec:
 V5E = ChipSpec()
 
 #: Chips a device run may report against, keyed by ``jax.Device.device_kind``.
-#: Peaks: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 393 TOP/s
-#: int8, 16 GB HBM at 819 GB/s).
+#: Published for v5e (Google Cloud, "TPU v5e"): 197 TFLOP/s bf16, 393 TOP/s
+#: int8, 16 GB HBM at 819 GB/s.  The fp32 and int8 peaks in ``ChipSpec`` are
+#: the cost model's ratios to bf16, not those figures: use them to rank
+#: plans, never as a roofline a chip measurement is reported against.
 CHIPS = {"TPU v5 lite": V5E}
 
 

@@ -7,16 +7,3 @@ Each has ops.py (jitted wrapper) and ref.py (pure-jnp oracle); all are
 validated in interpret mode on CPU and AOT-compiled for the v5e
 (tests/test_tpu_compile.py).
 """
-
-
-def mxu_precision(dtype):
-    """The dot precision a kernel's matmul asks Mosaic for.
-
-    float32 operands get ``HIGHEST`` so the MXU contraction keeps fp32
-    accuracy instead of the compiler's default reduced-precision passes;
-    narrower operands (bf16, int8) contract natively and pass ``None``.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    return jax.lax.Precision.HIGHEST if jnp.dtype(dtype) == jnp.float32 else None

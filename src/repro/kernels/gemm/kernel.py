@@ -25,7 +25,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.conv_spec import apply_activation
-from repro.kernels import mxu_precision
+from repro.util import dot_precision
 
 
 def _accumulate_k_block(a_ref, b_ref, acc_ref):
@@ -38,7 +38,7 @@ def _accumulate_k_block(a_ref, b_ref, acc_ref):
 
     acc_ref[...] += jnp.dot(
         a_ref[...], b_ref[...], preferred_element_type=jnp.float32,
-        precision=mxu_precision(a_ref.dtype),
+        precision=dot_precision(a_ref.dtype),
     )
 
 
@@ -67,14 +67,14 @@ def _matmul_bias_kernel_6loop(a_ref, b_ref, bias_ref, c_ref, acc_ref, *,
 def _matmul_kernel_3loop(a_ref, b_ref, c_ref, *, activation: str):
     """Grid (nm, nn): one full-K panel per output block (paper Fig. 2)."""
     out = jnp.dot(a_ref[...], b_ref[...], preferred_element_type=jnp.float32,
-                  precision=mxu_precision(a_ref.dtype))
+                  precision=dot_precision(a_ref.dtype))
     c_ref[...] = apply_activation(out, activation).astype(c_ref.dtype)
 
 
 def _matmul_bias_kernel_3loop(a_ref, b_ref, bias_ref, c_ref, *, activation: str):
     """3-loop variant with a fused bias + activation epilogue."""
     out = jnp.dot(a_ref[...], b_ref[...], preferred_element_type=jnp.float32,
-                  precision=mxu_precision(a_ref.dtype))
+                  precision=dot_precision(a_ref.dtype))
     out = out + bias_ref[...].astype(jnp.float32)
     c_ref[...] = apply_activation(out, activation).astype(c_ref.dtype)
 

@@ -31,7 +31,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.conv_spec import apply_activation
-from repro.kernels import mxu_precision
+from repro.util import dot_precision
 
 
 def _accumulate_taps(x_ref, w_ref, acc_ref, *, kh, kw, sh, sw, toh, ow):
@@ -48,7 +48,7 @@ def _accumulate_taps(x_ref, w_ref, acc_ref, *, kh, kw, sh, sw, toh, ow):
 
     bc = x_ref.shape[-1]
     wq = x_ref.shape[-2] // sw
-    precision = mxu_precision(x_ref.dtype)
+    precision = dot_precision(x_ref.dtype)
     for di in range(kh):
         for dj in range(kw):
             patch = x_ref[

@@ -41,7 +41,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.conv_spec import apply_activation
 from repro.core.winograd import AT, BT
-from repro.kernels import mxu_precision
+from repro.util import dot_precision
 
 _BT = tuple(tuple(float(c) for c in row) for row in BT)
 _AT = tuple(tuple(float(c) for c in row) for row in AT)
@@ -101,7 +101,7 @@ def _tuple_multiply_kernel(v_ref, u_ref, m_ref, acc_ref):
 
     acc_ref[...] += jnp.dot(
         v_ref[0], u_ref[0], preferred_element_type=jnp.float32,
-        precision=mxu_precision(v_ref.dtype),
+        precision=dot_precision(v_ref.dtype),
     )
 
     @pl.when(pl.program_id(3) == pl.num_programs(3) - 1)
@@ -129,7 +129,7 @@ def _fused_accumulate(cstep, d_ref, u_ref, acc_ref):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    precision = mxu_precision(jnp.float32)
+    precision = dot_precision(jnp.float32)
     for a in range(8):
         rows = _input_rows(d_ref, a)
         for b in range(8):

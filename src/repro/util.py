@@ -23,3 +23,18 @@ def pad_bias_row(bias, n_padded: int):
     n = bias.shape[0]
     return (jnp.pad(bias, (0, n_padded - n)) if n_padded != n
             else bias).reshape(1, n_padded)
+
+
+def dot_precision(dtype):
+    """The matmul precision for operands of ``dtype``, in the Pallas kernels
+    and the XLA ops around them alike.
+
+    float32 gets ``HIGHEST``: a TPU's default contracts fp32 in bf16
+    passes, which on a v5e put planned VGG-16's logits 7.3e-3 (relative L2)
+    from the fp32 reference, and the Winograd kernels alone 4.8e-2 from a
+    float64 conv.  Narrower types (bf16, int8) contract natively: ``None``.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    return jax.lax.Precision.HIGHEST if jnp.dtype(dtype) == jnp.float32 else None

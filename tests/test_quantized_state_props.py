@@ -50,8 +50,9 @@ def _round_trip_bound(x: np.ndarray) -> None:
 # ---------------------------------------------------------------------------
 # Hypothesis properties.
 
+# Bounds must be exactly representable at width 32.
 finite_f32 = st.floats(
-    min_value=-1e30, max_value=1e30,
+    min_value=float(np.float32(-1e30)), max_value=float(np.float32(1e30)),
     allow_nan=False, allow_infinity=False, width=32,
 )
 

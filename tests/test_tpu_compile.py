@@ -5,26 +5,38 @@ No chip is needed: the TPU compiler is installed and compiles for a
 interpret mode cannot — block shapes Mosaic refuses (unaligned lane dims,
 matmuls with two batch dims) and kernels that overrun scoped VMEM.
 
-Every case compiles with ``vmem_limit_bytes`` set to the footprint
-``core/vmem_model`` predicts for its blocks, so a pass also proves the model
-covers what the compiler allocates: the planner budgets with that model and
-can no longer pick blocks the compiler refuses.
+Every compile sets ``vmem_limit_bytes`` to the footprint ``core/vmem_model``
+predicts for the kernel's blocks, so a pass also proves the model covers
+what the compiler allocates.  Two sets of kernels are compiled: fixed cases,
+each a layer the compiler once refused, and every distinct kernel call the
+planner picks for the benchmark's networks (VGG-16@224 batch 8,
+YOLOv3-tiny@416 and YOLOv3-20@608 batch 1; fp32 and int8).
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library at a time, and the test workers all
 import this file.
 """
 import functools
+import os
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.conv_spec import ConvSpec
+import repro
+from repro.configs import vgg16, yolov3
+from repro.core.conv_spec import ConvAlgorithm, ConvSpec
+from repro.core.netplan import (
+    plan_network,
+    prepare_net_params,
+    pretransform_flags,
+    run_network,
+)
 from repro.core.vmem_model import (
     gemm_kernel_vmem_bytes,
     im2col_kernel_vmem_bytes,
+    itemsize,
     winograd_kernel_vmem_bytes,
 )
 from repro.hw import V5E
@@ -35,6 +47,9 @@ from repro.util import ceil_to
 def topo():
     from jax.experimental import topologies
 
+    # Otherwise loading the TPU library writes its logs to a fixed directory
+    # outside the checkout.
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
         return topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2"
@@ -75,15 +90,15 @@ def _im2col_case(b, hw, cin, cout, stride, dtype, cin_phys=None):
 
     spec = ConvSpec(cin, cout, stride=(stride, stride))
     oh, ow = spec.out_hw(hw, hw)
-    itemsize = jnp.dtype(dtype).itemsize
+    nbytes = jnp.dtype(dtype).itemsize
     cp = cin_phys or ceil_to(cin, V5E.lane_width)
     toh, bc, bo = blocks = pick_blocks(
-        oh, ow, cp, cout, 3, 3, stride, stride, itemsize
+        oh, ow, cp, cout, 3, 3, stride, stride, nbytes
     )
     op = ceil_to(cout, bo)
     q8 = dtype == jnp.int8
     model = im2col_kernel_vmem_bytes(
-        min(toh, oh), ow, bc, bo, 3, 3, stride, stride, itemsize
+        min(toh, oh), ow, bc, bo, 3, 3, stride, stride, nbytes
     )
 
     def fn(x, w, row):
@@ -114,11 +129,30 @@ def _winograd_case(b, hw, cin, cout):
     return fn, args, winograd_kernel_vmem_bytes(bt, bc, bo)
 
 
+def _winograd_3pass_case(b, hw, cin, cout):
+    """Input transform, tuple multiply and output transform (the 3-pass
+    pipeline) in one program; each kernel's own footprint is at most the
+    modeled pipeline maximum."""
+    from repro.kernels.winograd.ops import conv2d_winograd_padded_call, pick_blocks
+
+    t = b * (-(-hw // 6)) ** 2
+    bt, bc, bo = blocks = pick_blocks(t, cin, cout, fused=False)
+    cp, op = ceil_to(cin, bc), ceil_to(cout, bo)
+
+    def fn(x_sp, u, bias):
+        return conv2d_winograd_padded_call(x_sp, u, hw, hw, blocks, bias_p=bias,
+                                           activation="relu", fused=False)
+
+    args = [((b, hw + 2, hw + 2, cp), jnp.float32),
+            ((8, 8, cp, op), jnp.float32), ((1, op), jnp.float32)]
+    return fn, args, winograd_kernel_vmem_bytes(bt, bc, bo, fused=False)
+
+
 def _gemm_case(m, k, n, dtype):
     from repro.kernels.gemm.ops import default_block, matmul_padded_call
 
-    itemsize = jnp.dtype(dtype).itemsize
-    cfg = default_block(m, n, k, itemsize)
+    nbytes = jnp.dtype(dtype).itemsize
+    cfg = default_block(m, n, k, nbytes)
     block = (cfg.bm, cfg.bn, cfg.bk)
     mp, kp, np_ = ceil_to(m, cfg.bm), ceil_to(k, cfg.bk), ceil_to(n, cfg.bn)
     q8 = dtype == jnp.int8
@@ -129,7 +163,7 @@ def _gemm_case(m, k, n, dtype):
 
     args = [((mp, kp), dtype), ((kp, np_), dtype), ((1, np_), jnp.float32)]
     model = gemm_kernel_vmem_bytes(
-        cfg.bm, cfg.bn, cfg.bk, itemsize, epilogue_rows=2 if q8 else 1
+        cfg.bm, cfg.bn, cfg.bk, nbytes, epilogue_rows=2 if q8 else 1
     )
     return fn, args, model
 
@@ -137,6 +171,8 @@ def _gemm_case(m, k, n, dtype):
 CASES = {
     # VGG-16's first 3x3 layer plans onto the fused megakernel.
     "winograd_fused_224x64-64": lambda: _winograd_case(8, 224, 64, 64),
+    # The 3-pass pipeline the planner can pin instead, at the same layer.
+    "winograd_3pass_224x64-64": lambda: _winograd_3pass_case(8, 224, 64, 64),
     # im2col at VGG-16 widths, batch 8, fp32 and int8.
     **{
         f"im2col_{jnp.dtype(dt).name}_{hw}x{ci}-{co}": functools.partial(
@@ -158,8 +194,8 @@ CASES = {
 
 #: The least ``vmem_limit_bytes`` at which each case compiled for v5e
 #: (bisected to 64 KiB with ``compile_with_limit``; jax 0.9.0, libtpu
-#: 0.0.34).  Each layer here was refused before the VMEM model counted tile
-#: padding and Mosaic's internal scratch.
+#: 0.0.34).  Each layer here but the 3-pass one was refused before the VMEM
+#: model counted tile padding and Mosaic's internal scratch.
 COMPILER_MIN_VMEM = {
     "gemm_float32_8x25088-4096": 8716288,
     "gemm_int8_8x4096-1000": 4325376,
@@ -170,6 +206,7 @@ COMPILER_MIN_VMEM = {
     "im2col_int8_224x3-64": 7340032,
     "im2col_int8_56x256-256": 6094848,
     "im2col_yolov3_20_l1_608s2_32-64": 10551296,
+    "winograd_3pass_224x64-64": 8650752,
     "winograd_fused_224x64-64": 9502720,
 }
 
@@ -186,9 +223,17 @@ def test_vmem_model_covers_compiler_requirement(case):
 
 def compile_with_limit(fn, args, sharding, vmem_limit_bytes):
     """AOT-compile ``fn`` on described-device shapes with
-    ``vmem_limit_bytes`` set in every kernel's compiler params."""
+    ``vmem_limit_bytes`` set in every kernel's compiler params.  ``args``
+    are (shape, dtype) pairs or pytrees of ``jax.ShapeDtypeStruct``."""
     cls = pltpu.CompilerParams
-    shapes = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in args]
+    shapes = [
+        jax.ShapeDtypeStruct(a[0], a[1], sharding=sharding)
+        if isinstance(a, tuple) else jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+            a,
+        )
+        for a in args
+    ]
     try:
         pltpu.CompilerParams = functools.partial(
             cls, vmem_limit_bytes=int(vmem_limit_bytes)
@@ -207,3 +252,96 @@ def test_kernel_compiles_for_v5e_within_modeled_vmem(
     assert model <= V5E.vmem_bytes, (case, model)
     compiled = compile_with_limit(fn, args, one_chip, vmem_limit_bytes=model)
     assert "tpu_custom_call" in compiled.as_text(), case
+
+
+# ---------------------------------------------------------------------------
+# Every kernel call the planner picks for the benchmark's networks.
+
+CELLS = {
+    f"{name}_{dtype}": (model, batch, dtype)
+    for name, model, batch in (
+        ("vgg16@224_b8", vgg16.MODEL, 8),
+        ("yolov3-tiny@416_b1", yolov3.TINY_MODEL, 1),
+        ("yolov3-20@608_b1", yolov3.MODEL_20, 1),
+    )
+    for dtype in ("float32", "int8")
+}
+
+
+def _modeled_vmem(step) -> int:
+    """The footprint ``core/vmem_model`` gives the kernel a planned conv step
+    runs, at the step's blocks and dtype (the bias, and int8's scale, rows
+    included)."""
+    plan, spec = step.plan, step.spec
+    d = itemsize(plan.dtype)
+    if plan.algorithm is ConvAlgorithm.WINOGRAD:
+        return winograd_kernel_vmem_bytes(*plan.kernel_blocks,
+                                          fused=plan.winograd_fused,
+                                          dtype_bytes=d)
+    if plan.algorithm is ConvAlgorithm.DIRECT:
+        bm, bn, bk = plan.kernel_blocks
+        return gemm_kernel_vmem_bytes(bm, bn, bk, d,
+                                      epilogue_rows=2 if d == 1 else 1)
+    toh, bc, bo = plan.kernel_blocks
+    oh, ow = step.out_hw
+    return im2col_kernel_vmem_bytes(min(toh, oh), ow, bc, bo, spec.kh,
+                                    spec.kw, *spec.stride, d)
+
+
+def _planned_calls(model, batch, dtype):
+    """{key: (step index, fn, args, modeled bytes)} for each distinct conv
+    kernel call of the planned network.  Params and the int8 calibration
+    batch are abstract, so nothing runs on the host."""
+    opts = repro.ExecutionOptions(impl="pallas", interpret=False, batch=batch,
+                                  dtype=dtype, cache_path=None)
+    netplan = plan_network(model.layers, *model.input_hw, opts.make_planner(),
+                           in_channels=model.in_channels, batch=batch,
+                           dtype=dtype)
+    x = jax.ShapeDtypeStruct((batch, *model.input_hw, model.in_channels),
+                             jnp.float32)
+    params = jax.eval_shape(
+        lambda c: prepare_net_params(
+            netplan, model.init_params(jax.random.PRNGKey(0)),
+            pretransform=True, calibration=c,
+        ), x,
+    )
+    flags = pretransform_flags(netplan, True)
+    calls = {}
+    for s in netplan.steps:
+        if s.plan is None:
+            continue
+        p = params[s.index]
+        xs = jax.ShapeDtypeStruct((batch, *s.in_hw, s.in_layout.phys_c),
+                                  jnp.float32)
+        key = (s.plan.algorithm.value, s.plan.winograd_fused, s.plan.dtype,
+               s.spec.kernel_size, s.spec.stride, xs.shape,
+               tuple(p["w"].shape), tuple(s.plan.kernel_blocks),
+               s.out_layout.phys_c)
+        if key in calls:
+            continue
+        fn = functools.partial(run_network, netplan, interpret=False,
+                               pretransformed=flags, start=s.index,
+                               stop=s.index + 1)
+        calls[key] = (s.index, lambda pp, xx, fn=fn: fn([pp], xx), [p, xs],
+                      _modeled_vmem(s))
+    return calls
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_planned_kernels_compile_for_v5e_within_modeled_vmem(
+    cell, one_chip, no_compile_cache
+):
+    calls = _planned_calls(*CELLS[cell])
+    assert calls, cell
+    refused = []
+    for key, (index, fn, args, model) in calls.items():
+        assert model <= V5E.vmem_bytes, (cell, index, key, model)
+        try:
+            compiled = compile_with_limit(fn, args, one_chip, model)
+        except Exception as e:  # noqa: BLE001 - collected and reported below
+            refused.append(f"layer {index} {key} at {model} B: "
+                           f"{str(e).splitlines()[0][:300]}")
+            continue
+        assert "tpu_custom_call" in compiled.as_text(), (cell, index, key)
+    assert not refused, f"{cell}: refused within the modeled VMEM:\n" + \
+        "\n".join(refused)
