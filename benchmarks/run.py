@@ -2,7 +2,6 @@
 
 Prints ``name,us_per_call,derived`` CSV rows (see benchmarks/common.emit).
 Sections:
-  breakdown          paper §II.B   (GEMM share of inference time)
   table2_blocksizes  paper Table II (BLIS block tuning, VMEM model)
   table3_veclen      paper Fig 6    (vector-length scaling)
   fig_cache_sweep    paper Figs 7-10 (cache x veclen co-design, both algos)
@@ -10,7 +9,6 @@ Sections:
   winograd_vs_im2col paper §VII     (2.4x / 1.35x / 1.5x claims)
   e2e_cnn            paper Figs 9-10 (planned end-to-end network; small
                      resolution here — full runs via benchmarks.e2e_cnn)
-  lm_roofline        beyond-paper   (assigned-arch dry-run roofline table)
 """
 from __future__ import annotations
 
@@ -20,10 +18,8 @@ import traceback
 
 def main() -> None:
     from benchmarks import (
-        breakdown,
         e2e_cnn,
         fig_cache_sweep,
-        lm_roofline,
         table2_blocksizes,
         table3_veclen,
         table4_ai,
@@ -31,7 +27,6 @@ def main() -> None:
     )
 
     sections = [
-        ("breakdown", breakdown.run),
         ("table2_blocksizes", table2_blocksizes.run),
         ("table3_veclen", table3_veclen.run),
         ("fig_cache_sweep", fig_cache_sweep.run),
@@ -39,7 +34,6 @@ def main() -> None:
         ("winograd_vs_im2col", winograd_vs_im2col.run),
         ("e2e_cnn", lambda: e2e_cnn.run(model="vgg16", input_hw=(64, 64),
                                         reps=1)),
-        ("lm_roofline", lm_roofline.run),
     ]
     failures = 0
     for name, fn in sections:

@@ -27,6 +27,7 @@ from typing import Any, Dict, Optional, Sequence, Tuple
 
 from repro.api.model import CNNModel, as_model, is_lm_config
 from repro.api.options import ExecutionOptions
+from repro.spans import span
 
 SAVE_FORMAT = "repro.api/1"
 
@@ -322,19 +323,28 @@ class CompiledCNN(CompiledModel):
     # -- the four verbs -------------------------------------------------------
 
     def run(self, x):
-        """Jitted whole-network inference on an (B, H, W, C) batch."""
+        """Jitted whole-network inference on an (B, H, W, C) batch.
+
+        Records host spans (``repro.spans``): ``run`` and, inside it,
+        ``run.asarray`` (the input cast), ``run.executor`` (the executor
+        lookup and ``save_plans``) and ``run.call`` (the executor call, up
+        to the jitted dispatch)."""
         import jax.numpy as jnp
 
-        # input_dtype, not dtype: under int8 the batch stays fp32 and is
-        # quantized per layer inside the executor.
-        x = jnp.asarray(x, _jnp_dtype(self.options.input_dtype))
-        if x.ndim != 4:
-            raise ValueError(
-                f"run() expects (B, H, W, C), got shape {tuple(x.shape)}"
-            )
-        executor = self._executor_for(int(x.shape[0]))
-        self.save_plans()       # no-op unless this batch tuned new plans
-        return executor(x)
+        with span("run"):
+            # input_dtype, not dtype: under int8 the batch stays fp32 and
+            # is quantized per layer inside the executor.
+            with span("run.asarray"):
+                x = jnp.asarray(x, _jnp_dtype(self.options.input_dtype))
+            if x.ndim != 4:
+                raise ValueError(
+                    f"run() expects (B, H, W, C), got shape {tuple(x.shape)}"
+                )
+            with span("run.executor"):
+                executor = self._executor_for(int(x.shape[0]))
+                self.save_plans()   # no-op unless this batch tuned new plans
+            with span("run.call"):
+                return executor(x)
 
     def serve(self, buckets: Optional[Tuple[int, ...]] = None, **kw):
         """A CNNServingEngine over this compilation's bucket ladder.

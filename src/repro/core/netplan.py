@@ -46,11 +46,13 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
+import json
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from repro import spans
 from repro.core.conv_spec import (
     ConvAlgorithm,
     ConvSpec,
@@ -1025,6 +1027,53 @@ def _align_channels(x: jnp.ndarray, want_phys: int) -> jnp.ndarray:
     return x[..., :want_phys]
 
 
+#: The named scope of the single channel crop at network exit.
+EXIT_SCOPE = "exit"
+
+
+def layer_scope(step: NetStep) -> str:
+    """The named scope that holds a planned layer's ops in ``run_network``:
+    ``L{index:03d}.{kind}``, with the resolved algorithm for convs
+    (``L000.conv.winograd``, ``L018.conv.im2col_gemm``, ``L002.maxpool``).
+    Indices are absolute, so a pipeline stage names its layers as the whole
+    network does.  XLA keeps the scope in each instruction's ``op_name``, so
+    a profiler trace puts every device op down to its layer."""
+    name = f"L{step.index:03d}.{step.layer.kind}"
+    if step.layer.kind == "conv":
+        algo = resolve_algorithm(step.spec, step.plan, *step.in_hw)
+        name = f"{name}.{algo.value}"
+    return name
+
+
+def layer_table(netplan: NetworkPlan) -> Dict[str, Any]:
+    """What a trace reader needs to put the forward's device ops down to
+    planned layers: per step its scope, index, kind, algorithm and the
+    plan's ``predicted_s``, under the forward's jitted name; and ``input``,
+    the forward's input argument, whose name XLA gives the copy that lays
+    the input out for the first layer.
+
+    JAX's persistent compilation cache keys a program without its metadata,
+    so an executable compiled before a scope was renamed would load with the
+    old names.  The name therefore carries a digest of the scopes (and of
+    the batch and input size), which the key does include."""
+    layers = []
+    for s in netplan.steps:
+        scope = layer_scope(s)
+        layers.append({
+            "scope": scope,
+            "index": s.index,
+            "kind": s.layer.kind,
+            "algorithm": (scope.split(".", 2)[2]
+                          if s.layer.kind == "conv" else None),
+            "predicted_s": s.plan.predicted_s if s.plan is not None else None,
+        })
+    key = json.dumps([[l["scope"] for l in layers], EXIT_SCOPE,
+                      netplan.batch, list(netplan.input_hw)])
+    digest = hashlib.sha256(key.encode()).hexdigest()[:8]
+    return {"name": f"fwd_{digest}", "input": "xx", "batch": netplan.batch,
+            "input_hw": list(netplan.input_hw), "layers": layers}
+
+
 def run_network(
     netplan: NetworkPlan,
     params: Sequence[Dict],
@@ -1067,86 +1116,91 @@ def run_network(
     outputs: List[jnp.ndarray] = []
     cur = x
     for s in netplan.steps[start:stop]:
-        l = s.layer
-        if l.kind == "conv":
-            p = params[s.index - start]
-            cur = _align_channels(cur, s.in_layout.phys_c)
-            quantized = "w_scale" in p
-            if quantized:
-                # int8 step (prepare_net_params quantized it offline): the
-                # activation re-quantizes at entry with the static
-                # calibrated scales, the kernel accumulates int8 x int8 in
-                # int32, and the fused epilogue dequantizes via w_scale —
-                # inter-layer activations stay fp32.
-                from repro.core.quant import quantize_activation
+        with jax.named_scope(layer_scope(s)):
+            l = s.layer
+            if l.kind == "conv":
+                p = params[s.index - start]
+                cur = _align_channels(cur, s.in_layout.phys_c)
+                quantized = "w_scale" in p
+                if quantized:
+                    # int8 step (prepare_net_params quantized it offline): the
+                    # activation re-quantizes at entry with the static
+                    # calibrated scales, the kernel accumulates int8 x int8 in
+                    # int32, and the fused epilogue dequantizes via w_scale —
+                    # inter-layer activations stay fp32.
+                    from repro.core.quant import quantize_activation
 
-                cur = quantize_activation(cur, p["x_scale"])
-                epi = Epilogue(bias=p["b"], activation=l.activation,
-                               scale=p["w_scale"])
-            else:
-                epi = Epilogue(bias=p["b"], activation=l.activation)
-            eff_impl = s.plan.impl if s.plan is not None else netplan.impl
-            if pretransformed is not None:
-                pre = bool(pretransformed[s.index])
-            else:                           # legacy guard, not a sniff: a
-                pre = (                     # 3x3 spec can't have raw (8,8)
-                    s.spec.kernel_size == (3, 3)
-                    and p["w"].ndim == 4
-                    and p["w"].shape[0] == 8
-                    and p["w"].shape[1] == 8
+                    cur = quantize_activation(cur, p["x_scale"])
+                    epi = Epilogue(bias=p["b"], activation=l.activation,
+                                   scale=p["w_scale"])
+                else:
+                    epi = Epilogue(bias=p["b"], activation=l.activation)
+                eff_impl = s.plan.impl if s.plan is not None else netplan.impl
+                if pretransformed is not None:
+                    pre = bool(pretransformed[s.index])
+                else:                           # legacy guard, not a sniff: a
+                    pre = (                     # 3x3 spec can't have raw (8,8)
+                        s.spec.kernel_size == (3, 3)
+                        and p["w"].ndim == 4
+                        and p["w"].shape[0] == 8
+                        and p["w"].shape[1] == 8
+                    )
+                if s.plan is not None and eff_impl == "pallas":
+                    # The executor owns the boundary: channels arrive block-
+                    # padded per in_layout, the crop defers per out_layout.
+                    cur = conv2d(
+                        cur, p["w"], s.spec, impl=eff_impl,
+                        interpret=interpret, plan=s.plan, epilogue=epi,
+                        in_layout=s.in_layout, out_layout=s.out_layout,
+                        pretransformed=pre,
+                    )
+                elif quantized:
+                    # Pure-jnp int8 reference: the same integer products in
+                    # fp32 (exact for int8 operands; accumulated rounding is
+                    # orders below the quantization noise), dequantized by the
+                    # shared epilogue.
+                    cur = conv2d(
+                        cur.astype(jnp.float32), p["w"].astype(jnp.float32),
+                        s.spec, impl=eff_impl, interpret=interpret,
+                        plan=s.plan, epilogue=epi, pretransformed=pre,
+                    )
+                else:
+                    cur = conv2d(
+                        cur, p["w"], s.spec, impl=eff_impl,
+                        interpret=interpret, plan=s.plan, epilogue=epi,
+                        pretransformed=pre,
+                    )
+            elif l.kind == "maxpool":
+                cur = jax.lax.reduce_window(
+                    cur, -jnp.inf, jax.lax.max,
+                    (1, l.size, l.size, 1),
+                    (1, l.stride, l.stride, 1), "SAME",
                 )
-            if s.plan is not None and eff_impl == "pallas":
-                # The executor owns the boundary: channels arrive block-
-                # padded per in_layout, the crop defers per out_layout.
-                cur = conv2d(
-                    cur, p["w"], s.spec, impl=eff_impl, interpret=interpret,
-                    plan=s.plan, epilogue=epi,
-                    in_layout=s.in_layout, out_layout=s.out_layout,
-                    pretransformed=pre,
-                )
-            elif quantized:
-                # Pure-jnp int8 reference: the same integer products in
-                # fp32 (exact for int8 operands; accumulated rounding is
-                # orders below the quantization noise), dequantized by the
-                # shared epilogue.
-                cur = conv2d(
-                    cur.astype(jnp.float32), p["w"].astype(jnp.float32),
-                    s.spec, impl=eff_impl, interpret=interpret,
-                    plan=s.plan, epilogue=epi, pretransformed=pre,
-                )
-            else:
-                cur = conv2d(
-                    cur, p["w"], s.spec, impl=eff_impl, interpret=interpret,
-                    plan=s.plan, epilogue=epi, pretransformed=pre,
-                )
-        elif l.kind == "maxpool":
-            cur = jax.lax.reduce_window(
-                cur, -jnp.inf, jax.lax.max,
-                (1, l.size, l.size, 1),
-                (1, l.stride, l.stride, 1), "SAME",
-            )
-        elif l.kind == "avgpool":
-            cur = cur.mean(axis=(1, 2))
-        elif l.kind == "upsample":
-            cur = jnp.repeat(jnp.repeat(cur, l.size, axis=1), l.size, axis=2)
-        elif l.kind == "shortcut":
-            cur = cur + outputs[l.from_layers[0] - start]
-        elif l.kind == "route":
-            cur = jnp.concatenate(
-                [outputs[j - start] for j in l.from_layers], axis=-1
-            )
-        elif l.kind == "fc":
-            p = params[s.index - start]
-            if cur.ndim == 4:
+            elif l.kind == "avgpool":
                 cur = cur.mean(axis=(1, 2))
-            cur = apply_activation(
-                jnp.dot(cur, p["w"], precision=dot_precision(cur.dtype))
-                + p["b"], l.activation,
-            )
-        outputs.append(cur)
+            elif l.kind == "upsample":
+                cur = jnp.repeat(
+                    jnp.repeat(cur, l.size, axis=1), l.size, axis=2
+                )
+            elif l.kind == "shortcut":
+                cur = cur + outputs[l.from_layers[0] - start]
+            elif l.kind == "route":
+                cur = jnp.concatenate(
+                    [outputs[j - start] for j in l.from_layers], axis=-1
+                )
+            elif l.kind == "fc":
+                p = params[s.index - start]
+                if cur.ndim == 4:
+                    cur = cur.mean(axis=(1, 2))
+                cur = apply_activation(
+                    jnp.dot(cur, p["w"], precision=dot_precision(cur.dtype))
+                    + p["b"], l.activation,
+                )
+            outputs.append(cur)
     exit_layout = netplan.exit_layout
     if stop == n_steps and exit_layout.pad_c:
-        cur = cur[..., :exit_layout.c]      # the single crop at network exit
+        with jax.named_scope(EXIT_SCOPE):
+            cur = cur[..., :exit_layout.c]  # the single crop at network exit
     return cur
 
 
@@ -1266,10 +1320,14 @@ class NetworkExecutor:
             devices = jax.devices()
         self.mesh = None
         self._placed = None
+        table = layer_table(netplan)
+        spans.RECORD.register(table)
 
-        def fwd(prms, xx):
+        def fwd(prms, xx):                 # xx: the table's "input"
             return run_network(netplan, prms, xx, interpret=interpret,
                                pretransformed=self.pretransformed)
+
+        fwd.__name__ = fwd.__qualname__ = table["name"]
 
         if len(devices) > 1 and netplan.batch % len(devices) == 0:
             import numpy as np
