@@ -73,11 +73,10 @@ def step_descriptors(
     if algo is ConvAlgorithm.WINOGRAD:
         from repro.kernels.winograd.ops import winograd_call_descriptors
 
-        bt, bc, bo = blocks
-        t = b * -(-oh // 6) * -(-ow // 6)
         descs = winograd_call_descriptors(
-            t, cp, ceil_to(o_phys, bo), blocks,
+            b, oh, ow, cp, ceil_to(o_phys, blocks[2]), blocks,
             bias=True, fused=bool(plan.winograd_fused), dtype_bytes=d,
+            vmem_budget=netplan.vmem_budget,
         )
         return [dict(x, step=step.index) for x in descs]
 
@@ -146,6 +145,7 @@ def reference_netplan(netplan: NetworkPlan) -> NetworkPlan:
         plans=[s.plan for s in netplan.steps],
         impl=netplan.impl,
         dtype=netplan.dtype_name,
+        vmem_budget=netplan.vmem_budget,
     )
 
 

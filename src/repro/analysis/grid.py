@@ -25,7 +25,9 @@ on:
   ``index_map x block_shape`` window is checked at every grid corner
   against the (padded) operand bounds; non-affine maps fall back to full
   grid enumeration when the grid is small enough, else the claim is
-  reported unprovable (a warning, never a silent pass).
+  reported unprovable (a warning, never a silent pass).  An output block
+  may overhang the end of a blocked dim if it starts inside it: Pallas's
+  partial edge block, whose writeback drops the overhang.
 
 - **Guard recovery** (``ref_accesses``): ``pl.when(pl.program_id(a) == s)``
   traces to a ``cond`` whose predicate chains back through
@@ -209,6 +211,13 @@ def window_violations(
 ) -> Tuple[List[WindowViolation], bool]:
     """(violations, proved) for one operand's block windows over the grid.
 
+    A window must lie inside the operand, except that an output's block in
+    a blocked (not element-indexed) dim may run past the end when it starts
+    before it — the partial edge block, written back clipped.  Its overhang
+    is then under one block; a block that starts at or past the end is
+    flagged.  Whether the blocks together cover the whole output is not
+    checked here: the kernels' tests against the reference show it.
+
     Affine maps are checked at the grid corners only — exact, because each
     window-start coordinate is affine in the program ids and so attains its
     extremes at box corners.  Non-affine maps enumerate the full grid when
@@ -225,6 +234,7 @@ def window_violations(
         points = list(itertools.product(*[range(int(g)) for g in grid]))
         evaluate = lambda pt: eval_index_map(op.index_map_jaxpr, pt)  # noqa: E731
     element = tuple(op.element_dims) or (False,) * len(op.block_shape)
+    clipped = op.kind == "out"
     violations: List[WindowViolation] = []
     for pt in points:
         idx = evaluate(pt)
@@ -232,7 +242,10 @@ def window_violations(
             zip(idx, op.block_shape, op.array_shape, element)
         ):
             start = int(i) if elem else int(i) * int(bs)
-            if start < 0 or start + int(bs) > int(n):
+            overhang = start + int(bs) - int(n)
+            partial_edge = clipped and not elem and 0 < overhang < int(bs)
+            if start < 0 or start >= int(n) or (overhang > 0
+                                                and not partial_edge):
                 violations.append(WindowViolation(
                     point=tuple(pt), dim=d,
                     start=start, stop=start + int(bs), extent=int(n),

@@ -40,6 +40,7 @@ def conv2d(
     in_layout: Optional["Layout"] = None,
     out_layout: Optional["Layout"] = None,
     pretransformed: bool = False,
+    vmem_budget: Optional[int] = None,
 ) -> jnp.ndarray:
     """Convolve ``x`` (B,H,W,C) with ``w`` (kh,kw,C,O) per ``spec``.
 
@@ -63,7 +64,13 @@ def conv2d(
     contract — it is never inferred from weight shapes, because the old
     sniff (``w.shape[0] != spec.kh``) was ambiguous for any kh == 8 kernel,
     whose raw weights are (8, 8, C, O) too.
+
+    ``vmem_budget`` is the VMEM budget the plan was made under (None: the
+    ``planner``'s, else the chip's); kernels that size blocks the plan does
+    not carry (the fused Winograd kernel's NHWC windows) keep within it.
     """
+    if vmem_budget is None and planner is not None:
+        vmem_budget = planner.vmem_budget
     if plan is None and planner is not None:
         plan = planner.plan(
             spec, x.shape[1], x.shape[2], batch=x.shape[0], dtype=x.dtype
@@ -84,7 +91,7 @@ def conv2d(
         return conv_ops.conv2d_pallas(
             x, w, spec, algo, interpret=interpret, plan=plan,
             epilogue=epilogue, in_layout=in_layout, out_layout=out_layout,
-            pretransformed=pretransformed,
+            pretransformed=pretransformed, vmem_budget=vmem_budget,
         )
     if (in_layout is not None and in_layout.pad_c) or (
         out_layout is not None and out_layout.pad_c

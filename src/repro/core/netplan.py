@@ -126,6 +126,10 @@ class NetworkPlan:
     batch: int
     impl: str
     dtype_name: str
+    # The planner's VMEM budget (None: the chip's), for the blocks a kernel
+    # sizes at run time: the fused Winograd kernel's NHWC windows.
+    vmem_budget: Optional[int] = dataclasses.field(default=None,
+                                                   compare=False)
 
     @property
     def layers(self) -> Tuple[Any, ...]:
@@ -258,6 +262,7 @@ def build_network_plan(
     impl: str = "jax",
     dtype: Any = "float32",
     snap_rows: bool = True,
+    vmem_budget: Optional[int] = None,
 ) -> NetworkPlan:
     """Pure layout resolution: layer table + per-layer plans -> NetworkPlan.
 
@@ -375,6 +380,7 @@ def build_network_plan(
         batch=batch,
         impl=impl,
         dtype_name=dtype_name,
+        vmem_budget=vmem_budget,
     )
 
 
@@ -437,7 +443,8 @@ def plan_network(
             pass                            # corrupt entry -> replan
         else:
             planner.network_hits += 1       # counted only once validated
-            return netplan
+            return dataclasses.replace(netplan,
+                                       vmem_budget=planner.vmem_budget)
     plans: List[Optional[ConvPlan]] = [
         (planner.plan(info["spec"], info["in"][0], info["in"][1],
                       batch=batch, dtype=dtype)
@@ -447,7 +454,7 @@ def plan_network(
     ]
     netplan = build_network_plan(
         layers, h, w, in_channels=in_channels, batch=batch, plans=plans,
-        impl=planner.impl, dtype=dtype,
+        impl=planner.impl, dtype=dtype, vmem_budget=planner.vmem_budget,
     )
     planner.put_network_entry(key, _entry_from_netplan(netplan))
     return netplan
@@ -1045,12 +1052,33 @@ def layer_scope(step: NetStep) -> str:
     return name
 
 
-def layer_table(netplan: NetworkPlan) -> Dict[str, Any]:
+def winograd_step_tiling(netplan: NetworkPlan, step: NetStep,
+                         shards: int = 1):
+    """The ``WinogradTiling`` a planned Pallas Winograd step runs with when
+    the batch is split over ``shards`` devices (each kernel sees
+    batch / shards images), or None for any other step."""
+    if (step.layer.kind != "conv" or step.plan is None
+            or step.plan.impl != "pallas"
+            or resolve_algorithm(step.spec, step.plan, *step.in_hw)
+            is not ConvAlgorithm.WINOGRAD):
+        return None
+    from repro.kernels.winograd.ops import winograd_tiling
+
+    return winograd_tiling(
+        netplan.batch // shards, *step.out_hw, tuple(step.plan.kernel_blocks),
+        fused=step.plan.winograd_fused, vmem_budget=netplan.vmem_budget,
+    )
+
+
+def layer_table(netplan: NetworkPlan, shards: int = 1) -> Dict[str, Any]:
     """What a trace reader needs to put the forward's device ops down to
     planned layers: per step its scope, index, kind, algorithm and the
     plan's ``predicted_s``, under the forward's jitted name; and ``input``,
     the forward's input argument, whose name XLA gives the copy that lays
-    the input out for the first layer.
+    the input out for the first layer.  A Pallas Winograd step also says
+    where its tiles are cut (``tiling``: ``"vmem"`` or ``"hbm"``) and
+    ``tile_ratio``, the tiles its kernel computes over the real B*nTH*nTW
+    (None on other steps), at the batch of one of ``shards`` devices.
 
     JAX's persistent compilation cache keys a program without its metadata,
     so an executable compiled before a scope was renamed would load with the
@@ -1059,6 +1087,7 @@ def layer_table(netplan: NetworkPlan) -> Dict[str, Any]:
     layers = []
     for s in netplan.steps:
         scope = layer_scope(s)
+        tiling = winograd_step_tiling(netplan, s, shards)
         layers.append({
             "scope": scope,
             "index": s.index,
@@ -1066,6 +1095,8 @@ def layer_table(netplan: NetworkPlan) -> Dict[str, Any]:
             "algorithm": (scope.split(".", 2)[2]
                           if s.layer.kind == "conv" else None),
             "predicted_s": s.plan.predicted_s if s.plan is not None else None,
+            "tiling": tiling.name if tiling is not None else None,
+            "tile_ratio": tiling.ratio if tiling is not None else None,
         })
     key = json.dumps([[l["scope"] for l in layers], EXIT_SCOPE,
                       netplan.batch, list(netplan.input_hw)])
@@ -1152,7 +1183,7 @@ def run_network(
                         cur, p["w"], s.spec, impl=eff_impl,
                         interpret=interpret, plan=s.plan, epilogue=epi,
                         in_layout=s.in_layout, out_layout=s.out_layout,
-                        pretransformed=pre,
+                        pretransformed=pre, vmem_budget=netplan.vmem_budget,
                     )
                 elif quantized:
                     # Pure-jnp int8 reference: the same integer products in
@@ -1320,7 +1351,9 @@ class NetworkExecutor:
             devices = jax.devices()
         self.mesh = None
         self._placed = None
-        table = layer_table(netplan)
+        shards = (len(devices) if len(devices) > 1
+                  and netplan.batch % len(devices) == 0 else 1)
+        table = layer_table(netplan, shards)
         spans.RECORD.register(table)
 
         def fwd(prms, xx):                 # xx: the table's "input"
@@ -1329,7 +1362,7 @@ class NetworkExecutor:
 
         fwd.__name__ = fwd.__qualname__ = table["name"]
 
-        if len(devices) > 1 and netplan.batch % len(devices) == 0:
+        if shards > 1:
             import numpy as np
             from jax.sharding import Mesh, PartitionSpec as P
 

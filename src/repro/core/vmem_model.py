@@ -23,6 +23,7 @@ accumulation in a VMEM scratch (our kernels/gemm):
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 from typing import Iterable, List, Optional, Tuple
 
@@ -279,7 +280,7 @@ def winograd_traffic_bytes(
     intermediates, each (64, tiles, C) fp32, round-trip through HBM between
     kernels — ``2*tiles*64*(cin+cout)`` elements that dominate the layer.
     ``fused=True`` models the single-pass megakernel
-    (kernels/winograd/kernel.py:fused_winograd_pallas): V lives in registers
+    (kernels/winograd/kernel.py:fused_winograd_nhwc_pallas): V lives in registers
     and M in a VMEM scratch accumulator, so both round-trips vanish and only
     the tile reads, the pre-transformed weights and the output remain.
 
@@ -383,7 +384,9 @@ def winograd_kernel_vmem_bytes(
     block and the (8, 8, bc, bo) weight block (both double-buffered across
     the Cin grid axis), the (8, 8, bt, bo) fp32 M accumulator scratch, the
     (6, 6, bt, bo) output block, the (1, bo) bias row, and (``internal``)
-    Mosaic's internal scratch for the transform planes.
+    Mosaic's internal scratch for the transform planes.  At bt = bb*k*ntw
+    it bounds the same kernel tiling in VMEM from above: its NHWC input
+    window (bb, 6k+2, 6 ntw + 8, bc) holds at most 56 planes per tile.
 
     ``fused=False``: the 3-pass pipeline's footprint is the max over its
     three kernels — each one's in/out blocks are live simultaneously (plus
@@ -517,3 +520,40 @@ def autotune_winograd_blocks(
         ).total_s,
     )
     return best, predict_winograd(tiles, cin, cout, best, hw, dtype_bytes, fused)
+
+
+@functools.lru_cache(maxsize=1024)
+def winograd_nhwc_blocks(
+    b: int, nth: int, ntw: int, bc: int, bo: int,
+    hw: ChipSpec = V5E, vmem_budget: Optional[int] = None,
+) -> Tuple[int, int, int]:
+    """(bb images, k tile rows, ntw_b tile columns) per program of the
+    fused Winograd kernel, which cuts its tiles from NHWC row windows, for
+    an (nth x ntw)-tile output of ``b`` images and channel blocks (bc, bo).
+
+    Fewest computed tiles first (tile columns pad to the ntw_b multiple of
+    8 sublanes, tile rows to the k multiple), then the most tiles per block
+    bb*k*ntw_b whose footprint — ``winograd_kernel_vmem_bytes`` at
+    bt = bb*k*ntw_b, an upper bound of the fp32 window
+    (bb, 6k+2, 6 ntw_b + 8, bc) — fits the budget: every block's 64 GEMMs
+    push their weight planes into the MXU, and a block re-fetches the
+    weights when Cp or Op spans several channel blocks, so fewer, larger
+    blocks amortize both.  Then the smaller input window.  Several images
+    share a block only when it holds all their tiles.  If not even one
+    8-tile row fits, (1, 1, 8) is returned anyway: blocks cannot shrink
+    below the sublane granule.
+    """
+    budget = vmem_budget if vmem_budget is not None else hw.vmem_bytes
+    best, best_key = (1, 1, 8), None
+    for nw in range(8, ceil_to(ntw, 8) + 1, 8):
+        for k in range(1, nth + 1):
+            whole = k == nth and nw >= ntw
+            for bb in range(1, (b if whole else 1) + 1):
+                te = bb * k * nw
+                if b % bb or winograd_kernel_vmem_bytes(te, bc, bo) > budget:
+                    continue
+                key = (ceil_to(nth, k) * ceil_to(ntw, nw), -te,
+                       bb * (6 * k + 2) * (6 * nw + 8))
+                if best_key is None or key < best_key:
+                    best, best_key = (bb, k, nw), key
+    return best

@@ -37,6 +37,7 @@ def conv2d_pallas(
     in_layout: Optional["Layout"] = None,
     out_layout: Optional["Layout"] = None,
     pretransformed: bool = False,
+    vmem_budget: Optional[int] = None,
 ) -> jnp.ndarray:
     """x (B,H,W,C), w (kh,kw,C,O) -> (B,OH,OW,O) via Pallas kernels.
 
@@ -48,7 +49,9 @@ def conv2d_pallas(
     the planner rewrites such layers to im2col/direct or keeps them fp32.
     ``pretransformed`` declares offline Winograd-transformed weights
     ((8, 8, C, O)); it is an explicit contract, never inferred from the
-    weight shape (raw kh == 8 kernels share that shape).
+    weight shape (raw kh == 8 kernels share that shape).  ``vmem_budget``
+    (None: the chip's VMEM) bounds the fused Winograd kernel's NHWC blocks,
+    which the plan does not carry.
     """
     import jax
 
@@ -67,7 +70,7 @@ def conv2d_pallas(
     if in_layout is not None or out_layout is not None:
         return _conv2d_pallas_laidout(
             x, w, spec, algo, blocks, interpret, bias, activation,
-            in_layout, out_layout, plan, pretransformed, scale,
+            in_layout, out_layout, plan, pretransformed, scale, vmem_budget,
         )
 
     if algo is ConvAlgorithm.DIRECT:
@@ -104,6 +107,7 @@ def conv2d_pallas(
             x, w, spec, blocks=blocks, interpret=interpret,
             pretransformed=pretransformed,
             bias=bias, activation=activation, fused=fused,
+            vmem_budget=vmem_budget,
         )
 
     from repro.kernels.im2col_gemm import conv2d_pallas_im2col
@@ -128,6 +132,7 @@ def _conv2d_pallas_laidout(
     plan: Optional["ConvPlan"],
     pretransformed: bool = False,
     scale: Optional[jnp.ndarray] = None,
+    vmem_budget: Optional[int] = None,
 ) -> jnp.ndarray:
     """Executor path: channels pre-padded in, channel crop deferred out.
 
@@ -191,16 +196,14 @@ def _conv2d_pallas_laidout(
 
         b, h, ww, cp = x.shape
         oh, ow = spec.out_hw(h, ww)
-        ph, pw = spec.padding
-        if ph or pw:
-            x = jnp.pad(x, ((0, 0), (ph, ph), (pw, pw), (0, 0)))
         # Offline-prepared weights arrive pre-transformed as (8, 8, Cp, Op);
         # the executor carries the flag explicitly (no shape sniffing).
         u = w if pretransformed else transform_weights(w, x.dtype)
         if blocks is None:
             t = b * -(-oh // 6) * -(-ow // 6)
             blocks = pick_blocks(
-                t, cp, u.shape[-1], dtype_bytes=jnp.dtype(x.dtype).itemsize
+                t, cp, u.shape[-1], vmem_budget=vmem_budget,
+                dtype_bytes=jnp.dtype(x.dtype).itemsize,
             )
         bt, bc, bo = blocks
         op = ceil_to(u.shape[-1], bo)
@@ -211,6 +214,7 @@ def _conv2d_pallas_laidout(
         y = conv2d_winograd_padded_call(
             x, u, oh, ow, blocks, interpret=interpret,
             bias_p=bias_p, activation=activation, fused=fused,
+            padding=spec.padding, vmem_budget=vmem_budget,
         )
         return y[..., :o_keep] if y.shape[-1] != o_keep else y
 

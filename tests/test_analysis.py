@@ -349,6 +349,37 @@ def test_oob_block_window_flags_bounds_only():
     assert "escapes" in f.message and f.actual > f.expected
 
 
+def test_partial_output_edge_block_is_in_bounds():
+    """An output whose extent is not a block multiple: the last grid row's
+    block starts inside the operand and overhangs its end, which Pallas
+    writes back clipped (the Winograd kernel tiled in VMEM writes its
+    (B, OH, OW, O) output so).  Bounds stays green; the same overhang on
+    the input is still flagged."""
+    from jax.experimental import pallas as pl
+
+    def kernel(x_ref, o_ref):
+        o_ref[...] = x_ref[...] * 2.0
+
+    def fn(x):
+        return pl.pallas_call(
+            kernel,
+            grid=(2, 2),
+            in_specs=[pl.BlockSpec((8, 128), lambda i, j: (i, j))],
+            out_specs=pl.BlockSpec((8, 128), lambda i, j: (i, j)),
+            out_shape=jax.ShapeDtypeStruct((13, 256), jnp.float32),
+            interpret=True,
+        )(x)
+
+    (rec,) = _records(fn, jnp.ones((16, 256), jnp.float32))
+    report = _interior_report([(rec, {"step": 0, "reduction_axes": ()})])
+    assert not report.by_pass("bounds"), report.findings
+
+    (rec,) = _records(fn, jnp.ones((13, 256), jnp.float32))
+    report = _interior_report([(rec, {"step": 0, "reduction_axes": ()})])
+    (f,) = report.by_pass("bounds")
+    assert "input operand 0" in f.message and f.actual == 16
+
+
 def test_flipped_init_guard_flags_accum_only():
     """An accumulator initialized under the *last*-step guard instead of the
     first: every earlier reduction step reads stale VMEM.  The accum pass

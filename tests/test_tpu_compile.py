@@ -8,15 +8,18 @@ matmuls with two batch dims) and kernels that overrun scoped VMEM.
 Every compile sets ``vmem_limit_bytes`` to the footprint ``core/vmem_model``
 predicts for the kernel's blocks, so a pass also proves the model covers
 what the compiler allocates.  Two sets of kernels are compiled: fixed cases,
-each a layer the compiler once refused, and every distinct kernel call the
-planner picks for the benchmark's networks (VGG-16@224 batch 8,
-YOLOv3-tiny@416 and YOLOv3-20@608 batch 1; fp32 and int8).
+each a layer the compiler once refused or a block shape the Winograd
+kernel tiled in VMEM takes (YOLOv3-20's stem, several images per block),
+and every distinct kernel call the planner picks for the benchmark's
+networks (VGG-16@224 batch 8, YOLOv3-tiny@416 and YOLOv3-20@608 batch 1;
+fp32 and int8).
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library at a time, and the test workers all
 import this file.
 """
 import functools
+import math
 import os
 
 import jax
@@ -32,6 +35,7 @@ from repro.core.netplan import (
     prepare_net_params,
     pretransform_flags,
     run_network,
+    winograd_step_tiling,
 )
 from repro.core.vmem_model import (
     gemm_kernel_vmem_bytes,
@@ -112,21 +116,31 @@ def _im2col_case(b, hw, cin, cout, stride, dtype, cin_phys=None):
     return fn, args, model
 
 
-def _winograd_case(b, hw, cin, cout):
-    from repro.kernels.winograd.kernel import fused_winograd_pallas
-    from repro.kernels.winograd.ops import pick_blocks
+def _winograd_case(b, hw, cin, cout, channel_blocks=None):
+    """The fused kernel as ``conv2d_winograd_padded_call`` runs it: tiles
+    cut in VMEM from a conv-padded, channel-padded (B, H+2, W+2, Cp)
+    activation, at the (bb, k, ntw) blocks ``winograd_tiling`` derives for
+    the channel blocks (``pick_blocks``' unless given); modeled as a tile
+    block of bt = bb*k*ntw tiles."""
+    from repro.kernels.winograd.ops import (
+        conv2d_winograd_padded_call,
+        pick_blocks,
+        winograd_tiling,
+    )
 
     t = b * (-(-hw // 6)) ** 2            # 6x6 output tiles (same padding)
     bt, bc, bo = pick_blocks(t, cin, cout)
-    tp, cp, op = ceil_to(t, bt), ceil_to(cin, bc), ceil_to(cout, bo)
+    bc, bo = channel_blocks or (bc, bo)
+    cp, op = ceil_to(cin, bc), ceil_to(cout, bo)
+    nhwc = winograd_tiling(b, hw, hw, (bt, bc, bo)).nhwc
 
-    def fn(tiles, u, bias):
-        return fused_winograd_pallas(tiles, u, bt, bc, bo, bias=bias,
-                                     activation="relu")
+    def fn(x_sp, u, bias):
+        return conv2d_winograd_padded_call(x_sp, u, hw, hw, (bt, bc, bo),
+                                           bias_p=bias, activation="relu")
 
-    args = [((8, 8, tp, cp), jnp.float32), ((8, 8, cp, op), jnp.float32),
-            ((1, op), jnp.float32)]
-    return fn, args, winograd_kernel_vmem_bytes(bt, bc, bo)
+    args = [((b, hw + 2, hw + 2, cp), jnp.float32),
+            ((8, 8, cp, op), jnp.float32), ((1, op), jnp.float32)]
+    return fn, args, winograd_kernel_vmem_bytes(math.prod(nhwc), bc, bo)
 
 
 def _winograd_3pass_case(b, hw, cin, cout):
@@ -169,10 +183,20 @@ def _gemm_case(m, k, n, dtype):
 
 
 CASES = {
-    # VGG-16's first 3x3 layer plans onto the fused megakernel.
+    # VGG-16's first 3x3 layer plans onto the fused megakernel, which cuts
+    # its tiles in VMEM, 40 tile columns (a 248-column window) at a time.
     "winograd_fused_224x64-64": lambda: _winograd_case(8, 224, 64, 64),
     # The 3-pass pipeline the planner can pin instead, at the same layer.
     "winograd_3pass_224x64-64": lambda: _winograd_3pass_case(8, 224, 64, 64),
+    # Tiled in VMEM: YOLOv3-20's stem, a 610 x 610 x 128 padded window
+    # (3 channels padded to 128) cut 6 tile rows x 8 tile columns at a time.
+    "winograd_nhwc_yolov3_20_l0_608x3-32": lambda: _winograd_case(
+        1, 608, 3, 32, (128, 128)),
+    # Tiled in VMEM, two whole 18x18 images per block (3 x 8 tiles each),
+    # over 4 Cin and 4 Cout blocks.  Two 28x28 images (5 x 8 tiles each)
+    # overrun the 16 MiB budget.
+    "winograd_nhwc_b8_18x512-512": lambda: _winograd_case(
+        8, 18, 512, 512, (128, 128)),
     # im2col at VGG-16 widths, batch 8, fp32 and int8.
     **{
         f"im2col_{jnp.dtype(dt).name}_{hw}x{ci}-{co}": functools.partial(
@@ -194,8 +218,10 @@ CASES = {
 
 #: The least ``vmem_limit_bytes`` at which each case compiled for v5e
 #: (bisected to 64 KiB with ``compile_with_limit``; jax 0.9.0, libtpu
-#: 0.0.34).  Each layer here but the 3-pass one was refused before the VMEM
-#: model counted tile padding and Mosaic's internal scratch.
+#: 0.0.34).  Each layer here but the Winograd ones was refused before the
+#: VMEM model counted tile padding and Mosaic's internal scratch (so was
+#: VGG-16's first layer on the fused kernel, when it still read tiles XLA
+#: had gathered in HBM).
 COMPILER_MIN_VMEM = {
     "gemm_float32_8x25088-4096": 8716288,
     "gemm_int8_8x4096-1000": 4325376,
@@ -207,7 +233,9 @@ COMPILER_MIN_VMEM = {
     "im2col_int8_56x256-256": 6094848,
     "im2col_yolov3_20_l1_608s2_32-64": 10551296,
     "winograd_3pass_224x64-64": 8650752,
-    "winograd_fused_224x64-64": 9502720,
+    "winograd_fused_224x64-64": 5570560,
+    "winograd_nhwc_b8_18x512-512": 14417920,
+    "winograd_nhwc_yolov3_20_l0_608x3-32": 6422528,
 }
 
 
@@ -268,15 +296,18 @@ CELLS = {
 }
 
 
-def _modeled_vmem(step) -> int:
+def _modeled_vmem(netplan, step) -> int:
     """The footprint ``core/vmem_model`` gives the kernel a planned conv step
     runs, at the step's blocks and dtype (the bias, and int8's scale, rows
-    included)."""
+    included).  A fused Winograd step, which tiles in VMEM, is modeled as a
+    tile block of its bb*k*ntw tiles."""
     plan, spec = step.plan, step.spec
     d = itemsize(plan.dtype)
     if plan.algorithm is ConvAlgorithm.WINOGRAD:
-        return winograd_kernel_vmem_bytes(*plan.kernel_blocks,
-                                          fused=plan.winograd_fused,
+        bt, bc, bo = plan.kernel_blocks
+        nhwc = winograd_step_tiling(netplan, step).nhwc
+        return winograd_kernel_vmem_bytes(math.prod(nhwc) if nhwc else bt,
+                                          bc, bo, fused=plan.winograd_fused,
                                           dtype_bytes=d)
     if plan.algorithm is ConvAlgorithm.DIRECT:
         bm, bn, bk = plan.kernel_blocks
@@ -323,7 +354,7 @@ def _planned_calls(model, batch, dtype):
                                pretransformed=flags, start=s.index,
                                stop=s.index + 1)
         calls[key] = (s.index, lambda pp, xx, fn=fn: fn([pp], xx), [p, xs],
-                      _modeled_vmem(s))
+                      _modeled_vmem(netplan, s))
     return calls
 
 
