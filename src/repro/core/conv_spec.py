@@ -109,6 +109,33 @@ def apply_activation(x, kind: str):
     raise ValueError(f"unknown activation {kind!r}")
 
 
+def max_pool(x, layer):
+    """A maxpool layer on NHWC ``x``: "SAME" windows, or ``layer.pad``
+    rows and columns of -inf on each side.  Either way every window holds
+    a real element, so all-zero channels stay zero."""
+    import jax
+    import jax.numpy as jnp
+
+    if layer.pad is None:
+        padding = "SAME"
+    else:
+        p = (layer.pad, layer.pad)
+        padding = ((0, 0), p, p, (0, 0))
+    return jax.lax.reduce_window(
+        x, -jnp.inf, jax.lax.max, (1, layer.size, layer.size, 1),
+        (1, layer.stride, layer.stride, 1), padding,
+    )
+
+
+def max_pool_out_hw(layer, h: int, w: int) -> Tuple[int, int]:
+    """The (H, W) ``max_pool`` gives a (h, w) map."""
+    s = layer.stride
+    if layer.pad is None:
+        return -(-h // s), -(-w // s)
+    p, k = layer.pad, layer.size
+    return (h + 2 * p - k) // s + 1, (w + 2 * p - k) // s + 1
+
+
 def apply_epilogue(y, epilogue: Optional[Epilogue]):
     """Reference epilogue: y * scale + bias, then activation (pure jnp)."""
     if epilogue is None:

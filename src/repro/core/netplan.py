@@ -32,7 +32,9 @@ planner decision:
 Elision is legal exactly when the padded region stays zero and divisible:
 the producer's weight/bias pads make its extra output channels
 act(0 + 0) = 0 (relu/leaky/linear all fix 0), maxpool/upsample preserve
-zero channels, and the consumer's zero weight pads ignore them — so a
+zero channels (a padded max pool too: its -inf pad never fills a whole
+window, so every window's max over an all-zero channel is 0), and the
+consumer's zero weight pads ignore them — so a
 producer's physical channel count that divides the consumer's channel block
 can flow through unchanged.  Any consumer that needs logical channels
 (route concat, shortcut add, fc, avgpool, or a layer referenced by one)
@@ -58,6 +60,8 @@ from repro.core.conv_spec import (
     ConvSpec,
     Epilogue,
     apply_activation,
+    max_pool,
+    max_pool_out_hw,
     select_algorithm,
 )
 from repro.core.planner import ConvPlan, Planner
@@ -236,7 +240,7 @@ def _propagate_shapes(
             cur_h, cur_w = spec.out_hw(cur_h, cur_w)
             cur_c = l.out_channels
         elif l.kind == "maxpool":
-            cur_h, cur_w = -(-cur_h // l.stride), -(-cur_w // l.stride)
+            cur_h, cur_w = max_pool_out_hw(l, cur_h, cur_w)
         elif l.kind == "upsample":
             cur_h, cur_w = cur_h * l.size, cur_w * l.size
         elif l.kind == "route":
@@ -1070,6 +1074,10 @@ def winograd_step_tiling(netplan: NetworkPlan, step: NetStep,
     )
 
 
+#: A step's ``residual`` role in the layer table, by kind.
+_RESIDUAL = {"shortcut": "add", "route": "branch"}
+
+
 def layer_table(netplan: NetworkPlan, shards: int = 1) -> Dict[str, Any]:
     """What a trace reader needs to put the forward's device ops down to
     planned layers: per step its scope, index, kind, algorithm and the
@@ -1079,12 +1087,18 @@ def layer_table(netplan: NetworkPlan, shards: int = 1) -> Dict[str, Any]:
     where its tiles are cut (``tiling``: ``"vmem"`` or ``"hbm"``) and
     ``tile_ratio``, the tiles its kernel computes over the real B*nTH*nTW
     (None on other steps), at the batch of one of ``shards`` devices.
+    ``residual`` marks the residual path: ``"add"`` on a shortcut,
+    ``"branch"`` on a route, ``"source"`` on a step whose output a later
+    shortcut or route reads (its layout is forced back to logical
+    channels), None elsewhere.
 
     JAX's persistent compilation cache keys a program without its metadata,
     so an executable compiled before a scope was renamed would load with the
     old names.  The name therefore carries a digest of the scopes (and of
     the batch and input size), which the key does include."""
     layers = []
+    referenced = {j for s in netplan.steps
+                  for j in getattr(s.layer, "from_layers", ())}
     for s in netplan.steps:
         scope = layer_scope(s)
         tiling = winograd_step_tiling(netplan, s, shards)
@@ -1097,6 +1111,8 @@ def layer_table(netplan: NetworkPlan, shards: int = 1) -> Dict[str, Any]:
             "predicted_s": s.plan.predicted_s if s.plan is not None else None,
             "tiling": tiling.name if tiling is not None else None,
             "tile_ratio": tiling.ratio if tiling is not None else None,
+            "residual": _RESIDUAL.get(s.layer.kind) or (
+                "source" if s.index in referenced else None),
         })
     key = json.dumps([[l["scope"] for l in layers], EXIT_SCOPE,
                       netplan.batch, list(netplan.input_hw)])
@@ -1202,11 +1218,7 @@ def run_network(
                         pretransformed=pre,
                     )
             elif l.kind == "maxpool":
-                cur = jax.lax.reduce_window(
-                    cur, -jnp.inf, jax.lax.max,
-                    (1, l.size, l.size, 1),
-                    (1, l.stride, l.stride, 1), "SAME",
-                )
+                cur = max_pool(cur, l)
             elif l.kind == "avgpool":
                 cur = cur.mean(axis=(1, 2))
             elif l.kind == "upsample":
@@ -1214,7 +1226,9 @@ def run_network(
                     jnp.repeat(cur, l.size, axis=1), l.size, axis=2
                 )
             elif l.kind == "shortcut":
-                cur = cur + outputs[l.from_layers[0] - start]
+                cur = apply_activation(
+                    cur + outputs[l.from_layers[0] - start], l.activation
+                )
             elif l.kind == "route":
                 cur = jnp.concatenate(
                     [outputs[j - start] for j in l.from_layers], axis=-1

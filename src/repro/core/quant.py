@@ -145,11 +145,15 @@ def calibrate_activation_scales(
     scales} for every conv step.  Runs eagerly, offline — the scales become
     constants of the jitted int8 forward.
     """
-    import jax
     import jax.numpy as jnp
 
     from repro.core.conv2d import conv2d_reference
-    from repro.core.conv_spec import Epilogue, apply_epilogue, apply_activation
+    from repro.core.conv_spec import (
+        Epilogue,
+        apply_activation,
+        apply_epilogue,
+        max_pool,
+    )
 
     scales: Dict[int, Any] = {}
     outputs: List[Any] = []
@@ -164,16 +168,14 @@ def calibrate_activation_scales(
                 y, Epilogue(bias=p["b"], activation=l.activation)
             )
         elif l.kind == "maxpool":
-            cur = jax.lax.reduce_window(
-                cur, -jnp.inf, jax.lax.max,
-                (1, l.size, l.size, 1), (1, l.stride, l.stride, 1), "SAME",
-            )
+            cur = max_pool(cur, l)
         elif l.kind == "avgpool":
             cur = cur.mean(axis=(1, 2))
         elif l.kind == "upsample":
             cur = jnp.repeat(jnp.repeat(cur, l.size, axis=1), l.size, axis=2)
         elif l.kind == "shortcut":
-            cur = cur + outputs[l.from_layers[0]]
+            cur = apply_activation(cur + outputs[l.from_layers[0]],
+                                   l.activation)
         elif l.kind == "route":
             cur = jnp.concatenate([outputs[j] for j in l.from_layers], axis=-1)
         elif l.kind == "fc":

@@ -85,10 +85,11 @@ def tiled_bytes(shape: Tuple[int, ...], dtype_bytes: int = 4) -> int:
 #: compiler's scoped-VMEM requirement on v5e — the least
 #: ``vmem_limit_bytes`` at which each kernel compiles, bisected to 64 KiB
 #: (tests/test_tpu_compile.py checks the bound on fixed layers and on every
-#: kernel the planner picks for VGG-16, YOLOv3-tiny and YOLOv3-20).  A
-#: fixed allowance per kernel plus fp32 planes of the matmul-row x lane
-#: shape: the im2col patch and matmul result, and the Winograd transforms'
-#: row planes, V and products.  The fused Winograd kernel's need depends on
+#: kernel the planner picks for VGG-16, YOLOv3-tiny, YOLOv3-20 and
+#: ResNet-50).  A fixed allowance per kernel plus fp32 planes of the
+#: matmul-row x lane shape: the im2col patch and matmul result, the
+#: Winograd transforms' row planes, V and products, and the direct GEMM's
+#: operand and result values.  The fused Winograd kernel's need depends on
 #: its grid as well as its blocks — at (32, 128, 128) it ranges from
 #: 9.06 MiB to 13.0 MiB (one Cin step, several Cout steps) — and its planes
 #: cover the largest.
@@ -256,6 +257,14 @@ def gemm_kernel_vmem_bytes(
     one for a fused bias, two for int8's scale + bias.  ``three_loop``
     models the full-K-panel variant, which accumulates in its output block
     and has no separate scratch (pass ``bk`` = the full K for it).
+
+    Mosaic's internal scratch is the fixed allowance plus the dot's operand
+    values and its fp32 result, which it can hold beside the buffers.  At
+    four of ResNet-50's batch-64 1x1 layers the compiler needed 0.23 to
+    1.98 MiB more than the buffers and the allowance; the operand and result
+    values are the least of the dot's values that cover the largest (2.0 MiB
+    at bm, bn, bk = 512, 256, 512), and over-count the others by 0.6 to
+    3.0 MiB (tests/test_tpu_compile.py keeps the bisected minima).
     """
     if out_dtype_bytes is None:
         out_dtype_bytes = ACC_BYTES if dtype_bytes == 1 else dtype_bytes
@@ -266,7 +275,11 @@ def gemm_kernel_vmem_bytes(
     total += buf * epilogue_rows * tiled_bytes((1, bn), ACC_BYTES)
     if not three_loop:
         total += tiled_bytes((bm, bn), ACC_BYTES)             # accumulator
-    return total + (INTERNAL_BYTES if internal else 0)
+    if internal:
+        total += (INTERNAL_BYTES + tiled_bytes((bm, bk), dtype_bytes)
+                  + tiled_bytes((bk, bn), dtype_bytes)
+                  + tiled_bytes((bm, bn), ACC_BYTES))
+    return total
 
 
 def winograd_traffic_bytes(

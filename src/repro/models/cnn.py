@@ -4,7 +4,8 @@ dispatcher.
 Re-implements the convolutional-layer kernel set the paper vectorizes
 (§II.B): im2col+GEMM / Winograd (via core/conv2d.py), plus fill_cpu,
 copy_cpu, normalize_cpu, add_bias, scale_bias, activate_array — here as
-fused jnp ops.  Layer tables for VGG16 / YOLOv3(-tiny) live in configs/.
+fused jnp ops.  Layer tables for VGG16 / YOLOv3(-tiny) / ResNet-50 live in
+configs/.
 """
 from __future__ import annotations
 
@@ -15,7 +16,13 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.conv_spec import ConvSpec, Epilogue, apply_activation
+from repro.core.conv_spec import (
+    ConvSpec,
+    Epilogue,
+    apply_activation,
+    max_pool,
+    max_pool_out_hw,
+)
 from repro.core.conv2d import conv2d
 from repro.models.layers import normal_init
 
@@ -26,11 +33,27 @@ class CNNLayer:
     out_channels: int = 0
     kernel: int = 3
     stride: int = 1
-    pad: Optional[int] = None      # None -> same-ish (kernel//2)
+    # conv: None -> kernel//2.  maxpool: None -> Darknet's "SAME" windows;
+    # else a symmetric -inf pad of at most size//2 (torchvision's MaxPool2d).
+    pad: Optional[int] = None
     batch_norm: bool = True
-    activation: str = "leaky"      # leaky | relu | linear
+    # leaky | relu | linear; None resolves per kind: linear on a shortcut
+    # (Darknet's parse_shortcut), leaky elsewhere.  A shortcut applies it
+    # after the add.
+    activation: Optional[str] = None
     from_layers: Tuple[int, ...] = ()  # shortcut/route sources (indices)
     size: int = 2                  # pool size / upsample factor
+
+    def __post_init__(self) -> None:
+        if self.activation is None:
+            object.__setattr__(
+                self, "activation",
+                "linear" if self.kind == "shortcut" else "leaky")
+        if (self.kind == "maxpool" and self.pad is not None
+                and not 0 <= self.pad <= self.size // 2):
+            raise ValueError(
+                f"maxpool pad {self.pad} outside [0, size // 2 = "
+                f"{self.size // 2}]: a window would hold no real element")
 
 
 def _conv_spec(layer: CNNLayer, in_ch: int) -> ConvSpec:
@@ -174,7 +197,7 @@ def _plan_layers(
             cur_h, cur_w = spec.out_hw(cur_h, cur_w)
             cur_ch = l.out_channels
         elif l.kind == "maxpool":
-            cur_h, cur_w = -(-cur_h // l.stride), -(-cur_w // l.stride)
+            cur_h, cur_w = max_pool_out_hw(l, cur_h, cur_w)
         elif l.kind == "upsample":
             cur_h, cur_w = cur_h * l.size, cur_w * l.size
         elif l.kind == "route":
@@ -241,17 +264,13 @@ def cnn_forward(
                 cur = add_bias(cur, p["b"])
             cur = activate_array(cur, l.activation)
         elif l.kind == "maxpool":
-            cur = jax.lax.reduce_window(
-                cur, -jnp.inf, jax.lax.max,
-                (1, l.size, l.size, 1),
-                (1, l.stride, l.stride, 1), "SAME",
-            )
+            cur = max_pool(cur, l)
         elif l.kind == "avgpool":
             cur = cur.mean(axis=(1, 2))
         elif l.kind == "upsample":
             cur = jnp.repeat(jnp.repeat(cur, l.size, axis=1), l.size, axis=2)
         elif l.kind == "shortcut":
-            cur = cur + outputs[l.from_layers[0]]
+            cur = activate_array(cur + outputs[l.from_layers[0]], l.activation)
         elif l.kind == "route":
             cur = jnp.concatenate([outputs[j] for j in l.from_layers], axis=-1)
         elif l.kind == "fc":
@@ -335,7 +354,7 @@ def conv_layer_dims(layers: Sequence[CNNLayer], h: int, w: int, in_ch: int = 3):
             })
             cur_ch, cur_h, cur_w = l.out_channels, oh, ow
         elif l.kind == "maxpool":
-            cur_h, cur_w = -(-cur_h // l.stride), -(-cur_w // l.stride)
+            cur_h, cur_w = max_pool_out_hw(l, cur_h, cur_w)
         elif l.kind == "upsample":
             cur_h, cur_w = cur_h * l.size, cur_w * l.size
         elif l.kind == "route":

@@ -10,14 +10,16 @@ predicts for the kernel's blocks, so a pass also proves the model covers
 what the compiler allocates.  Two sets of kernels are compiled: fixed cases,
 each a layer the compiler once refused or a block shape the Winograd
 kernel tiled in VMEM takes (YOLOv3-20's stem, several images per block),
+among them four of ResNet-50's 1x1 convs at batch 64,
 and every distinct kernel call the planner picks for the benchmark's
-networks (VGG-16@224 batch 8, YOLOv3-tiny@416 and YOLOv3-20@608 batch 1;
-fp32 and int8).
+networks (VGG-16@224 batch 8, YOLOv3-tiny@416 and YOLOv3-20@608 batch 1,
+ResNet-50@224 batch 64; fp32 and int8).
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library at a time, and the test workers all
 import this file.
 """
+import dataclasses
 import functools
 import math
 import os
@@ -28,7 +30,8 @@ import pytest
 from jax.experimental.pallas import tpu as pltpu
 
 import repro
-from repro.configs import vgg16, yolov3
+from repro.api.model import CNNModel
+from repro.configs import resnet50, vgg16, yolov3
 from repro.core.conv_spec import ConvAlgorithm, ConvSpec
 from repro.core.netplan import (
     plan_network,
@@ -44,6 +47,7 @@ from repro.core.vmem_model import (
     winograd_kernel_vmem_bytes,
 )
 from repro.hw import V5E
+from repro.models.cnn import CNNLayer
 from repro.util import ceil_to
 
 
@@ -182,6 +186,16 @@ def _gemm_case(m, k, n, dtype):
     return fn, args, model
 
 
+def _direct_1x1_case(b, hw, cin, cout, block, dtype):
+    """A 1x1 conv planned alone on a (B, hw, hw, cin) map, run by the direct
+    GEMM kernel at ``block`` (bm, bn, bk) as the network executor runs it."""
+    model = CNNModel((CNNLayer("conv", out_channels=cout, kernel=1,
+                               activation="relu"),), (hw, hw), cin)
+    (call,) = _planned_calls(model, b, dtype, blocks={0: block}).values()
+    _, fn, args, modeled = call
+    return fn, args, modeled
+
+
 CASES = {
     # VGG-16's first 3x3 layer plans onto the fused megakernel, which cuts
     # its tiles in VMEM, 40 tile columns (a 248-column window) at a time.
@@ -213,6 +227,22 @@ CASES = {
     # VGG-16's classifier GEMMs.
     "gemm_float32_8x25088-4096": lambda: _gemm_case(8, 25088, 4096, jnp.float32),
     "gemm_int8_8x4096-1000": lambda: _gemm_case(8, 4096, 1000, jnp.int8),
+    # ResNet-50's 1x1 convs at batch 64 on the direct GEMM kernel, at the
+    # blocks the planner picks in the network: the four the compiler refused
+    # while the model counted no dot operand or result values (layers 4, 34,
+    # 40 and 66; layer 66 then took bk = 1024, which the model now prices
+    # over the budget).
+    **{
+        f"direct_resnet50_l{i:03d}_{hw}x{ci}-{co}": functools.partial(
+            _direct_1x1_case, 64, hw, ci, co, block, "float32"
+        )
+        for i, hw, ci, co, block in (
+            (4, 56, 64, 256, (512, 256, 128)),
+            (34, 28, 512, 256, (512, 256, 512)),
+            (40, 14, 1024, 256, (512, 256, 1024)),
+            (66, 7, 2048, 512, (512, 512, 512)),
+        )
+    },
 }
 
 
@@ -221,8 +251,15 @@ CASES = {
 #: 0.0.34).  Each layer here but the Winograd ones was refused before the
 #: VMEM model counted tile padding and Mosaic's internal scratch (so was
 #: VGG-16's first layer on the fused kernel, when it still read tiles XLA
-#: had gathered in HBM).
+#: had gathered in HBM).  ResNet-50's direct 1x1 kernels needed 0.23, 1.98,
+#: 0.48 and 1.97 MiB (layer 66 at bk = 1024: 13893632) more than their
+#: buffers and the fixed allowance; of the dot's values, A + B + the fp32
+#: result (2.0 MiB at layer 34) is the least sum that covers layer 34.
 COMPILER_MIN_VMEM = {
+    "direct_resnet50_l004_56x64-256": 2883584,
+    "direct_resnet50_l034_28x512-256": 7077888,
+    "direct_resnet50_l040_14x1024-256": 8650752,
+    "direct_resnet50_l066_7x2048-512": 7602176,
     "gemm_float32_8x25088-4096": 8716288,
     "gemm_int8_8x4096-1000": 4325376,
     "im2col_float32_112x128-128": 6291456,
@@ -291,6 +328,7 @@ CELLS = {
         ("vgg16@224_b8", vgg16.MODEL, 8),
         ("yolov3-tiny@416_b1", yolov3.TINY_MODEL, 1),
         ("yolov3-20@608_b1", yolov3.MODEL_20, 1),
+        ("resnet50@224_b64", resnet50.MODEL, 64),
     )
     for dtype in ("float32", "int8")
 }
@@ -319,15 +357,22 @@ def _modeled_vmem(netplan, step) -> int:
                                     spec.kw, *spec.stride, d)
 
 
-def _planned_calls(model, batch, dtype):
+def _planned_calls(model, batch, dtype, blocks=None):
     """{key: (step index, fn, args, modeled bytes)} for each distinct conv
     kernel call of the planned network.  Params and the int8 calibration
-    batch are abstract, so nothing runs on the host."""
+    batch are abstract, so nothing runs on the host.  ``blocks`` ({step
+    index: kernel blocks}) overrides the planner's blocks of those steps."""
     opts = repro.ExecutionOptions(impl="pallas", interpret=False, batch=batch,
                                   dtype=dtype, cache_path=None)
     netplan = plan_network(model.layers, *model.input_hw, opts.make_planner(),
                            in_channels=model.in_channels, batch=batch,
                            dtype=dtype)
+    if blocks:
+        netplan = dataclasses.replace(netplan, steps=tuple(
+            dataclasses.replace(s, plan=dataclasses.replace(
+                s.plan, kernel_blocks=tuple(blocks[s.index])))
+            if s.index in blocks else s
+            for s in netplan.steps))
     x = jax.ShapeDtypeStruct((batch, *model.input_hw, model.in_channels),
                              jnp.float32)
     params = jax.eval_shape(
