@@ -14,7 +14,12 @@ from __future__ import annotations
 from typing import Any, Dict, List, Optional
 
 from repro.core.conv_spec import ConvAlgorithm
-from repro.core.netplan import NetworkPlan, NetStep, resolve_algorithm
+from repro.core.netplan import (
+    NetworkPlan,
+    NetStep,
+    resolve_algorithm,
+    step_folded_k,
+)
 from repro.core.vmem_model import (
     GemmShape,
     im2col_gemm_traffic_bytes,
@@ -42,7 +47,8 @@ def step_descriptors(
 
     Empty for non-conv and non-pallas steps (fc layers run as plain XLA
     dots).  One descriptor for direct/im2col/fused-Winograd, three for the
-    3-pass Winograd pipeline.
+    3-pass Winograd pipeline; an im2col step that folds its taps names the
+    GEMM call it runs on the (B*OH*OW, folded K) patch matrix.
     """
     if not planned_pallas(step):
         return []
@@ -59,13 +65,14 @@ def step_descriptors(
     o_phys = step.out_layout.phys_c     # offline weight padding target
     blocks = plan.kernel_blocks
 
-    if algo is ConvAlgorithm.DIRECT:
+    if algo is ConvAlgorithm.DIRECT or plan.taps_folded:
         from repro.kernels.gemm.ops import gemm_call_descriptor
 
         bm, bn, bk = blocks
         m = b * oh * ow
+        k = step_folded_k(step) if plan.taps_folded else ceil_to(cp, bk)
         desc = gemm_call_descriptor(
-            ceil_to(m, bm), ceil_to(o_phys, bn), ceil_to(cp, bk), blocks,
+            ceil_to(m, bm), ceil_to(o_phys, bn), k, blocks,
             dtype_bytes=d, bias=True, scale=quantized,
         )
         return [dict(desc, step=step.index)]

@@ -404,6 +404,7 @@ class CompiledCNN(CompiledModel):
                 "predicted_s": s.plan.predicted_s,
                 "source": s.plan.source,
                 "winograd_fused": s.plan.winograd_fused,
+                "taps_folded": s.plan.taps_folded,
                 "elided": not s.out_layout.trivial,
             }
             if pipeplan is not None:

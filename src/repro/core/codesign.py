@@ -24,6 +24,7 @@ from repro.core.vmem_model import (
     predict_gemm,
 )
 from repro.hw import V5E, ChipSpec
+from repro.util import ceil_to
 
 MB = 1024 * 1024
 
@@ -145,6 +146,83 @@ def predict_conv_time(
     )
     flops = 2.0 * batch * oh * ow * kh * kw * cin * cout
     return max(flops / peak, gemm_bytes / bw)
+
+
+def narrow_input(spec: ConvSpec, hw: ChipSpec = V5E) -> bool:
+    """Is this a conv whose taps can fold: several taps over fewer input
+    channels than a lane block (RGB stems; 32-channel layers)?"""
+    return spec.kh * spec.kw > 1 and spec.in_channels < hw.lane_width
+
+
+def predict_narrow_conv_time(
+    spec: ConvSpec,
+    h: int,
+    w: int,
+    algorithm,
+    hw: ChipSpec = V5E,
+    dtype_bytes: int = 4,
+    batch: int = 1,
+    winograd_fused: bool = True,
+    taps_folded: bool = False,
+) -> float:
+    """Modeled seconds of a narrow-input conv at the work each realization
+    really does, for choosing between them (``predict_conv_time`` prices the
+    logical shapes, which hide the padding this choice is about).
+
+    Each realization is a roofline over its HBM traffic and its MXU work,
+    at the rate fp32 runs at HIGHEST precision (six bf16 passes), plus the
+    passes XLA makes to lay its operands out, at ``hw``'s measured
+    ``relayout_bandwidth`` and ``lane_concat_bandwidth``.  Every
+    realization reads the input once and writes the output at its
+    lane-padded out channels.  The fused im2col kernel (``algorithm``
+    IM2COL_GEMM) and the Winograd kernel multiply the input channels padded
+    to a lane block: the wrapper writes that padded copy (one pass; the
+    im2col wrapper splits a stride into phases, two more), and the kernel
+    reads it once per tap window, or as overlapping 8x8 tiles (plus V and
+    M through HBM when not ``winograd_fused``).  Tap folding
+    (``taps_folded``) multiplies K = kh*kw*C rounded up to the lane width
+    once, over a (B*OH*OW, K) patch matrix it writes and reads, which XLA
+    builds by concatenating the taps' narrow channel slices along the
+    lanes, after splitting a stride into its phases (a pass over the input
+    per phase).
+    """
+    from repro.core.conv_spec import ConvAlgorithm
+    from repro.core.vmem_model import peak_flops
+
+    lane = hw.lane_width
+    oh, ow = spec.out_hw(h, w)
+    sh, sw = spec.stride
+    m = batch * oh * ow
+    op = ceil_to(spec.out_channels, lane)
+    taps = spec.kh * spec.kw
+    x = batch * h * w * spec.in_channels
+    elems = x + m * op
+    if taps_folded:
+        k = taps * spec.in_channels
+        kp = ceil_to(k, lane)
+        flops = 2.0 * m * kp * op
+        elems += 2 * m * kp + kp * op
+        moved = (m * k / hw.lane_concat_bandwidth
+                 + (sh * sw * x if sh * sw > 1 else 0)
+                 / hw.relayout_bandwidth)
+    else:
+        cp = ceil_to(spec.in_channels, lane)
+        x_p = batch * h * w * cp
+        if algorithm is ConvAlgorithm.WINOGRAD:
+            tiles = batch * -(-oh // 6) * -(-ow // 6)
+            flops = 2.0 * tiles * 64 * cp * op
+            elems += x_p + tiles * 64 * cp + 64 * cp * op
+            if not winograd_fused:
+                elems += 2 * tiles * 64 * (cp + op)
+            moved = x_p / hw.relayout_bandwidth
+        else:
+            flops = 2.0 * m * taps * cp * op
+            elems += 2 * x_p + taps * cp * op
+            moved = (3 if sh * sw > 1 else 1) * x_p / hw.relayout_bandwidth
+    peak = (hw.peak_flops_bf16 / 6 if dtype_bytes == 4
+            else peak_flops(hw, dtype_bytes))
+    return (max(flops / peak, dtype_bytes * elems / hw.hbm_bandwidth)
+            + dtype_bytes * moved)
 
 
 def select_algorithm_by_cost(

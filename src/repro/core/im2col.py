@@ -13,6 +13,7 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax.numpy as jnp
+from jax import lax
 
 from repro.core.conv_spec import ConvSpec, Epilogue, apply_epilogue
 
@@ -45,12 +46,23 @@ def im2col(
     if ph or pw:
         x = jnp.pad(x, ((0, 0), (ph, ph), (pw, pw), (0, 0)))
 
-    # Row/col gather indices; broadcasting builds the (OH, OW, kh, kw) grid.
-    rows = (jnp.arange(oh) * sh)[:, None] + (jnp.arange(kh) * dh)[None, :]  # (OH, kh)
-    cols = (jnp.arange(ow) * sw)[:, None] + (jnp.arange(kw) * dw)[None, :]  # (OW, kw)
-    # patches: (B, OH, OW, kh, kw, C)
-    patches = x[:, rows[:, None, :, None], cols[None, :, None, :], :]
-    return patches.reshape(b, oh, ow, kh * kw * c)
+    # The input is split into its stride phases once, and each tap is a
+    # unit-stride slice of one phase, concatenated along channels: a
+    # strided slice per tap reads the whole map with a lane stride for
+    # every tap (on a TPU, 5x slower for a 7x7/2 stem).  lax.slice, not
+    # strided indexing, which jnp lowers to a gather per tap.
+    phases = {}
+    taps = []
+    for i in range(kh):
+        for j in range(kw):
+            r, q = i * dh, j * dw
+            key = (r % sh, q % sw)
+            if key not in phases:
+                phases[key] = lax.slice(x, (0, *key, 0), x.shape, (1, sh, sw, 1))
+            taps.append(lax.slice(
+                phases[key], (0, r // sh, q // sw, 0),
+                (b, r // sh + oh, q // sw + ow, c)))
+    return jnp.concatenate(taps, axis=-1) if len(taps) > 1 else taps[0]
 
 
 def conv2d_im2col(

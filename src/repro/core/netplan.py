@@ -182,7 +182,10 @@ def resolve_algorithm(
 
 
 def _in_channel_multiple(plan: ConvPlan, algo: ConvAlgorithm) -> int:
-    """The input-channel block the layer's Pallas kernel reduces over."""
+    """The input-channel block the layer's Pallas kernel reduces over; 1
+    for tap folding, which builds its patch matrix from logical channels."""
+    if plan.taps_folded:
+        return 1
     if algo is ConvAlgorithm.DIRECT:
         return plan.kernel_blocks[2]        # (bm, bn, bk) -> bk
     return plan.kernel_blocks[1]            # (toh|bt, bc, bo) -> bc
@@ -190,7 +193,7 @@ def _in_channel_multiple(plan: ConvPlan, algo: ConvAlgorithm) -> int:
 
 def _out_channel_multiple(plan: ConvPlan, algo: ConvAlgorithm) -> int:
     """The out-channel block the layer's kernel emits in multiples of."""
-    if algo is ConvAlgorithm.DIRECT:
+    if algo is ConvAlgorithm.DIRECT or plan.taps_folded:
         return plan.kernel_blocks[1]        # bn
     return plan.kernel_blocks[2]            # bo
 
@@ -208,7 +211,7 @@ def _snap_row_tile(plan: ConvPlan, algo: ConvAlgorithm, oh: int) -> ConvPlan:
     must not explode the grid into one program per output row — the
     executor's im2col path crops the row tail exactly like the wrapper.
     """
-    if algo is not ConvAlgorithm.IM2COL_GEMM:
+    if algo is not ConvAlgorithm.IM2COL_GEMM or plan.taps_folded:
         return plan
     toh, bc, bo = plan.kernel_blocks
     snapped = min(toh, oh)
@@ -322,7 +325,8 @@ def build_network_plan(
                 plan = _snap_row_tile(plan, algo, oh_)
             if planned_pallas:
                 in_mult = _in_channel_multiple(plan, algo)
-                if carry.pad_c and carry.phys_c % in_mult == 0:
+                if (carry.pad_c and carry.phys_c % in_mult == 0
+                        and not plan.taps_folded):
                     in_layout = carry       # producer elided into us
                 else:
                     in_layout = Layout(ic, ceil_to(ic, in_mult) - ic)
@@ -337,7 +341,10 @@ def build_network_plan(
                         algoj = resolve_algorithm(
                             specj, pj, *infos[j]["in"][:2]
                         )
-                        elide = out_phys % _in_channel_multiple(pj, algoj) == 0
+                        # A folded consumer takes logical channels: the
+                        # crop happens here, at the producer.
+                        elide = (not pj.taps_folded and out_phys
+                                 % _in_channel_multiple(pj, algoj) == 0)
                 out_layout = (
                     Layout(oc, out_phys - oc) if elide else Layout(oc)
                 )
@@ -952,7 +959,8 @@ def prepare_net_params(
     (paper §VII.A excludes it from timing for the same reason).  The layers
     transformed are exactly ``pretransform_flags(netplan, pretransform)``;
     pass those flags to ``run_network`` so execution routes the transformed
-    weights explicitly.
+    weights explicitly.  A step whose plan folds its taps gets its weights
+    folded to the (K, O) matrix its GEMM reads (``fold_weights``).
 
     Under an int8 network plan the steps whose ConvPlan resolved to
     ``dtype == 'int8'`` are additionally quantized offline (core/quant.py):
@@ -1020,6 +1028,10 @@ def prepare_net_params(
             from repro.core.winograd import transform_weights
 
             w = transform_weights(w, w.dtype)           # (8, 8, Cp, Op)
+        elif s.plan is not None and s.plan.taps_folded:
+            from repro.kernels.im2col_gemm.ops import fold_weights
+
+            w = fold_weights(w, step_folded_k(s))       # (Kp, Op)
         out.append({"w": w, "b": b})
     return out
 
@@ -1074,6 +1086,18 @@ def winograd_step_tiling(netplan: NetworkPlan, step: NetStep,
     )
 
 
+def step_folded_k(step: NetStep) -> Optional[int]:
+    """The contraction depth of a step whose im2col taps are folded into
+    one GEMM (kh*kw*C, padded to the lane width when it spans several K
+    blocks), or None."""
+    if step.plan is None or not step.plan.taps_folded:
+        return None
+    from repro.kernels.im2col_gemm.ops import folded_k
+
+    return folded_k(step.spec, step.spec.in_channels,
+                    step.plan.kernel_blocks[2])
+
+
 #: A step's ``residual`` role in the layer table, by kind.
 _RESIDUAL = {"shortcut": "add", "route": "branch"}
 
@@ -1087,6 +1111,9 @@ def layer_table(netplan: NetworkPlan, shards: int = 1) -> Dict[str, Any]:
     where its tiles are cut (``tiling``: ``"vmem"`` or ``"hbm"``) and
     ``tile_ratio``, the tiles its kernel computes over the real B*nTH*nTW
     (None on other steps), at the batch of one of ``shards`` devices.
+    An im2col step says whether it folds its taps into one GEMM
+    (``taps_folded``) and over what K (``folded_k``, None when tap-wise);
+    other steps carry None for both.
     ``residual`` marks the residual path: ``"add"`` on a shortcut,
     ``"branch"`` on a route, ``"source"`` on a step whose output a later
     shortcut or route reads (its layout is forced back to logical
@@ -1101,16 +1128,19 @@ def layer_table(netplan: NetworkPlan, shards: int = 1) -> Dict[str, Any]:
                   for j in getattr(s.layer, "from_layers", ())}
     for s in netplan.steps:
         scope = layer_scope(s)
+        algo = scope.split(".", 2)[2] if s.layer.kind == "conv" else None
         tiling = winograd_step_tiling(netplan, s, shards)
         layers.append({
             "scope": scope,
             "index": s.index,
             "kind": s.layer.kind,
-            "algorithm": (scope.split(".", 2)[2]
-                          if s.layer.kind == "conv" else None),
+            "algorithm": algo,
             "predicted_s": s.plan.predicted_s if s.plan is not None else None,
             "tiling": tiling.name if tiling is not None else None,
             "tile_ratio": tiling.ratio if tiling is not None else None,
+            "taps_folded": (s.plan.taps_folded if s.plan is not None and algo
+                            == ConvAlgorithm.IM2COL_GEMM.value else None),
+            "folded_k": step_folded_k(s),
             "residual": _RESIDUAL.get(s.layer.kind) or (
                 "source" if s.index in referenced else None),
         })
@@ -1258,7 +1288,8 @@ def expected_channel_ops(netplan: NetworkPlan) -> List[Dict[str, Any]]:
     the kernel wrappers' deferred channel crop when the kernel's out-channel
     grid (``ceil_to(phys, block)``) overshoots the layout's keep count, the
     direct GEMM's K-axis pad when the incoming channels don't divide ``bk``,
-    and the single exit crop.  ``repro.analysis``'s elision pass census
+    a folded step's pad of its patch matrix to the folded K, and the single
+    exit crop.  ``repro.analysis``'s elision pass census
     (taint-tracked pad/slice ops on the traced jaxpr's minor axis) must
     match this list exactly — any extra op is executor drift from the plan,
     any missing op means the plan promised movement that can't happen.
@@ -1293,6 +1324,11 @@ def expected_channel_ops(netplan: NetworkPlan) -> List[Dict[str, Any]]:
                     if ceil_to(want, bk) != want:
                         ops.append({"step": s.index, "kind": "pad"})
                     emitted = ceil_to(o_phys, bn)
+                elif s.plan.taps_folded:
+                    # The patch matrix's K tail, padded once.
+                    if step_folded_k(s) != s.spec.kh * s.spec.kw * want:
+                        ops.append({"step": s.index, "kind": "pad"})
+                    emitted = ceil_to(o_phys, s.plan.kernel_blocks[1])
                 else:
                     emitted = ceil_to(o_phys, s.plan.kernel_blocks[2])
                 if emitted != o_keep:

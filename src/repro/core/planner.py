@@ -60,7 +60,11 @@ from repro.util import ceil_to
 # process rebuilds a NetworkPlan with zero re-tunes.  v7: the VMEM model
 # counts Mosaic's (8, 128) tile padding and internal scratch and the im2col
 # kernel tiles rows (not whole images), so v6 kernel blocks are invalid.
-PLAN_CACHE_VERSION = 7
+# v8: an im2col plan may fold its taps into one GEMM contraction
+# (``ConvPlan.taps_folded``), which changes the algorithm, blocks and
+# layouts of narrow-input layers; a v7 plan or network entry would be read
+# back as tap-wise, so v7 caches re-tune once.
+PLAN_CACHE_VERSION = 8
 
 # Default on-disk location (overridable per Planner and via environment).
 DEFAULT_CACHE_PATH = os.environ.get(
@@ -213,10 +217,14 @@ class ConvPlan:
 
     ``block`` is the autotuned GEMM-level BlockConfig (the paper's Table II
     block sizes, VMEM in the role of L2).  ``kernel_blocks`` is what the
-    Pallas wrappers actually consume — (bm, bn, bk) for the direct GEMM,
-    (toh, bc, bo) for the fused im2col kernel, (bt, bc, bo) for the Winograd
-    pipeline.  ``predicted_s`` is modeled seconds in cost mode and measured
-    wall seconds in measure mode (``source`` says which).
+    Pallas wrappers actually consume — (bm, bn, bk) for the direct GEMM and
+    for tap folding, (toh, bc, bo) for the fused im2col kernel, (bt, bc, bo)
+    for the Winograd pipeline.  ``taps_folded`` marks the im2col realization
+    that builds the patch matrix once from the input's logical channels and
+    runs one GEMM over K = kh*kw*C (kernels/im2col_gemm/ops.py), chosen for
+    inputs narrower than a lane block.  ``predicted_s`` is modeled seconds
+    in cost mode and measured wall seconds in measure mode (``source`` says
+    which).
     """
 
     algorithm: ConvAlgorithm
@@ -231,6 +239,7 @@ class ConvPlan:
     dtype: str = "float32"              # resolved execution precision; under
                                         # an int8 request this may stay
                                         # 'float32' (quantization policy)
+    taps_folded: bool = False           # im2col as patch matrix + one GEMM
 
     def to_json(self) -> Dict[str, Any]:
         return {
@@ -243,6 +252,7 @@ class ConvPlan:
             "fused_epilogue": self.fused_epilogue,
             "winograd_fused": self.winograd_fused,
             "dtype": self.dtype,
+            "taps_folded": self.taps_folded,
         }
 
     @classmethod
@@ -257,6 +267,7 @@ class ConvPlan:
             fused_epilogue=bool(d.get("fused_epilogue", False)),
             winograd_fused=bool(d.get("winograd_fused", False)),
             dtype=d.get("dtype", "float32"),
+            taps_folded=bool(d.get("taps_folded", False)),
         )
 
 
@@ -311,6 +322,17 @@ def _dtype_bytes(dtype) -> int:
     from repro.core.vmem_model import itemsize
 
     return itemsize(_dtype_name(dtype))
+
+
+def _folded_bk(k: int, bk: int, lane: int) -> int:
+    """The K block of a folded GEMM over ``k`` taps x channels, at most the
+    autotuned ``bk``: all of ``k`` in one full-dimension block when it fits
+    (no K pad: XLA writes the patch matrix in one pass), else the largest
+    lane multiple dividing ``k`` rounded up to the lane width."""
+    kp = ceil_to(k, lane)
+    if kp <= bk:
+        return k
+    return max(d for d in range(lane, bk + 1, lane) if kp % d == 0)
 
 
 def _eligible_algorithms(spec: ConvSpec) -> List[ConvAlgorithm]:
@@ -580,6 +602,7 @@ class Planner:
         batch: int,
         dtype_bytes: int,
         winograd_fused: bool = True,
+        taps_folded: bool = False,
     ) -> Tuple[BlockConfig, Tuple[int, int, int]]:
         """(GEMM BlockConfig, kernel block tuple) for one algorithm choice.
 
@@ -589,7 +612,8 @@ class Planner:
         (bt, bc, bo) are autotuned per realization — the fused megakernel's
         M-accumulator scratch (8*8*bt*bo*4 bytes) is budgeted alongside the
         tile and weight blocks, so the fused and 3-pass variants can land on
-        different tuples.
+        different tuples.  Tap folding runs the GEMM kernel itself, on the
+        same BlockConfig, with bk the folded K or a divisor of it.
         """
         oh, ow = spec.out_hw(h, w)
         cin, cout = spec.in_channels, spec.out_channels
@@ -614,6 +638,9 @@ class Planner:
                 shape.m, cin, cout, self.hw, self.vmem_budget, dtype_bytes,
                 fused=winograd_fused,
             )
+        elif taps_folded:
+            kernel_blocks = (cfg.bm, cfg.bn,
+                             _folded_bk(shape.k, cfg.bk, self.hw.lane_width))
         elif algo is ConvAlgorithm.IM2COL_GEMM:
             from repro.kernels.im2col_gemm.ops import pick_blocks
 
@@ -628,8 +655,22 @@ class Planner:
     def _tune_cost_model(
         self, spec: ConvSpec, h: int, w: int, batch: int, dtype
     ) -> ConvPlan:
-        """Analytic decision: codesign routing + vmem_model block autotune."""
-        from repro.core.codesign import predict_conv_time, select_algorithm_by_cost
+        """Analytic decision: codesign routing + vmem_model block autotune.
+
+        On the Pallas path, a layer whose input is narrower than a lane
+        block is priced once more at the work each realization really does
+        (``codesign.predict_narrow_conv_time``): the algorithm chosen above
+        (the fused im2col kernel's taps, or Winograd unless the spec forces
+        it, over lane-padded channels) against folding the taps into one
+        patch-matrix GEMM, and the cheaper runs.  Other layers' decisions
+        and prices are those of ``predict_conv_time`` alone.
+        """
+        from repro.core.codesign import (
+            narrow_input,
+            predict_conv_time,
+            predict_narrow_conv_time,
+            select_algorithm_by_cost,
+        )
 
         dtype_bytes = _dtype_bytes(dtype)
         if spec.algorithm in (ConvAlgorithm.AUTO, ConvAlgorithm.AUTO_COST):
@@ -660,10 +701,27 @@ class Planner:
                 )
             else:
                 wf = self.winograd_fused
+        foldable = algo is ConvAlgorithm.IM2COL_GEMM or (
+            algo is ConvAlgorithm.WINOGRAD and spec.algorithm in
+            (ConvAlgorithm.AUTO, ConvAlgorithm.AUTO_COST))
+        folded = False
+        if (self.impl == "pallas" and foldable
+                and narrow_input(spec, self.hw)):
+            t_fold = predict_narrow_conv_time(
+                spec, h, w, ConvAlgorithm.IM2COL_GEMM, self.hw, dtype_bytes,
+                batch, taps_folded=True,
+            )
+            folded = t_fold < predict_narrow_conv_time(
+                spec, h, w, algo, self.hw, dtype_bytes, batch,
+                winograd_fused=wf,
+            )
+            if folded:
+                algo, wf = ConvAlgorithm.IM2COL_GEMM, False
         cfg, kernel_blocks = self._resolve_blocks(
-            spec, algo, h, w, batch, dtype_bytes, winograd_fused=wf
+            spec, algo, h, w, batch, dtype_bytes, winograd_fused=wf,
+            taps_folded=folded,
         )
-        t = predict_conv_time(
+        t = t_fold if folded else predict_conv_time(
             spec, h, w, algo, self.hw, dtype_bytes, batch, winograd_fused=wf
         )
         return ConvPlan(
@@ -676,6 +734,7 @@ class Planner:
             fused_epilogue=self.fuse_epilogue,
             winograd_fused=wf,
             dtype=_dtype_name(dtype),
+            taps_folded=folded,
         )
 
     def _tune_int8(self, spec: ConvSpec, h: int, w: int, batch: int) -> ConvPlan:
@@ -771,23 +830,30 @@ class Planner:
                 ),
                 activation="relu",
             )
+        from repro.core.codesign import narrow_input
+
         best: Tuple[Optional[ConvPlan], float] = (None, float("inf"))
         candidates = []
         for algo in _eligible_algorithms(spec):
             if algo is ConvAlgorithm.WINOGRAD:
                 if self.winograd_fused is not None:
-                    candidates.append((algo, self.winograd_fused))
+                    candidates.append((algo, self.winograd_fused, False))
                 elif self.impl == "pallas":
                     # Both realizations exist only on the Pallas path: time
                     # the fused megakernel against the 3-pass pipeline.
-                    candidates += [(algo, True), (algo, False)]
+                    candidates += [(algo, True, False), (algo, False, False)]
                 else:
-                    candidates.append((algo, True))
+                    candidates.append((algo, True, False))
             else:
-                candidates.append((algo, False))
-        for algo, wf in candidates:
+                candidates.append((algo, False, False))
+                if (algo is ConvAlgorithm.IM2COL_GEMM and self.impl == "pallas"
+                        and narrow_input(spec, self.hw)):
+                    # Tap folding exists only on the Pallas path.
+                    candidates.append((algo, False, True))
+        for algo, wf, tf in candidates:
             cfg, kernel_blocks = self._resolve_blocks(
-                spec, algo, h, w, batch, dtype_bytes, winograd_fused=wf
+                spec, algo, h, w, batch, dtype_bytes, winograd_fused=wf,
+                taps_folded=tf,
             )
             candidate = ConvPlan(
                 algorithm=algo,
@@ -799,6 +865,7 @@ class Planner:
                 fused_epilogue=self.fuse_epilogue,
                 winograd_fused=wf,
                 dtype=_dtype_name(dtype),
+                taps_folded=tf,
             )
             fn = jax.jit(
                 lambda a, b, p=candidate: conv2d(a, b, spec, plan=p,

@@ -26,6 +26,13 @@ class ChipSpec:
     sublanes: int = 8                    # VREG second-minor granularity
     lane_width: int = 128                # VREG minor (lane) granularity
     grid_step_overhead_s: float = 0.3e-6 # per-grid-step issue/DMA overhead
+    # XLA's data movement around narrow-channel convs, measured on a v5e
+    # (PERF.md §6): pads and stride-phase splits of lane-padded maps
+    # (4-byte elements at ~235 GB/s), and the concatenation of narrow
+    # channel slices along the lanes that builds a patch matrix (~44 GB/s
+    # of its unpadded elements).
+    relayout_bandwidth: float = 235e9
+    lane_concat_bandwidth: float = 44e9
 
 
 V5E = ChipSpec()

@@ -3,7 +3,8 @@
 Routes per the paper's selector: 1x1 -> blocked GEMM (direct), 3x3 stride-1
 -> Winograd kernels, everything else -> fused im2col+GEMM kernel.  When a
 ``ConvPlan`` is supplied the kernels run with its autotuned block sizes
-instead of their built-in heuristics.
+instead of their built-in heuristics, and an im2col plan that folds its taps
+runs the patch matrix through the blocked GEMM kernel instead.
 
 With an explicit ``Layout`` pair (core/netplan.py) the dispatcher runs the
 network executor's contract instead of the self-contained wrappers: the
@@ -110,6 +111,21 @@ def conv2d_pallas(
             vmem_budget=vmem_budget,
         )
 
+    if plan is not None and plan.taps_folded:
+        from repro.kernels.im2col_gemm.ops import (
+            conv2d_folded_call,
+            fold_weights,
+            folded_k,
+        )
+
+        assert scale is None, "int8 im2col runs tap-wise"
+        kp = folded_k(spec, x.shape[-1], blocks[2])
+        out = conv2d_folded_call(
+            x, fold_weights(w, kp), spec, blocks, interpret=interpret,
+            bias=bias, activation=activation,
+        )
+        return out[..., :spec.out_channels]
+
     from repro.kernels.im2col_gemm import conv2d_pallas_im2col
 
     return conv2d_pallas_im2col(
@@ -138,11 +154,12 @@ def _conv2d_pallas_laidout(
 
     Contract (enforced by core/netplan): ``x``'s channel count equals
     ``in_layout.phys_c`` and divides the plan's channel block; ``w``/``bias``
-    were padded offline to (in phys, out phys); the out-channel padding is
-    zeros-in → act(0 + 0) = 0 out, so a deferred crop is exact.  Whatever
-    padding remains here (row-tile tails, tile-count alignment, the M tail
-    of the direct GEMM) is intra-layer data movement the boundary cannot
-    remove.
+    were padded offline to (in phys, out phys), and a plan that folds its
+    taps gets ``w`` folded offline to (folded K, out phys); the out-channel
+    padding is zeros-in → act(0 + 0) = 0 out, so a deferred crop is exact.
+    Whatever padding remains here (row-tile tails, tile-count alignment, the
+    M tail of the direct GEMM, a folded patch matrix's K and M tails) is
+    intra-layer data movement the boundary cannot remove.
     """
     o_keep = (
         out_layout.phys_c
@@ -151,6 +168,19 @@ def _conv2d_pallas_laidout(
     )
     if in_layout is not None:
         assert x.shape[-1] == in_layout.phys_c, (x.shape, in_layout)
+
+    if plan is not None and plan.taps_folded:
+        from repro.kernels.im2col_gemm.ops import conv2d_folded_call, folded_k
+
+        assert scale is None, "int8 im2col runs tap-wise"
+        assert w.shape[0] == folded_k(spec, x.shape[-1], blocks[2]), (
+            w.shape, x.shape)
+        out = conv2d_folded_call(
+            x, w, spec, blocks, interpret=interpret, bias=bias,
+            activation=activation,
+        )
+        return out[..., :o_keep] if out.shape[-1] != o_keep else out
+
     assert w.shape[2] == x.shape[-1], (w.shape, x.shape)
 
     if algo is ConvAlgorithm.DIRECT:

@@ -1,4 +1,4 @@
-"""Jitted wrapper for the fused im2col+GEMM conv kernel.
+"""Jitted wrapper for the fused im2col+GEMM conv kernel, and tap folding.
 
 Pads input/weights to HW-aligned block multiples, picks block sizes from the
 co-design model (channel blocks sized so the *full* per-program footprint —
@@ -10,6 +10,15 @@ The pad/crop bookkeeping is split out of the jitted body
 network executor (core/netplan.py) can own the layer boundaries: a planned
 network pads once at entry, flows block-padded activations between layers,
 and crops once at exit.
+
+Tap folding is the other realization of im2col+GEMM, for convs whose input
+channels are narrower than a lane block: the fused kernel would multiply
+every tap's channels padded to 128 lanes (a 3-channel RGB stem does 43x its
+work), so the wrapper builds the (B*OH*OW, kh*kw*C) patch matrix once from
+the logical channels and runs the blocked GEMM kernel (kernels/gemm) on it
+with the weights folded to match.  K is one full-dimension block where it
+fits the GEMM's K block, so XLA writes the matrix K-minor in one pass with
+no pad; a deeper K is padded once to the lane width.
 """
 from __future__ import annotations
 
@@ -20,12 +29,14 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.conv_spec import ConvSpec
+from repro.core.im2col import im2col
 from repro.core.vmem_model import (
     ACC_BYTES,
     im2col_kernel_vmem_bytes,
     im2col_window,
 )
 from repro.hw import V5E
+from repro.kernels.gemm.ops import matmul_padded_call
 from repro.kernels.im2col_gemm.kernel import conv2d_im2col_gemm_pallas
 from repro.util import ceil_to, pad_bias_row
 
@@ -128,6 +139,64 @@ def conv2d_im2col_padded_call(
     if out.shape[1:3] != (oh, ow):
         out = out[:, :oh, :ow]
     return out
+
+
+def folded_k(spec: ConvSpec, cin: int, bk: int) -> int:
+    """The folded contraction depth at GEMM K block ``bk``: the kh*kw*cin
+    taps of one output pixel, one full-dimension block when ``bk`` is that
+    depth (147 for a 7x7 RGB stem), else padded to the lane width."""
+    k = spec.kh * spec.kw * cin
+    return k if bk == k else ceil_to(k, V5E.lane_width)
+
+
+def fold_weights(w: jnp.ndarray, kp: int) -> jnp.ndarray:
+    """(kh, kw, C, O) -> (kp, O): one row per tap and channel, in the
+    (kh, kw, c) order ``core.im2col.im2col`` gives the patch columns, with
+    zero rows to the folded depth ``kp``."""
+    kh, kw, c, o = w.shape
+    k = kh * kw * c
+    return jnp.pad(w.reshape(k, o), ((0, kp - k), (0, 0)))
+
+
+def conv2d_folded_call(
+    x: jnp.ndarray,
+    w_f: jnp.ndarray,
+    spec: ConvSpec,
+    blocks: Tuple[int, int, int],
+    interpret: bool = False,
+    bias: Optional[jnp.ndarray] = None,
+    activation: str = "linear",
+) -> jnp.ndarray:
+    """Tap folding: x (B, H, W, C) at its logical channels and ``w_f`` from
+    ``fold_weights`` -> (B, OH, OW, Np), Np the out channels rounded up to
+    the GEMM's ``bn``.
+
+    The patch matrix is built once (unit-stride slices of the input's
+    stride phases), its K and M tails padded, and the blocked GEMM kernel
+    runs it with the fused bias + activation epilogue.  ``blocks`` are the
+    GEMM's (bm, bn, bk); bk is the folded K or divides it.  The channel
+    crop belongs to the caller.
+    """
+    b, h, w, c = x.shape
+    oh, ow = spec.out_hw(h, w)
+    bm, bn, _ = blocks
+    m, k = b * oh * ow, spec.kh * spec.kw * c
+    kp, n = w_f.shape
+    np_ = ceil_to(n, bn)
+    if np_ != n:
+        w_f = jnp.pad(w_f, ((0, 0), (0, np_ - n)))
+    a = im2col(x, spec.kernel_size, spec.stride, spec.padding,
+               spec.dilation).reshape(m, k)
+    mp = ceil_to(m, bm)
+    if (mp, kp) != (m, k):
+        a = jnp.pad(a, ((0, mp - m), (0, kp - k)))
+    out = matmul_padded_call(
+        a, w_f, blocks, interpret=interpret,
+        bias_p=pad_bias_row(bias, np_), activation=activation,
+    )
+    if mp != m:
+        out = out[:m]
+    return out.reshape(b, oh, ow, np_)
 
 
 @functools.partial(

@@ -159,6 +159,30 @@ def test_clean_plan_kernel_level_zero_findings(model, dtype):
         )
 
 
+def test_folded_stem_descriptor_names_the_gemm_call():
+    """ResNet-50's 7x7/2 stem folds its taps: its expected-call descriptor
+    is the blocked GEMM over the (B*OH*OW, 147) patch matrix, K in one
+    block — name, grid, VMEM and traffic — and the traced forward verifies
+    against it clean."""
+    from repro.analysis.descriptors import step_descriptors
+    from repro.configs import resnet50
+
+    planner = Planner(impl="pallas", cache_path=None)
+    netplan = plan_network(resnet50.LAYERS[:2], 64, 64, planner, batch=2)
+    stem = netplan.steps[0]
+    assert stem.plan.taps_folded and stem.in_layout.phys_c == 3
+    bm, bn, bk = stem.plan.kernel_blocks
+    (desc,) = step_descriptors(netplan, stem)
+    assert desc["family"] == "gemm"
+    assert desc["name"] == "_matmul_bias_kernel_6loop"
+    assert bk == 147
+    assert desc["grid"] == (-(-2 * 32 * 32 // bm), 128 // bn, 1)
+    assert desc["k_elems"] == 147
+    report = _verify(netplan)
+    assert report.ok and not report.findings, report.findings
+    assert [tuple(r["grid"]) for r in report.kernels] == [desc["grid"]]
+
+
 def test_plan_level_zero_findings():
     """Plan-level (no trace) verification is also green, and cheap enough
     that it never needs prepared parameters."""

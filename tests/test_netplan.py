@@ -181,10 +181,14 @@ def test_row_tile_snapped_to_divisor_of_oh():
     )
     planner = Planner(impl="pallas", cache_path=None)
     # 28x28 stride-2 -> OH = 14; an autotuned toh of e.g. 8 would emit 16
-    # rows; the plan must land on a divisor of 14.
-    netplan = plan_network(layers, 28, 28, planner, batch=1)
+    # rows; the plan must land on a divisor of 14.  The input is a lane
+    # block wide: a narrower one folds its taps into one GEMM, which has
+    # no row tile.
+    netplan = plan_network(layers, 28, 28, planner, in_channels=128,
+                           batch=1)
     step = netplan.steps[0]
     assert step.plan.algorithm is ConvAlgorithm.IM2COL_GEMM
+    assert not step.plan.taps_folded
     toh = step.plan.kernel_blocks[0]
     assert step.out_hw[0] % toh == 0, (toh, step.out_hw)
 
@@ -192,7 +196,7 @@ def test_row_tile_snapped_to_divisor_of_oh():
     # (one program per output row); the tuned tile stays and the executor
     # crops the row tail instead.
     prime = (
-        C("conv", out_channels=32, kernel=3, stride=2, activation="leaky"),
+        C("conv", out_channels=128, kernel=3, stride=2, activation="leaky"),
         C("conv", out_channels=32, kernel=5, stride=1, pad=2,
           activation="leaky"),
     )
@@ -201,6 +205,7 @@ def test_row_tile_snapped_to_divisor_of_oh():
                              batch=1)
     step_p = netplan_p.steps[1]        # 149x149 input, 5x5 -> im2col
     assert step_p.plan.algorithm is ConvAlgorithm.IM2COL_GEMM
+    assert not step_p.plan.taps_folded
     assert step_p.out_hw[0] == 149
     assert step_p.plan.kernel_blocks[0] > 1
 

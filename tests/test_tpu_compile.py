@@ -10,8 +10,8 @@ predicts for the kernel's blocks, so a pass also proves the model covers
 what the compiler allocates.  Two sets of kernels are compiled: fixed cases,
 each a layer the compiler once refused or a block shape the Winograd
 kernel tiled in VMEM takes (YOLOv3-20's stem, several images per block),
-among them four of ResNet-50's 1x1 convs at batch 64,
-and every distinct kernel call the planner picks for the benchmark's
+among them four of ResNet-50's 1x1 convs at batch 64 and its 7x7/2 stem
+with the taps folded into one GEMM, and every distinct kernel call the planner picks for the benchmark's
 networks (VGG-16@224 batch 8, YOLOv3-tiny@416 and YOLOv3-20@608 batch 1,
 ResNet-50@224 batch 64; fp32 and int8).
 
@@ -189,9 +189,20 @@ def _gemm_case(m, k, n, dtype):
 def _direct_1x1_case(b, hw, cin, cout, block, dtype):
     """A 1x1 conv planned alone on a (B, hw, hw, cin) map, run by the direct
     GEMM kernel at ``block`` (bm, bn, bk) as the network executor runs it."""
-    model = CNNModel((CNNLayer("conv", out_channels=cout, kernel=1,
-                               activation="relu"),), (hw, hw), cin)
-    (call,) = _planned_calls(model, b, dtype, blocks={0: block}).values()
+    return _single_conv_case(b, hw, cin, cout, 1, 1, block, dtype)
+
+
+def _single_conv_case(b, hw, cin, cout, kernel, stride, block, dtype,
+                      folded=False):
+    """One conv planned alone on a (B, hw, hw, cin) map, run as the network
+    executor runs it at kernel blocks ``block``; ``folded`` says whether
+    the planner folds its taps into one GEMM."""
+    model = CNNModel((CNNLayer("conv", out_channels=cout, kernel=kernel,
+                               stride=stride, activation="relu"),),
+                     (hw, hw), cin)
+    ((key, call),) = _planned_calls(model, b, dtype,
+                                    blocks={0: block}).items()
+    assert key[2] is folded, key
     _, fn, args, modeled = call
     return fn, args, modeled
 
@@ -202,8 +213,9 @@ CASES = {
     "winograd_fused_224x64-64": lambda: _winograd_case(8, 224, 64, 64),
     # The 3-pass pipeline the planner can pin instead, at the same layer.
     "winograd_3pass_224x64-64": lambda: _winograd_3pass_case(8, 224, 64, 64),
-    # Tiled in VMEM: YOLOv3-20's stem, a 610 x 610 x 128 padded window
-    # (3 channels padded to 128) cut 6 tile rows x 8 tile columns at a time.
+    # Tiled in VMEM: YOLOv3-20's stem as the Winograd kernel ran it before
+    # the stem folded its taps, a 610 x 610 x 128 padded window (3 channels
+    # padded to 128) cut 6 tile rows x 8 tile columns at a time.
     "winograd_nhwc_yolov3_20_l0_608x3-32": lambda: _winograd_case(
         1, 608, 3, 32, (128, 128)),
     # Tiled in VMEM, two whole 18x18 images per block (3 x 8 tiles each),
@@ -219,8 +231,9 @@ CASES = {
         for dt in (jnp.float32, jnp.int8)
         for hw, ci, co in ((224, 3, 64), (112, 128, 128), (56, 256, 256))
     },
-    # YOLOv3-20 layer 1: 3x3/s2 at 608, 32 -> 64, over the activation layer 0
-    # left channel-padded to 128.
+    # YOLOv3-20 layer 1 on the tap-wise kernel, as it ran before it folded
+    # its taps: 3x3/s2 at 608, 32 -> 64, over the activation layer 0 left
+    # channel-padded to 128.
     "im2col_yolov3_20_l1_608s2_32-64": functools.partial(
         _im2col_case, 1, 608, 32, 64, 2, jnp.float32, cin_phys=128
     ),
@@ -243,6 +256,12 @@ CASES = {
             (66, 7, 2048, 512, (512, 512, 512)),
         )
     },
+    # ResNet-50's 7x7/2 stem at batch 64 with its taps folded: the GEMM
+    # kernel over the (802816, 147) patch matrix, K in one block.
+    "folded_resnet50_l000_224s2_7x7x3-64": functools.partial(
+        _single_conv_case, 64, 224, 3, 64, 7, 2, (512, 128, 147), "float32",
+        folded=True,
+    ),
 }
 
 
@@ -255,11 +274,13 @@ CASES = {
 #: 0.48 and 1.97 MiB (layer 66 at bk = 1024: 13893632) more than their
 #: buffers and the fixed allowance; of the dot's values, A + B + the fp32
 #: result (2.0 MiB at layer 34) is the least sum that covers layer 34.
+#: ResNet-50's folded stem is the GEMM kernel at (512, 128, 147).
 COMPILER_MIN_VMEM = {
     "direct_resnet50_l004_56x64-256": 2883584,
     "direct_resnet50_l034_28x512-256": 7077888,
     "direct_resnet50_l040_14x1024-256": 8650752,
     "direct_resnet50_l066_7x2048-512": 7602176,
+    "folded_resnet50_l000_224s2_7x7x3-64": 2228224,
     "gemm_float32_8x25088-4096": 8716288,
     "gemm_int8_8x4096-1000": 4325376,
     "im2col_float32_112x128-128": 6291456,
@@ -338,7 +359,8 @@ def _modeled_vmem(netplan, step) -> int:
     """The footprint ``core/vmem_model`` gives the kernel a planned conv step
     runs, at the step's blocks and dtype (the bias, and int8's scale, rows
     included).  A fused Winograd step, which tiles in VMEM, is modeled as a
-    tile block of its bb*k*ntw tiles."""
+    tile block of its bb*k*ntw tiles; a step that folds its taps runs the
+    GEMM kernel."""
     plan, spec = step.plan, step.spec
     d = itemsize(plan.dtype)
     if plan.algorithm is ConvAlgorithm.WINOGRAD:
@@ -347,7 +369,7 @@ def _modeled_vmem(netplan, step) -> int:
         return winograd_kernel_vmem_bytes(math.prod(nhwc) if nhwc else bt,
                                           bc, bo, fused=plan.winograd_fused,
                                           dtype_bytes=d)
-    if plan.algorithm is ConvAlgorithm.DIRECT:
+    if plan.algorithm is ConvAlgorithm.DIRECT or plan.taps_folded:
         bm, bn, bk = plan.kernel_blocks
         return gemm_kernel_vmem_bytes(bm, bn, bk, d,
                                       epilogue_rows=2 if d == 1 else 1)
@@ -389,7 +411,8 @@ def _planned_calls(model, batch, dtype, blocks=None):
         p = params[s.index]
         xs = jax.ShapeDtypeStruct((batch, *s.in_hw, s.in_layout.phys_c),
                                   jnp.float32)
-        key = (s.plan.algorithm.value, s.plan.winograd_fused, s.plan.dtype,
+        key = (s.plan.algorithm.value, s.plan.winograd_fused,
+               s.plan.taps_folded, s.plan.dtype,
                s.spec.kernel_size, s.spec.stride, xs.shape,
                tuple(p["w"].shape), tuple(s.plan.kernel_blocks),
                s.out_layout.phys_c)
