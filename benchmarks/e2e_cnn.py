@@ -93,37 +93,7 @@ def run(
          f"tunes={report['tunes']} hits={report['hits']}",
          provenance={"tunes": report["tunes"], "hits": report["hits"]})
 
-    # -- 1b. fused-vs-3-pass-vs-im2col over the Winograd-eligible layer set --
-    # Modeled totals for the 3x3/stride-1 layers run three ways: im2col+GEMM,
-    # the 3-pass Winograd pipeline (V/M via HBM), and the single-pass fused
-    # megakernel (V/M in VMEM) — the headline single-kernel win.
-    from repro.core.codesign import predict_conv_time
-    from repro.core.conv_spec import ConvAlgorithm, ConvSpec
-    from repro.models.cnn import conv_layer_dims
-
-    t_im2col = t_3pass = t_fused = 0.0
-    n_elig = 0
-    for d in conv_layer_dims(layers, h, w, in_ch):
-        if d["kernel"] != 3 or d["stride"] != 1:
-            continue
-        spec = ConvSpec(d["cin"], d["cout"], (3, 3), (1, 1), (1, 1))
-        t_im2col += predict_conv_time(
-            spec, d["h"], d["w"], ConvAlgorithm.IM2COL_GEMM, batch=batch)
-        t_3pass += predict_conv_time(
-            spec, d["h"], d["w"], ConvAlgorithm.WINOGRAD, batch=batch,
-            winograd_fused=False)
-        t_fused += predict_conv_time(
-            spec, d["h"], d["w"], ConvAlgorithm.WINOGRAD, batch=batch,
-            winograd_fused=True)
-        n_elig += 1
-    if n_elig:
-        emit(f"e2e_{model}_wino_fused_vs_3pass", t_fused,
-             f"3x3s1_layers={n_elig} im2col_s={t_im2col:.6f} "
-             f"3pass_s={t_3pass:.6f} fused_s={t_fused:.6f} "
-             f"fused_vs_3pass={t_3pass / t_fused:.2f}x "
-             f"fused_vs_im2col={t_im2col / t_fused:.2f}x")
-
-    # -- 1c. int8: the quantized compilation's resolved per-layer decisions --
+    # -- 1b. int8: the quantized compilation's resolved per-layer decisions --
     # Modeled (cost-model) rows like section 1 — deterministic, so they land
     # in the committed baseline and the regression gate.  The planner
     # resolves dtype per layer: entry/head layers whose fp32 output writes
@@ -150,7 +120,7 @@ def run(
                      "fp32_predicted_total_s": t32})
     compiled8.save_plans()
 
-    # -- 1d. serving resilience: healthy-path degradation counters -----------
+    # -- 1c. serving resilience: healthy-path degradation counters -----------
     # One request through the serving engine; ``seconds`` is the sum of the
     # resilience degradation counters — 0.0 on a healthy stack — so the
     # regression gate's exact-equality rule for zero-second rows catches a
@@ -174,7 +144,7 @@ def run(
          f"ladder={'>'.join(health['ladder'])}",
          provenance=health)
 
-    # -- 1e. pipeline sweep: cost-balanced stage partitions, modeled ---------
+    # -- 1d. pipeline sweep: cost-balanced stage partitions, modeled ---------
     # Deterministic rows (planner cost model only — no devices needed): the
     # stage partitioner splits the NetworkPlan at legal cut points balancing
     # planner-predicted seconds, and the row's ``seconds`` is the GPipe
@@ -203,7 +173,7 @@ def run(
                 },
             )
 
-    # -- 1f. kernel-interior proofs: zero-cost verification row --------------
+    # -- 1e. kernel-interior proofs: zero-cost verification row --------------
     # The kernel-level static analyzer runs over the *Pallas* compilation of
     # the same network (interpret mode, trace-only — nothing executes), fp32
     # and int8, and ``seconds`` is the total error-finding count: 0.0 while

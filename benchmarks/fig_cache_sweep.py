@@ -11,7 +11,7 @@ from __future__ import annotations
 
 from benchmarks.common import emit, vgg16_gemms, yolov3_20_gemms
 from repro.core.codesign import MB, sweep_cache_size
-from repro.core.vmem_model import GemmShape, winograd_traffic_bytes
+from repro.core.vmem_model import GemmShape
 from repro.core.winograd import winograd_flops
 from repro.hw import V5E
 
@@ -25,6 +25,20 @@ def _im2col_total(layers, budget):
                                budgets=(budget,))[budget]
         total += min(p.estimate.total_s for p in pts)
     return total
+
+
+def _pipeline_traffic_bytes(oh, ow, cin, cout, dtype_bytes=4):
+    """HBM bytes of the paper's Winograd pipeline, one stage after another:
+    the overlapping 8x8 tile reads, the pre-transformed weights (read once)
+    and the output, plus the V and M intermediates, each (64, tiles, C),
+    written by one stage and read by the next."""
+    tiles = -(-oh // 6) * -(-ow // 6)
+    x_bytes = tiles * 64 * cin
+    v_bytes = 2 * tiles * 64 * cin
+    u_bytes = 64 * cin * cout
+    m_bytes = 2 * tiles * 64 * cout
+    y_bytes = tiles * 36 * cout
+    return dtype_bytes * (x_bytes + v_bytes + u_bytes + m_bytes + y_bytes)
 
 
 def _winograd_total(layers, budget):
@@ -44,7 +58,7 @@ def _winograd_total(layers, budget):
         pts = sweep_cache_size(GemmShape(tiles, d["cout"], d["cin"]),
                                budgets=(budget,))[budget]
         tuple_t = 64 * min(p.estimate.total_s for p in pts)
-        tf_t = (winograd_traffic_bytes(oh, ow, d["cin"], d["cout"])
+        tf_t = (_pipeline_traffic_bytes(oh, ow, d["cin"], d["cout"])
                 / V5E.hbm_bandwidth
                 + fl["transform_flops"] / V5E.peak_flops_fp32)
         total += tuple_t + tf_t

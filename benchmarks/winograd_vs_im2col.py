@@ -7,11 +7,10 @@ Three measurements here:
   1. MEASURED on this CPU: jitted pure-JAX winograd vs im2col conv at real
      YOLOv3/VGG16 layer sizes (XLA:CPU timing is a proxy, but the FLOP
      advantage is algorithm-level and shows through).
-  2. MODELED for TPU v5e: FLOP+traffic roofline of im2col vs the 3-pass
-     Winograd pipeline (V/M round-trip HBM) vs the single-pass fused
-     megakernel (V/M stay in VMEM) — each Winograd variant at the block
-     tuple the planner autotuned for it, resolved through the persistent
-     plan cache (a second resolve must re-tune nothing).
+  2. MODELED for TPU v5e: FLOP+traffic roofline of im2col vs the Winograd
+     megakernel (V/M stay in VMEM) at the block tuple the planner autotuned
+     for it, resolved through the persistent plan cache (a second resolve
+     must re-tune nothing).
   3. Network-level Amdahl projection from the eligible-FLOPs fraction.
 
 Run directly:  PYTHONPATH=src python -m benchmarks.winograd_vs_im2col
@@ -27,7 +26,6 @@ from benchmarks.common import emit, time_jit, vgg16_gemms, yolov3_20_gemms
 from repro.core.conv_spec import ConvAlgorithm, ConvSpec
 from repro.core.im2col import conv2d_im2col
 from repro.core.winograd import conv2d_winograd, transform_weights
-from repro.core.vmem_model import predict_winograd
 
 # Representative 3x3/stride-1 YOLOv3 layers (paper's winograd-eligible set).
 LAYER_SET = [
@@ -54,38 +52,22 @@ def _measured(layer) -> tuple:
 
 
 def _modeled(layer, planner) -> tuple:
-    """v5e modeled seconds + plans for one layer.
+    """v5e modeled seconds + plan for one layer.
 
-    Returns ``(t_i, t_w3, t_wf, ratio_f3, plan_f, plan_3)``: im2col, 3-pass
-    winograd and fused-megakernel roofline seconds from the repo's shared
-    model (``predict_conv_time`` — the same numbers the planner's algorithm
-    selection and e2e_cnn.py use, so the rows are mutually consistent), plus
-    the fused-vs-3-pass ratio from the block-aware ``predict_winograd``
-    estimates at each realization's planner-autotuned tuple (same model
-    fidelity on both sides of *that* ratio: panel re-reads + grid startup).
+    Returns ``(t_i, t_w, plan)``: im2col and Winograd roofline seconds from
+    the repo's shared model (``predict_conv_time`` — the same numbers the
+    planner's algorithm selection and e2e_cnn.py use, so the rows are
+    mutually consistent), plus the Winograd plan, which round-trips through
+    the shared persistent cache.
     """
     from repro.core.codesign import predict_conv_time
 
     h, w, cin, cout = layer["h"], layer["w"], layer["cin"], layer["cout"]
     spec = ConvSpec(cin, cout, (3, 3), (1, 1), (1, 1),
                     algorithm=ConvAlgorithm.WINOGRAD)
-    oh, ow = spec.out_hw(h, w)
-    tiles = -(-oh // 6) * -(-ow // 6)
-
     t_i = predict_conv_time(spec, h, w, ConvAlgorithm.IM2COL_GEMM)
-    t_w3 = predict_conv_time(spec, h, w, ConvAlgorithm.WINOGRAD,
-                             winograd_fused=False)
-    t_wf = predict_conv_time(spec, h, w, ConvAlgorithm.WINOGRAD,
-                             winograd_fused=True)
-    # Each realization runs at the block tuple the planner tuned *for it*
-    # (the fused megakernel budgets its M-accumulator scratch, so the tuples
-    # can differ); plans round-trip through the shared persistent cache.
-    plan_f = planner["fused"].plan(spec, h, w)
-    plan_3 = planner["3pass"].plan(spec, h, w)
-    est_f = predict_winograd(tiles, cin, cout, plan_f.kernel_blocks, fused=True)
-    est_3 = predict_winograd(tiles, cin, cout, plan_3.kernel_blocks, fused=False)
-    ratio_f3 = est_3.total_s / est_f.total_s
-    return t_i, t_w3, t_wf, ratio_f3, plan_f, plan_3
+    t_w = predict_conv_time(spec, h, w, ConvAlgorithm.WINOGRAD)
+    return t_i, t_w, planner.plan(spec, h, w)
 
 
 def run(layers: int | None = None, modeled_only: bool = False,
@@ -93,18 +75,13 @@ def run(layers: int | None = None, modeled_only: bool = False,
     from repro.core.planner import DEFAULT_CACHE_PATH, Planner
 
     cache = cache_path if cache_path is not None else DEFAULT_CACHE_PATH
-    # autosave=False: one merge+write per planner after the layer loop,
-    # not one locked read-merge-rewrite of the shared file per miss.
-    planners = {
-        "fused": Planner(cache_path=cache, winograd_fused=True,
-                         autosave=False),
-        "3pass": Planner(cache_path=cache, winograd_fused=False,
-                         autosave=False),
-    }
+    # autosave=False: one merge+write after the layer loop, not one locked
+    # read-merge-rewrite of the shared file per miss.
+    planner = Planner(cache_path=cache, autosave=False)
     layer_set = LAYER_SET[:layers] if layers is not None else LAYER_SET
     ratios_m = []
     for layer in layer_set:
-        m_i, m_w3, m_wf, ratio_f3, plan_f, plan_3 = _modeled(layer, planners)
+        m_i, m_w, plan = _modeled(layer, planner)
         t_i, t_w = (0.0, 0.0) if modeled_only else _measured(layer)
         if not modeled_only:
             ratios_m.append(t_i / t_w)
@@ -113,31 +90,22 @@ def run(layers: int | None = None, modeled_only: bool = False,
             t_w,
             (f"im2col_s={t_i:.4f};measured_speedup="
              f"{(t_i / t_w) if t_w else 0:.2f};"
-             f"v5e_3pass_speedup={m_i / m_w3:.2f};"
-             f"v5e_fused_speedup={m_i / m_wf:.2f};"
-             f"fused_vs_3pass={ratio_f3:.2f};"
-             f"fused_blocks={'x'.join(map(str, plan_f.kernel_blocks))};"
-             f"3pass_blocks={'x'.join(map(str, plan_3.kernel_blocks))};"
+             f"v5e_fused_speedup={m_i / m_w:.2f};"
+             f"fused_blocks={'x'.join(map(str, plan.kernel_blocks))};"
              f"paper=2.4"),
         )
 
-    planners["fused"].save()
-    planners["3pass"].save()
+    planner.save()
 
-    # Warm-cache proof: fresh planners on the same file re-tune nothing.
-    warm = {
-        "fused": Planner(cache_path=cache, winograd_fused=True),
-        "3pass": Planner(cache_path=cache, winograd_fused=False),
-    }
+    # Warm-cache proof: a fresh planner on the same file re-tunes nothing.
+    warm = Planner(cache_path=cache)
     for layer in layer_set:
         spec = ConvSpec(layer["cin"], layer["cout"], (3, 3), (1, 1), (1, 1),
                         algorithm=ConvAlgorithm.WINOGRAD)
-        warm["fused"].plan(spec, layer["h"], layer["w"])
-        warm["3pass"].plan(spec, layer["h"], layer["w"])
-    retunes = warm["fused"].stats["tunes"] + warm["3pass"].stats["tunes"]
+        warm.plan(spec, layer["h"], layer["w"])
+    retunes = warm.stats["tunes"]
     emit("winograd/warm_retunes", 0.0,
-         f"retunes={retunes};hits="
-         f"{warm['fused'].stats['hits'] + warm['3pass'].stats['hits']}")
+         f"retunes={retunes};hits={warm.stats['hits']}")
     assert retunes == 0, "warm winograd plan cache re-tuned — persistence broken"
 
     if modeled_only:

@@ -46,9 +46,9 @@ def step_descriptors(
     """The pallas_call descriptor list one conv step emits (program order).
 
     Empty for non-conv and non-pallas steps (fc layers run as plain XLA
-    dots).  One descriptor for direct/im2col/fused-Winograd, three for the
-    3-pass Winograd pipeline; an im2col step that folds its taps names the
-    GEMM call it runs on the (B*OH*OW, folded K) patch matrix.
+    dots).  One descriptor for direct/im2col/Winograd; an im2col step that
+    folds its taps names the GEMM call it runs on the (B*OH*OW, folded K)
+    patch matrix.
     """
     if not planned_pallas(step):
         return []
@@ -82,8 +82,7 @@ def step_descriptors(
 
         descs = winograd_call_descriptors(
             b, oh, ow, cp, ceil_to(o_phys, blocks[2]), blocks,
-            bias=True, fused=bool(plan.winograd_fused), dtype_bytes=d,
-            vmem_budget=netplan.vmem_budget,
+            bias=True, vmem_budget=netplan.vmem_budget,
         )
         return [dict(x, step=step.index) for x in descs]
 
@@ -124,7 +123,6 @@ def ideal_traffic_bytes(netplan: NetworkPlan, step: NetStep) -> Optional[int]:
         return winograd_traffic_bytes(
             oh, ow, spec.in_channels, spec.out_channels,
             batch=netplan.batch, dtype_bytes=d,
-            fused=bool(plan.winograd_fused),
         )
     return im2col_gemm_traffic_bytes(
         oh, ow, spec.in_channels, spec.out_channels, spec.kh, spec.kw,

@@ -403,7 +403,6 @@ class CompiledCNN(CompiledModel):
                 "kernel_blocks": list(s.plan.kernel_blocks),
                 "predicted_s": s.plan.predicted_s,
                 "source": s.plan.source,
-                "winograd_fused": s.plan.winograd_fused,
                 "taps_folded": s.plan.taps_folded,
                 "elided": not s.out_layout.trivial,
             }

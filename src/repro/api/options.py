@@ -34,8 +34,6 @@ class ExecutionOptions:
       cache_path      persistent v4 plan-cache JSON (None = no persistence).
       vmem_budget     VMEM bytes for block autotuning (None = chip default).
       fuse_epilogue   bias + activation fused into the kernels' output stage.
-      winograd_fused  single-pass Winograd megakernel policy: None = auto
-                      (tuner decides), True/False = forced.
 
     Execution:
       interpret       run Pallas kernels in interpret mode (None = auto:
@@ -110,7 +108,6 @@ class ExecutionOptions:
     cache_path: Optional[str] = DEFAULT_CACHE_PATH
     vmem_budget: Optional[int] = None
     fuse_epilogue: bool = True
-    winograd_fused: Optional[bool] = None
     pretransform: bool = True
     batch: int = 1
     buckets: Tuple[int, ...] = (1, 4, 8)
@@ -225,5 +222,4 @@ class ExecutionOptions:
             vmem_budget=self.vmem_budget,
             autosave=False,
             fuse_epilogue=self.fuse_epilogue,
-            winograd_fused=self.winograd_fused,
         )

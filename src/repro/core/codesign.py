@@ -107,17 +107,14 @@ def predict_conv_time(
     hw: ChipSpec = V5E,
     dtype_bytes: int = 4,
     batch: int = 1,
-    winograd_fused: bool = True,
 ) -> float:
     """Modeled seconds for one conv layer executed with ``algorithm``.
 
     Roofline time max(compute, HBM traffic) at this layer's dims.  GEMM-family
     algorithms (direct / im2col) move the patch matrix, the weights and the
-    output; Winograd moves the tile/transform pipeline — by default the
-    single-pass megakernel's traffic (transforms and M accumulation fused in
-    VMEM, ``winograd_fused=True``), or the 3-pass pipeline's traffic with the
-    V/M HBM round-trips (``winograd_fused=False``).  Activation terms scale
-    with ``batch``; weight terms do not.
+    output; Winograd moves the megakernel's tile reads, transformed weights
+    and output (transforms and M accumulation stay in VMEM).  Activation
+    terms scale with ``batch``; weight terms do not.
 
     Itemsize-aware: ``dtype_bytes`` prices the operand traffic and picks the
     fp32/bf16/int8 MXU peak; the output write is priced separately because
@@ -137,7 +134,7 @@ def predict_conv_time(
 
         fl = winograd_flops(oh, ow, cin, cout)
         wino_bytes = winograd_traffic_bytes(
-            oh, ow, cin, cout, batch, dtype_bytes, fused=winograd_fused
+            oh, ow, cin, cout, batch, dtype_bytes
         )
         return max(batch * fl["winograd_flops"] / peak, wino_bytes / bw)
     # direct-1x1 and im2col share the GEMM roofline; direct just has K = Cin.
@@ -162,7 +159,6 @@ def predict_narrow_conv_time(
     hw: ChipSpec = V5E,
     dtype_bytes: int = 4,
     batch: int = 1,
-    winograd_fused: bool = True,
     taps_folded: bool = False,
 ) -> float:
     """Modeled seconds of a narrow-input conv at the work each realization
@@ -178,8 +174,7 @@ def predict_narrow_conv_time(
     IM2COL_GEMM) and the Winograd kernel multiply the input channels padded
     to a lane block: the wrapper writes that padded copy (one pass; the
     im2col wrapper splits a stride into phases, two more), and the kernel
-    reads it once per tap window, or as overlapping 8x8 tiles (plus V and
-    M through HBM when not ``winograd_fused``).  Tap folding
+    reads it once per tap window, or as overlapping 8x8 tiles.  Tap folding
     (``taps_folded``) multiplies K = kh*kw*C rounded up to the lane width
     once, over a (B*OH*OW, K) patch matrix it writes and reads, which XLA
     builds by concatenating the taps' narrow channel slices along the
@@ -212,8 +207,6 @@ def predict_narrow_conv_time(
             tiles = batch * -(-oh // 6) * -(-ow // 6)
             flops = 2.0 * tiles * 64 * cp * op
             elems += x_p + tiles * 64 * cp + 64 * cp * op
-            if not winograd_fused:
-                elems += 2 * tiles * 64 * (cp + op)
             moved = x_p / hw.relayout_bandwidth
         else:
             flops = 2.0 * m * taps * cp * op
@@ -227,7 +220,7 @@ def predict_narrow_conv_time(
 
 def select_algorithm_by_cost(
     spec: ConvSpec, h: int, w: int, hw: ChipSpec = V5E, dtype_bytes: int = 4,
-    winograd_fused: bool = True, batch: int = 1,
+    batch: int = 1,
 ):
     """Roofline-model-driven per-layer algorithm choice (beyond paper).
 
@@ -235,9 +228,7 @@ def select_algorithm_by_cost(
     (critical AI ~120 fp32) that rule over-triggers: Winograd's 64/9x
     weight-traffic inflation loses for deep low-resolution layers.  This
     selector compares modeled times of im2col+GEMM vs the Winograd
-    realization that would actually run (``winograd_fused``: the single-pass
-    megakernel by default, the 3-pass pipeline when a planner forces it)
-    and picks the winner.
+    megakernel and picks the winner.
     """
     from repro.core.conv_spec import ConvAlgorithm, select_algorithm
 
@@ -245,8 +236,7 @@ def select_algorithm_by_cost(
     if base is not ConvAlgorithm.WINOGRAD:
         return base
     t_wino = predict_conv_time(
-        spec, h, w, ConvAlgorithm.WINOGRAD, hw, dtype_bytes, batch,
-        winograd_fused=winograd_fused,
+        spec, h, w, ConvAlgorithm.WINOGRAD, hw, dtype_bytes, batch
     )
     t_im2col = predict_conv_time(
         spec, h, w, ConvAlgorithm.IM2COL_GEMM, hw, dtype_bytes, batch

@@ -420,8 +420,6 @@ def network_key(
             "net", digest, f"h{h}w{w}", f"ci{in_channels}", f"b{batch}",
             planner.hw.name, dtype_name, planner.impl, planner.mode,
             f"e{int(planner.fuse_epilogue)}",
-            "wf" + ("a" if planner.winograd_fused is None
-                    else str(int(planner.winograd_fused))),
             f"v{planner.vmem_budget}",
         ]
     )
@@ -1082,7 +1080,7 @@ def winograd_step_tiling(netplan: NetworkPlan, step: NetStep,
 
     return winograd_tiling(
         netplan.batch // shards, *step.out_hw, tuple(step.plan.kernel_blocks),
-        fused=step.plan.winograd_fused, vmem_budget=netplan.vmem_budget,
+        vmem_budget=netplan.vmem_budget,
     )
 
 
@@ -1108,7 +1106,6 @@ def layer_table(netplan: NetworkPlan, shards: int = 1) -> Dict[str, Any]:
     plan's ``predicted_s``, under the forward's jitted name; and ``input``,
     the forward's input argument, whose name XLA gives the copy that lays
     the input out for the first layer.  A Pallas Winograd step also says
-    where its tiles are cut (``tiling``: ``"vmem"`` or ``"hbm"``) and
     ``tile_ratio``, the tiles its kernel computes over the real B*nTH*nTW
     (None on other steps), at the batch of one of ``shards`` devices.
     An im2col step says whether it folds its taps into one GEMM
@@ -1136,7 +1133,6 @@ def layer_table(netplan: NetworkPlan, shards: int = 1) -> Dict[str, Any]:
             "kind": s.layer.kind,
             "algorithm": algo,
             "predicted_s": s.plan.predicted_s if s.plan is not None else None,
-            "tiling": tiling.name if tiling is not None else None,
             "tile_ratio": tiling.ratio if tiling is not None else None,
             "taps_folded": (s.plan.taps_folded if s.plan is not None and algo
                             == ConvAlgorithm.IM2COL_GEMM.value else None),

@@ -63,8 +63,10 @@ from repro.util import ceil_to
 # v8: an im2col plan may fold its taps into one GEMM contraction
 # (``ConvPlan.taps_folded``), which changes the algorithm, blocks and
 # layouts of narrow-input layers; a v7 plan or network entry would be read
-# back as tap-wise, so v7 caches re-tune once.
-PLAN_CACHE_VERSION = 8
+# back as tap-wise, so v7 caches re-tune once.  v9: the Pallas path has one
+# Winograd kernel, so keys drop the realization policy's ``wf`` token and
+# plans its flag; v8 caches re-plan to the same plans under the new keys.
+PLAN_CACHE_VERSION = 9
 
 # Default on-disk location (overridable per Planner and via environment).
 DEFAULT_CACHE_PATH = os.environ.get(
@@ -219,7 +221,7 @@ class ConvPlan:
     block sizes, VMEM in the role of L2).  ``kernel_blocks`` is what the
     Pallas wrappers actually consume — (bm, bn, bk) for the direct GEMM and
     for tap folding, (toh, bc, bo) for the fused im2col kernel, (bt, bc, bo)
-    for the Winograd pipeline.  ``taps_folded`` marks the im2col realization
+    for the Winograd kernel.  ``taps_folded`` marks the im2col realization
     that builds the patch matrix once from the input's logical channels and
     runs one GEMM over K = kh*kw*C (kernels/im2col_gemm/ops.py), chosen for
     inputs narrower than a lane block.  ``predicted_s`` is modeled seconds
@@ -234,8 +236,6 @@ class ConvPlan:
     predicted_s: float
     source: str = "cost_model"          # cost_model | measured
     fused_epilogue: bool = False        # bias+activation fused in the kernel
-    winograd_fused: bool = False        # single-pass Winograd megakernel
-                                        # (vs the 3-pass V/M-via-HBM pipeline)
     dtype: str = "float32"              # resolved execution precision; under
                                         # an int8 request this may stay
                                         # 'float32' (quantization policy)
@@ -250,7 +250,6 @@ class ConvPlan:
             "predicted_s": self.predicted_s,
             "source": self.source,
             "fused_epilogue": self.fused_epilogue,
-            "winograd_fused": self.winograd_fused,
             "dtype": self.dtype,
             "taps_folded": self.taps_folded,
         }
@@ -265,7 +264,6 @@ class ConvPlan:
             predicted_s=float(d["predicted_s"]),
             source=d.get("source", "cost_model"),
             fused_epilogue=bool(d.get("fused_epilogue", False)),
-            winograd_fused=bool(d.get("winograd_fused", False)),
             dtype=d.get("dtype", "float32"),
             taps_folded=bool(d.get("taps_folded", False)),
         )
@@ -282,14 +280,8 @@ def plan_key(
     mode: str = "cost",
     vmem_budget: Optional[int] = None,
     fuse_epilogue: bool = False,
-    winograd_fused: Optional[bool] = None,
 ) -> str:
-    """Canonical cache key: every field that changes the decision.
-
-    ``winograd_fused`` is the planner's *policy* (None = auto: the tuner
-    picks fused vs 3-pass; True/False = forced), not the resolved decision —
-    an auto planner must never reuse a plan tuned under a forced policy.
-    """
+    """Canonical cache key: every field that changes the decision."""
     return "|".join(
         [
             chip,
@@ -297,7 +289,6 @@ def plan_key(
             impl,
             mode,
             f"e{int(fuse_epilogue)}",
-            f"wf{'a' if winograd_fused is None else int(winograd_fused)}",
             f"v{vmem_budget if vmem_budget is not None else 0}",
             f"b{batch}",
             f"h{h}w{w}",
@@ -369,7 +360,6 @@ class Planner:
         measure_reps: int = 3,
         autosave: bool = True,
         fuse_epilogue: bool = False,
-        winograd_fused: Optional[bool] = None,
     ):
         if mode not in ("cost", "measure"):
             raise ValueError(f"mode must be 'cost' or 'measure', got {mode!r}")
@@ -380,11 +370,6 @@ class Planner:
         # the epilogue inside the kernel exactly when the plan was tuned
         # that way; keyed separately in the cache.
         self.fuse_epilogue = fuse_epilogue
-        # Winograd realization policy: None lets the tuner choose between
-        # the single-pass fused megakernel and the 3-pass pipeline (cost
-        # mode compares modeled traffic; measure mode on the pallas impl
-        # times both); True/False forces one realization.
-        self.winograd_fused = winograd_fused
         self.cache_path = cache_path
         self.vmem_budget = vmem_budget if vmem_budget is not None else hw.vmem_bytes
         self.measure_reps = measure_reps
@@ -570,7 +555,6 @@ class Planner:
         key = plan_key(
             spec, h, w, batch, self.hw.name, _dtype_name(dtype), self.impl,
             self.mode, self.vmem_budget, self.fuse_epilogue,
-            self.winograd_fused,
         )
         cached = self._plans.get(key)
         if cached is not None:
@@ -601,7 +585,6 @@ class Planner:
         w: int,
         batch: int,
         dtype_bytes: int,
-        winograd_fused: bool = True,
         taps_folded: bool = False,
     ) -> Tuple[BlockConfig, Tuple[int, int, int]]:
         """(GEMM BlockConfig, kernel block tuple) for one algorithm choice.
@@ -609,10 +592,9 @@ class Planner:
         The BlockConfig is autotuned on the GEMM exactly as the kernel runs
         it (direct: (B*OH*OW, O, C); im2col: K = kh*kw*C; winograd: the
         per-position tuple multiply (tiles, O, C)).  Winograd kernel blocks
-        (bt, bc, bo) are autotuned per realization — the fused megakernel's
-        M-accumulator scratch (8*8*bt*bo*4 bytes) is budgeted alongside the
-        tile and weight blocks, so the fused and 3-pass variants can land on
-        different tuples.  Tap folding runs the GEMM kernel itself, on the
+        (bt, bc, bo) are autotuned with the megakernel's M-accumulator
+        scratch (8*8*bt*bo*4 bytes) budgeted alongside the tile and weight
+        blocks.  Tap folding runs the GEMM kernel itself, on the
         same BlockConfig, with bk the folded K or a divisor of it.
         """
         oh, ow = spec.out_hw(h, w)
@@ -636,7 +618,6 @@ class Planner:
 
             kernel_blocks, _ = autotune_winograd_blocks(
                 shape.m, cin, cout, self.hw, self.vmem_budget, dtype_bytes,
-                fused=winograd_fused,
             )
         elif taps_folded:
             kernel_blocks = (cfg.bm, cfg.bn,
@@ -674,33 +655,13 @@ class Planner:
 
         dtype_bytes = _dtype_bytes(dtype)
         if spec.algorithm in (ConvAlgorithm.AUTO, ConvAlgorithm.AUTO_COST):
-            # Selection must model the Winograd realization this planner's
-            # policy would actually run: a forced-3-pass planner competes
-            # im2col against the 3-pass pipeline, not the megakernel.
-            # Batch matters too: the im2col-vs-winograd crossover shifts as
+            # Batch matters: the im2col-vs-winograd crossover shifts as
             # activation traffic amortizes the weight term.
             algo = select_algorithm_by_cost(
-                spec, h, w, self.hw, dtype_bytes,
-                winograd_fused=(self.winograd_fused
-                                if self.winograd_fused is not None else True),
-                batch=batch,
+                spec, h, w, self.hw, dtype_bytes, batch=batch,
             )
         else:
             algo = select_algorithm(spec)
-        wf = False
-        if algo is ConvAlgorithm.WINOGRAD:
-            if self.winograd_fused is None:
-                # Auto: the megakernel wins whenever its eliminated V/M
-                # round-trips beat the 3-pass pipeline's modeled time.
-                wf = predict_conv_time(
-                    spec, h, w, algo, self.hw, dtype_bytes, batch,
-                    winograd_fused=True,
-                ) <= predict_conv_time(
-                    spec, h, w, algo, self.hw, dtype_bytes, batch,
-                    winograd_fused=False,
-                )
-            else:
-                wf = self.winograd_fused
         foldable = algo is ConvAlgorithm.IM2COL_GEMM or (
             algo is ConvAlgorithm.WINOGRAD and spec.algorithm in
             (ConvAlgorithm.AUTO, ConvAlgorithm.AUTO_COST))
@@ -713,16 +674,14 @@ class Planner:
             )
             folded = t_fold < predict_narrow_conv_time(
                 spec, h, w, algo, self.hw, dtype_bytes, batch,
-                winograd_fused=wf,
             )
             if folded:
-                algo, wf = ConvAlgorithm.IM2COL_GEMM, False
+                algo = ConvAlgorithm.IM2COL_GEMM
         cfg, kernel_blocks = self._resolve_blocks(
-            spec, algo, h, w, batch, dtype_bytes, winograd_fused=wf,
-            taps_folded=folded,
+            spec, algo, h, w, batch, dtype_bytes, taps_folded=folded,
         )
         t = t_fold if folded else predict_conv_time(
-            spec, h, w, algo, self.hw, dtype_bytes, batch, winograd_fused=wf
+            spec, h, w, algo, self.hw, dtype_bytes, batch
         )
         return ConvPlan(
             algorithm=algo,
@@ -732,7 +691,6 @@ class Planner:
             predicted_s=t,
             source="cost_model",
             fused_epilogue=self.fuse_epilogue,
-            winograd_fused=wf,
             dtype=_dtype_name(dtype),
             taps_folded=folded,
         )
@@ -771,14 +729,11 @@ class Planner:
             algo = ConvAlgorithm.WINOGRAD
         else:
             algo = ConvAlgorithm.IM2COL_GEMM
-        wf = fp32_plan.winograd_fused if algo is ConvAlgorithm.WINOGRAD else False
-        t_int8 = predict_conv_time(
-            spec, h, w, algo, self.hw, 1, batch, winograd_fused=wf
-        )
+        t_int8 = predict_conv_time(spec, h, w, algo, self.hw, 1, batch)
         if t_int8 >= fp32_plan.predicted_s:
             return fp32_plan
         cfg, kernel_blocks = self._resolve_blocks(
-            spec, algo, h, w, batch, 1, winograd_fused=wf
+            spec, algo, h, w, batch, 1
         )
         return ConvPlan(
             algorithm=algo,
@@ -788,7 +743,6 @@ class Planner:
             predicted_s=t_int8,
             source="cost_model",
             fused_epilogue=self.fuse_epilogue,
-            winograd_fused=wf,
             dtype="int8",
         )
 
@@ -835,25 +789,14 @@ class Planner:
         best: Tuple[Optional[ConvPlan], float] = (None, float("inf"))
         candidates = []
         for algo in _eligible_algorithms(spec):
-            if algo is ConvAlgorithm.WINOGRAD:
-                if self.winograd_fused is not None:
-                    candidates.append((algo, self.winograd_fused, False))
-                elif self.impl == "pallas":
-                    # Both realizations exist only on the Pallas path: time
-                    # the fused megakernel against the 3-pass pipeline.
-                    candidates += [(algo, True, False), (algo, False, False)]
-                else:
-                    candidates.append((algo, True, False))
-            else:
-                candidates.append((algo, False, False))
-                if (algo is ConvAlgorithm.IM2COL_GEMM and self.impl == "pallas"
-                        and narrow_input(spec, self.hw)):
-                    # Tap folding exists only on the Pallas path.
-                    candidates.append((algo, False, True))
-        for algo, wf, tf in candidates:
+            candidates.append((algo, False))
+            if (algo is ConvAlgorithm.IM2COL_GEMM and self.impl == "pallas"
+                    and narrow_input(spec, self.hw)):
+                # Tap folding exists only on the Pallas path.
+                candidates.append((algo, True))
+        for algo, tf in candidates:
             cfg, kernel_blocks = self._resolve_blocks(
-                spec, algo, h, w, batch, dtype_bytes, winograd_fused=wf,
-                taps_folded=tf,
+                spec, algo, h, w, batch, dtype_bytes, taps_folded=tf,
             )
             candidate = ConvPlan(
                 algorithm=algo,
@@ -863,7 +806,6 @@ class Planner:
                 predicted_s=0.0,
                 source="measured",
                 fused_epilogue=self.fuse_epilogue,
-                winograd_fused=wf,
                 dtype=_dtype_name(dtype),
                 taps_folded=tf,
             )

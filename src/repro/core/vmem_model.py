@@ -284,18 +284,11 @@ def gemm_kernel_vmem_bytes(
 
 def winograd_traffic_bytes(
     oh: int, ow: int, cin: int, cout: int, batch: int = 1, dtype_bytes: int = 4,
-    fused: bool = False,
 ) -> int:
-    """HBM traffic of the winograd pipeline (input/V/M/output + U once).
-
-    ``fused=False`` models the 3-pass realization (input transform, tuple
-    multiply, output transform as separate kernels): the V and M
-    intermediates, each (64, tiles, C) fp32, round-trip through HBM between
-    kernels — ``2*tiles*64*(cin+cout)`` elements that dominate the layer.
-    ``fused=True`` models the single-pass megakernel
-    (kernels/winograd/kernel.py:fused_winograd_nhwc_pallas): V lives in registers
-    and M in a VMEM scratch accumulator, so both round-trips vanish and only
-    the tile reads, the pre-transformed weights and the output remain.
+    """Ideal-reuse HBM traffic of the Winograd kernel
+    (kernels/winograd/kernel.py:fused_winograd_nhwc_pallas): V lives in
+    registers and M in a VMEM scratch accumulator, so only the tile reads,
+    the pre-transformed weights (read once) and the output remain.
 
     Winograd's working set per stage is smaller than im2col's K-panel —
     the reason the paper finds it needs less cache (§VII.B).
@@ -305,11 +298,7 @@ def winograd_traffic_bytes(
     x_bytes = tiles * 64 * cin            # overlapping 8x8 reads
     u_bytes = 64 * cin * cout             # pre-transformed weights, read once
     y_bytes = tiles * 36 * cout           # output write
-    if fused:
-        return dtype_bytes * (x_bytes + u_bytes + y_bytes)
-    v_bytes = 2 * tiles * 64 * cin        # V write + read
-    m_bytes = 2 * tiles * 64 * cout       # M write + read
-    return dtype_bytes * (x_bytes + v_bytes + u_bytes + m_bytes + y_bytes)
+    return dtype_bytes * (x_bytes + u_bytes + y_bytes)
 
 
 def im2col_gemm_traffic_bytes(
@@ -388,56 +377,35 @@ def im2col_kernel_vmem_bytes(
 
 
 def winograd_kernel_vmem_bytes(
-    bt: int, bc: int, bo: int, fused: bool = True, dtype_bytes: int = 4,
+    bt: int, bc: int, bo: int, dtype_bytes: int = 4,
     double_buffer: bool = True, internal: bool = True,
 ) -> int:
-    """Per-program VMEM footprint of the Winograd Pallas kernels.
+    """Per-program VMEM footprint of the Winograd Pallas kernel.
 
-    ``fused=True``: the single-pass megakernel holds the (8, 8, bt, bc) tile
+    The megakernel holds the (8, 8, bt, bc) tile
     block and the (8, 8, bc, bo) weight block (both double-buffered across
     the Cin grid axis), the (8, 8, bt, bo) fp32 M accumulator scratch, the
     (6, 6, bt, bo) output block, the (1, bo) bias row, and (``internal``)
     Mosaic's internal scratch for the transform planes.  At bt = bb*k*ntw
     it bounds the same kernel tiling in VMEM from above: its NHWC input
     window (bb, 6k+2, 6 ntw + 8, bc) holds at most 56 planes per tile.
-
-    ``fused=False``: the 3-pass pipeline's footprint is the max over its
-    three kernels — each one's in/out blocks are live simultaneously (plus
-    the tuple-multiply's fp32 accumulator scratch).
     """
     buf = 2 if double_buffer else 1
     d = dtype_bytes
     planes = WINOGRAD_INTERNAL_PLANES if internal else 0
     extra = INTERNAL_BYTES if internal else 0
-    if fused:
-        return (
-            buf * tiled_bytes((8, 8, bt, bc), d)         # input tile block
-            + buf * tiled_bytes((8, 8, bc, bo), d)       # transformed weights
-            + tiled_bytes((8, 8, bt, bo), ACC_BYTES)     # M accumulator
-            + buf * tiled_bytes((6, 6, bt, bo), d)       # output block
-            + buf * tiled_bytes((1, bo), ACC_BYTES)      # bias row
-            + planes * tiled_bytes((bt, bc + bo), ACC_BYTES)
-            + extra
-        )
-    input_tf = (
-        buf * 2 * tiled_bytes((8, 8, bt, bc), d)
-        + planes * tiled_bytes((bt, bc), ACC_BYTES)
+    return (
+        buf * tiled_bytes((8, 8, bt, bc), d)         # input tile block
+        + buf * tiled_bytes((8, 8, bc, bo), d)       # transformed weights
+        + tiled_bytes((8, 8, bt, bo), ACC_BYTES)     # M accumulator
+        + buf * tiled_bytes((6, 6, bt, bo), d)       # output block
+        + buf * tiled_bytes((1, bo), ACC_BYTES)      # bias row
+        + planes * tiled_bytes((bt, bc + bo), ACC_BYTES)
+        + extra
     )
-    tuple_mul = (
-        buf * (tiled_bytes((bt, bc), d) + tiled_bytes((bc, bo), d))
-        + buf * tiled_bytes((bt, bo), d)
-        + tiled_bytes((bt, bo), ACC_BYTES)
-    )
-    output_tf = (
-        buf * tiled_bytes((8, 8, bt, bo), d)
-        + buf * tiled_bytes((6, 6, bt, bo), d)
-        + buf * tiled_bytes((1, bo), ACC_BYTES)
-        + planes * tiled_bytes((bt, bo), ACC_BYTES)
-    )
-    return max(input_tf, tuple_mul, output_tf) + extra
 
 
-# Candidate (bt, bc, bo) grids for the Winograd kernels: tiles on sublanes,
+# Candidate (bt, bc, bo) grids for the Winograd kernel: tiles on sublanes,
 # channels on lanes — the same HW granularity the GEMM candidates use.
 WINOGRAD_BTS = (8, 16, 32, 64, 128, 256)
 WINOGRAD_BCS = (128, 256, 512)
@@ -451,15 +419,12 @@ def predict_winograd(
     blocks: Tuple[int, int, int],
     hw: ChipSpec = V5E,
     dtype_bytes: int = 4,
-    fused: bool = True,
 ) -> GemmEstimate:
-    """First-order time prediction for the Winograd kernels at one blocking.
+    """First-order time prediction for the Winograd kernel at one blocking.
 
     The traffic term is block-aware (BLIS-style panel re-reads: the tile
     panel per out-channel panel, the weight panel per tile panel), unlike
-    ``winograd_traffic_bytes`` which reports the ideal-reuse totals; the
-    3-pass variant additionally pays the V/M round trips and a 64x larger
-    grid for the tuple-multiply stage.
+    ``winograd_traffic_bytes`` which reports the ideal-reuse totals.
     """
     bt, bc, bo = blocks
     tp = ceil_to(tiles, bt)
@@ -472,23 +437,13 @@ def predict_winograd(
     x_bytes = tiles * 64 * cin * dtype_bytes
     u_bytes = 64 * cin * cout * dtype_bytes
     y_bytes = tiles * 36 * cout * dtype_bytes
-    if fused:
-        grid = nt * no * nc
-        traffic = x_bytes * no + u_bytes * nt + y_bytes
-    else:
-        v_bytes = tiles * 64 * cin * dtype_bytes
-        m_bytes = tiles * 64 * cout * dtype_bytes
-        grid = nt * nc + 64 * nt * no * nc + nt * no
-        traffic = (
-            (x_bytes + v_bytes)                       # input transform
-            + (v_bytes * no + u_bytes * nt + m_bytes)  # tuple multiply
-            + (m_bytes + y_bytes)                      # output transform
-        )
+    grid = nt * no * nc
+    traffic = x_bytes * no + u_bytes * nt + y_bytes
     return GemmEstimate(
         compute_s=compute_s,
         memory_s=traffic / hw.hbm_bandwidth,
         startup_s=grid * hw.grid_step_overhead_s,
-        vmem_bytes=winograd_kernel_vmem_bytes(bt, bc, bo, fused, dtype_bytes),
+        vmem_bytes=winograd_kernel_vmem_bytes(bt, bc, bo, dtype_bytes),
         hbm_bytes=traffic,
         mxu_utilization=(tiles * cin * cout) / float(tp * cp * op),
     )
@@ -501,7 +456,6 @@ def autotune_winograd_blocks(
     hw: ChipSpec = V5E,
     vmem_budget: Optional[int] = None,
     dtype_bytes: int = 4,
-    fused: bool = True,
 ) -> Tuple[Tuple[int, int, int], GemmEstimate]:
     """Pick the predicted-fastest (bt, bc, bo) under a VMEM budget.
 
@@ -522,17 +476,17 @@ def autotune_winograd_blocks(
         for bc in WINOGRAD_BCS
         for bo in WINOGRAD_BOS
         if bt <= bt_max and bc <= bc_max and bo <= bo_max
-        and winograd_kernel_vmem_bytes(bt, bc, bo, fused, dtype_bytes) <= budget
+        and winograd_kernel_vmem_bytes(bt, bc, bo, dtype_bytes) <= budget
     ]
     if not candidates:
         candidates = [(8, 128, 128)]
     best = min(
         candidates,
         key=lambda b: predict_winograd(
-            tiles, cin, cout, b, hw, dtype_bytes, fused
+            tiles, cin, cout, b, hw, dtype_bytes
         ).total_s,
     )
-    return best, predict_winograd(tiles, cin, cout, best, hw, dtype_bytes, fused)
+    return best, predict_winograd(tiles, cin, cout, best, hw, dtype_bytes)
 
 
 @functools.lru_cache(maxsize=1024)

@@ -1,2 +1,2 @@
 """Distribution layer: mesh context, partition rules, pipeline parallelism,
-gradient compression, fault tolerance / elastic re-mesh."""
+fault tolerance / elastic re-mesh."""

@@ -101,13 +101,10 @@ def conv2d_pallas(
     if algo is ConvAlgorithm.WINOGRAD:
         from repro.kernels.winograd import conv2d_winograd_pallas
 
-        # The single-pass fused megakernel is the default; a plan can pin
-        # the 3-pass pipeline (e.g. a measure-mode planner that timed both).
-        fused = plan.winograd_fused if plan is not None else True
         return conv2d_winograd_pallas(
             x, w, spec, blocks=blocks, interpret=interpret,
             pretransformed=pretransformed,
-            bias=bias, activation=activation, fused=fused,
+            bias=bias, activation=activation,
             vmem_budget=vmem_budget,
         )
 
@@ -235,15 +232,14 @@ def _conv2d_pallas_laidout(
                 t, cp, u.shape[-1], vmem_budget=vmem_budget,
                 dtype_bytes=jnp.dtype(x.dtype).itemsize,
             )
-        bt, bc, bo = blocks
+        bo = blocks[2]
         op = ceil_to(u.shape[-1], bo)
         if op != u.shape[-1]:
             u = jnp.pad(u, ((0, 0), (0, 0), (0, 0), (0, op - u.shape[-1])))
         bias_p = pad_bias_row(bias, op)
-        fused = plan.winograd_fused if plan is not None else True
         y = conv2d_winograd_padded_call(
             x, u, oh, ow, blocks, interpret=interpret,
-            bias_p=bias_p, activation=activation, fused=fused,
+            bias_p=bias_p, activation=activation,
             padding=spec.padding, vmem_budget=vmem_budget,
         )
         return y[..., :o_keep] if y.shape[-1] != o_keep else y

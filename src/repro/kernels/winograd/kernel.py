@@ -12,16 +12,7 @@ constant B^T / A^T coefficients baked in at trace time, i.e. vector FMAs
 with no relayout, and every tuple multiply is one 2-D MXU matmul per
 transform position — the only matmul form Mosaic lowers.
 
-Two realizations of the same pipeline:
-
-The 3-pass decomposition (one kernel per stage, V and M via HBM) on
-position-major operands, (8, 8, T, C) in and (6, 6, T, O) out:
-  input_transform:   V = B^T d B     (per tile x channel)
-  tuple_multiply:    M[p] = V[p] @ U[p]  batched GEMM over the 64 positions
-                     (the paper's "increase the number of blocks for GEMM")
-  output_transform:  Y = A^T M A     (per tile x out-channel)
-
-The single-pass megakernel (``fused_winograd_nhwc_pallas``): one grid
+The megakernel (``fused_winograd_nhwc_pallas``): one grid
 (B/bb, nTH/k, nTW/ntw, O/bo, C/bc) over the padded NHWC activation itself.
 A program reads the element-offset window of its bb images x (6k+2) rows x
 (6 ntw + 8) columns; tile position (i, j) of its bb*k*ntw tiles is one
@@ -78,11 +69,6 @@ def _input_rows(load, a):
     ]
 
 
-def _tile_planes(d_ref):
-    """Plane loader of a position-major (8, 8, bt, bc) tile block."""
-    return lambda i, j: d_ref[i, j]
-
-
 def _nhwc_planes(x_ref, k, ntw):
     """Plane loader of an NHWC (bb, 6k+2, 6 ntw + 8, bc) row window: plane
     (i, j) holds tile (b, kr, tc) at row (b*k + kr)*ntw + tc, read from
@@ -111,44 +97,6 @@ def _output_tiles(m_ref, bias_ref, activation):
             if bias is not None:
                 out = out + bias
             yield x, y, apply_activation(out, activation)
-
-
-def _input_transform_kernel(d_ref, v_ref):
-    """d (8, 8, bt, bc) -> V (8, 8, bt, bc)."""
-    for a in range(8):
-        rows = _input_rows(_tile_planes(d_ref), a)
-        for b in range(8):
-            v_ref[a, b] = _combine(_BT[b], lambda j: rows[j]).astype(v_ref.dtype)
-
-
-def _tuple_multiply_kernel(v_ref, u_ref, m_ref, acc_ref):
-    """Grid (64, nt, no, nc): per-position GEMM with K(=cin) accumulation."""
-
-    @pl.when(pl.program_id(3) == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    acc_ref[...] += jnp.dot(
-        v_ref[0], u_ref[0], preferred_element_type=jnp.float32,
-        precision=dot_precision(v_ref.dtype),
-    )
-
-    @pl.when(pl.program_id(3) == pl.num_programs(3) - 1)
-    def _done():
-        m_ref[...] = acc_ref[...].astype(m_ref.dtype)[None]
-
-
-def _output_transform_kernel(m_ref, y_ref, *, activation: str = "linear"):
-    """M (8, 8, bt, bo) -> Y (6, 6, bt, bo)."""
-    for x, y, out in _output_tiles(m_ref, None, activation):
-        y_ref[x, y] = out.astype(y_ref.dtype)
-
-
-def _output_transform_bias_kernel(m_ref, bias_ref, y_ref, *, activation: str):
-    """Output transform with the fused epilogue: bias (1, bo) + activation
-    applied to the fp32 transform result before the store."""
-    for x, y, out in _output_tiles(m_ref, bias_ref, activation):
-        y_ref[x, y] = out.astype(y_ref.dtype)
 
 
 def _fused_accumulate(cstep, load, u_ref, acc_ref):
@@ -276,80 +224,3 @@ def fused_winograd_nhwc_pallas(
         ),
         interpret=interpret,
     )(*inputs)
-
-
-def input_transform_pallas(
-    tiles: jnp.ndarray, bt: int, bc: int, interpret: bool = False
-) -> jnp.ndarray:
-    """(8, 8, T, C) -> (8, 8, T, C); T % bt == 0, C % bc == 0."""
-    _, _, t, c = tiles.shape
-    return pl.pallas_call(
-        _input_transform_kernel,
-        grid=(t // bt, c // bc),
-        in_specs=[pl.BlockSpec((8, 8, bt, bc), lambda i, j: (0, 0, i, j))],
-        out_specs=pl.BlockSpec((8, 8, bt, bc), lambda i, j: (0, 0, i, j)),
-        out_shape=jax.ShapeDtypeStruct((8, 8, t, c), tiles.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")
-        ),
-        interpret=interpret,
-    )(tiles)
-
-
-def tuple_multiply_pallas(
-    v: jnp.ndarray,  # (64, T, C)
-    u: jnp.ndarray,  # (64, C, O)
-    bt: int,
-    bc: int,
-    bo: int,
-    interpret: bool = False,
-) -> jnp.ndarray:
-    """Batched per-position GEMM -> M (64, T, O)."""
-    p, t, c = v.shape
-    _, _, o = u.shape
-    return pl.pallas_call(
-        _tuple_multiply_kernel,
-        grid=(p, t // bt, o // bo, c // bc),
-        in_specs=[
-            pl.BlockSpec((1, bt, bc), lambda pp, i, j, k: (pp, i, k)),
-            pl.BlockSpec((1, bc, bo), lambda pp, i, j, k: (pp, k, j)),
-        ],
-        out_specs=pl.BlockSpec((1, bt, bo), lambda pp, i, j, k: (pp, i, j)),
-        out_shape=jax.ShapeDtypeStruct((p, t, o), v.dtype),
-        scratch_shapes=[pltpu.VMEM((bt, bo), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
-        ),
-        interpret=interpret,
-    )(v, u)
-
-
-def output_transform_pallas(
-    m: jnp.ndarray, bt: int, bo: int, interpret: bool = False,
-    bias=None, activation: str = "linear",
-) -> jnp.ndarray:
-    """(8, 8, T, O) -> (6, 6, T, O), with an optional fused bias (1, O) +
-    activation epilogue applied to the fp32 transform output."""
-    _, _, t, o = m.shape
-    assert bias is None or bias.shape == (1, o), (o, getattr(bias, "shape", None))
-    in_specs = [pl.BlockSpec((8, 8, bt, bo), lambda i, j: (0, 0, i, j))]
-    if bias is not None:
-        kernel = functools.partial(
-            _output_transform_bias_kernel, activation=activation
-        )
-        in_specs.append(pl.BlockSpec((1, bo), lambda i, j: (0, j)))
-    else:
-        kernel = functools.partial(
-            _output_transform_kernel, activation=activation
-        )
-    return pl.pallas_call(
-        kernel,
-        grid=(t // bt, o // bo),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((6, 6, bt, bo), lambda i, j: (0, 0, i, j)),
-        out_shape=jax.ShapeDtypeStruct((6, 6, t, o), m.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")
-        ),
-        interpret=interpret,
-    )(m, *(() if bias is None else (bias,)))

@@ -1,24 +1,15 @@
-"""Jitted end-to-end Winograd conv on the Pallas kernels.
+"""Jitted end-to-end Winograd conv on the Pallas kernel.
 
 Pipeline (paper §IV.B):  tile -> input transform -> tuple multiply ->
 output transform -> untile, with the weight transform done offline.  The
-compute stages run either as the single-pass fused megakernel (``fused=True``,
-the default: transforms and M accumulation never leave VMEM) or as the
-3-pass kernel pipeline whose V/M intermediates round-trip through HBM.
-
-The two realizations cut the overlapping 8x8 tiles in different places:
-
-- the fused kernel reads element-offset row windows of the padded NHWC
-  activation and cuts the tiles itself with loads strided by 6, then
-  writes NHWC.  Around it are one XLA pad (the conv's spatial padding plus
-  the tile tail) and, for a caller that drops padded channels, one crop.
-  The tile columns of a block are padded to the 8-sublane granule, which is
-  MXU and VPU work on tiles that do not exist (``winograd_tiling`` counts
-  it).
-- the 3-pass pipeline has XLA gather the tiles into a position-major
-  (8, 8, T, C) tensor, pad T to the bt multiple, and transpose the
-  (6, 6, T, O) result back to NHWC: each of these writes and reads the
-  tiled activation in HBM.
+compute stages run as one megakernel whose transforms and M accumulation
+never leave VMEM.  It reads element-offset row windows of the padded NHWC
+activation, cuts the overlapping 8x8 tiles itself with loads strided by 6,
+and writes NHWC.  Around it are one XLA pad (the conv's spatial padding plus
+the tile tail) and, for a caller that drops padded channels, one crop.  The
+tile columns of a block are padded to the 8-sublane granule, which is MXU
+and VPU work on tiles that do not exist (``winograd_tiling`` counts it).
+The jnp realization of the same decomposition is ``core/winograd.py``.
 """
 from __future__ import annotations
 
@@ -30,25 +21,25 @@ import jax.numpy as jnp
 
 from repro.core.conv_spec import ConvSpec
 from repro.core.vmem_model import (
+    ACC_BYTES,
     winograd_kernel_vmem_bytes,
     winograd_nhwc_blocks,
 )
-from repro.core.winograd import OUT_TILE, TILE, _tile_input, transform_weights
+from repro.core.winograd import OUT_TILE, transform_weights
 from repro.hw import V5E
 from repro.util import ceil_to, pad_bias_row
 
 
 def pick_blocks(
     t: int, c: int, o: int, vmem_budget: Optional[int] = None,
-    fused: bool = True, dtype_bytes: int = 4,
+    dtype_bytes: int = 4,
 ) -> Tuple[int, int, int]:
     """(bt, bc, bo) aligned to (sublane, lane) granularity, VMEM-bounded.
 
     Budgets the **full** per-kernel footprint via
-    ``vmem_model.winograd_kernel_vmem_bytes`` — for the fused megakernel the
-    double-buffered tile + weight blocks, the (8, 8, bt, bo) fp32 M
-    accumulator scratch and the output block; for the 3-pass pipeline the
-    max footprint across its three kernels.  (The old heuristic budgeted
+    ``vmem_model.winograd_kernel_vmem_bytes``: the double-buffered tile +
+    weight blocks, the (8, 8, bt, bo) fp32 M accumulator scratch and the
+    output block.  (The old heuristic budgeted
     only the input-transform block, 2*bt*64*bc*4 bytes, and silently
     overflowed VMEM through the weight block and tuple-multiply scratch.)
     The channel blocks shrink first (they are what the weight block is
@@ -62,7 +53,7 @@ def pick_blocks(
 
     def fits() -> bool:
         return winograd_kernel_vmem_bytes(
-            bt, bc, bo, fused=fused, dtype_bytes=dtype_bytes
+            bt, bc, bo, dtype_bytes=dtype_bytes
         ) <= budget
 
     # Shrink in granularity multiples: halving a non-power-of-two start
@@ -81,18 +72,13 @@ def pick_blocks(
 class WinogradTiling(NamedTuple):
     """Where a Winograd layer cuts its 8x8 tiles, and how many it computes.
 
-    ``nhwc`` is None for the 3-pass pipeline's HBM tiling, else the fused
-    kernel's block in VMEM: (bb images, k tile rows, ntw tile columns).
-    ``computed`` counts the tiles the kernel computes, padding included;
-    ``real`` is B*nTH*nTW."""
+    ``nhwc`` is the kernel's block in VMEM: (bb images, k tile rows, ntw
+    tile columns).  ``computed`` counts the tiles the kernel computes,
+    padding included; ``real`` is B*nTH*nTW."""
 
-    nhwc: Optional[Tuple[int, int, int]]
+    nhwc: Tuple[int, int, int]
     computed: int
     real: int
-
-    @property
-    def name(self) -> str:
-        return "hbm" if self.nhwc is None else "vmem"
 
     @property
     def ratio(self) -> float:
@@ -101,22 +87,19 @@ class WinogradTiling(NamedTuple):
 
 def winograd_tiling(
     b: int, oh: int, ow: int, blocks: Tuple[int, int, int],
-    fused: bool = True, vmem_budget: Optional[int] = None,
+    vmem_budget: Optional[int] = None,
 ) -> WinogradTiling:
     """The tiling of a Winograd layer with a (b, oh, ow) output.
 
-    The fused kernel cuts its tiles in VMEM, in (bb, k, ntw) blocks that
+    The kernel cuts its tiles in VMEM, in (bb, k, ntw) blocks that
     ``winograd_nhwc_blocks`` picks for the plan's channel blocks under
     ``vmem_budget`` (the planner's; None: the chip's VMEM).  Padding the
     tile columns to the 8-sublane granule costs 5% at 224² (38 -> 40) and
-    60% at 28² and 56² (5 -> 8, 10 -> 16).  The 3-pass pipeline tiles in
-    HBM, its tile count padded to the bt multiple.
+    60% at 28² and 56² (5 -> 8, 10 -> 16).
     """
-    bt, bc, bo = blocks
+    _, bc, bo = blocks
     nth, ntw = -(-oh // OUT_TILE), -(-ow // OUT_TILE)
     real = b * nth * ntw
-    if not fused:
-        return WinogradTiling(None, ceil_to(real, bt), real)
     nhwc = winograd_nhwc_blocks(b, nth, ntw, bc, bo, vmem_budget=vmem_budget)
     _, k, nw = nhwc
     return WinogradTiling(nhwc, b * ceil_to(nth, k) * ceil_to(ntw, nw), real)
@@ -131,7 +114,6 @@ def conv2d_winograd_padded_call(
     interpret: bool = False,
     bias_p: Optional[jnp.ndarray] = None,
     activation: str = "linear",
-    fused: bool = True,
     padding: Tuple[int, int] = (0, 0),
     vmem_budget: Optional[int] = None,
 ) -> jnp.ndarray:
@@ -141,30 +123,21 @@ def conv2d_winograd_padded_call(
     ``padding`` is the conv's spatial (ph, pw), applied here ((0, 0) for an
     ``x`` that already carries it).  ``u_p`` (8, 8, Cp, Op) is the
     pre-transformed weight padded to the same channel blocks, and ``bias_p``
-    (1, Op) or None.  The fused kernel cuts the tiles in VMEM
+    (1, Op) or None.  The kernel cuts the tiles in VMEM
     (``winograd_vmem_tiled_call``, blocks from ``winograd_tiling`` under
-    ``vmem_budget``); the 3-pass pipeline has XLA cut them in HBM
-    (``winograd_3pass_call``).  Either way the spatial padding and the
-    tile-count padding are intra-layer data movement and stay here; the
-    *channel* pad/crop pair is what the network executor (core/netplan.py)
-    elides between consecutive layers.  Returns (B, OH, OW, Op): rows/cols
+    ``vmem_budget``).  The spatial padding and the tile-count padding are
+    intra-layer data movement and stay here; the *channel* pad/crop pair is
+    what the network executor (core/netplan.py) elides between consecutive
+    layers.  Returns (B, OH, OW, Op): rows/cols
     cut to logical (the 6-multiple tail rows carry act(bias), never zeros,
     so they must not flow on), channels kept padded for the caller to crop
     — or to hand straight to the next layer.
     """
-    if fused:
-        tiling = winograd_tiling(x.shape[0], oh, ow, blocks,
-                                 vmem_budget=vmem_budget)
-        return winograd_vmem_tiled_call(
-            x, u_p, oh, ow, tiling.nhwc, blocks[1:], interpret=interpret,
-            bias_p=bias_p, activation=activation, padding=padding,
-        )
-    ph, pw = padding
-    if ph or pw:
-        x = jnp.pad(x, ((0, 0), (ph, ph), (pw, pw), (0, 0)))
-    return winograd_3pass_call(
-        x, u_p, oh, ow, blocks, interpret=interpret, bias_p=bias_p,
-        activation=activation,
+    tiling = winograd_tiling(x.shape[0], oh, ow, blocks,
+                             vmem_budget=vmem_budget)
+    return winograd_vmem_tiled_call(
+        x, u_p, oh, ow, tiling.nhwc, blocks[1:], interpret=interpret,
+        bias_p=bias_p, activation=activation, padding=padding,
     )
 
 
@@ -207,61 +180,10 @@ def winograd_vmem_tiled_call(
     return y.astype(dtype)
 
 
-def winograd_3pass_call(
-    x_sp: jnp.ndarray,
-    u_p: jnp.ndarray,
-    oh: int,
-    ow: int,
-    blocks: Tuple[int, int, int],
-    interpret: bool = False,
-    bias_p: Optional[jnp.ndarray] = None,
-    activation: str = "linear",
-) -> jnp.ndarray:
-    """The 3-pass pipeline on tiles XLA gathers in HBM: the overlapping 8x8
-    tiles as a position-major (8, 8, T, Cp) tensor padded to the bt
-    multiple, the input transform, tuple multiply and output transform
-    kernels, then the (6, 6, T, Op) result transposed back to NHWC."""
-    from repro.kernels.winograd.kernel import (
-        input_transform_pallas,
-        output_transform_pallas,
-        tuple_multiply_pallas,
-    )
-
-    b = x_sp.shape[0]
-    cp = x_sp.shape[-1]
-    op = u_p.shape[-1]
-    bt, bc, bo = blocks
-    assert cp % bc == 0 and op % bo == 0, (cp, bc, op, bo)
-
-    tiles, nth, ntw = _tile_input(x_sp, oh, ow)  # (B, nTH, nTW, 8, 8, Cp)
-    t = b * nth * ntw
-    # Position-major (8, 8, T, Cp): tiles on sublanes, channels on lanes.
-    tiles = tiles.transpose(3, 4, 0, 1, 2, 5).reshape(TILE, TILE, t, cp)
-    tp = ceil_to(t, bt)
-    if tp != t:
-        tiles = jnp.pad(tiles, ((0, 0), (0, 0), (0, tp - t), (0, 0)))
-
-    v = input_transform_pallas(tiles, bt, bc, interpret=interpret)
-    v = v.reshape(TILE * TILE, tp, cp)
-    m = tuple_multiply_pallas(
-        v, u_p.reshape(TILE * TILE, cp, op), bt, bc, bo, interpret=interpret,
-    )
-    y = output_transform_pallas(
-        m.reshape(TILE, TILE, tp, op), bt, bo, interpret=interpret,
-        bias=bias_p, activation=activation,
-    )  # (6, 6, tp, op)
-
-    y = y[:, :, :t].reshape(OUT_TILE, OUT_TILE, b, nth, ntw, op)
-    y = y.transpose(2, 3, 0, 4, 1, 5).reshape(
-        b, nth * OUT_TILE, ntw * OUT_TILE, op
-    )
-    return y[:, :oh, :ow, :]
-
-
 @functools.partial(
     jax.jit,
     static_argnames=("spec", "blocks", "interpret", "pretransformed",
-                     "activation", "fused", "vmem_budget"),
+                     "activation", "vmem_budget"),
 )
 def conv2d_winograd_pallas(
     x: jnp.ndarray,
@@ -272,20 +194,13 @@ def conv2d_winograd_pallas(
     interpret: bool = False,
     bias: Optional[jnp.ndarray] = None,
     activation: str = "linear",
-    fused: bool = True,
     vmem_budget: Optional[int] = None,
 ) -> jnp.ndarray:
     """x (B,H,W,C), w (3,3,C,O) [or (8,8,C,O) pretransformed] -> (B,OH,OW,O).
 
-    ``fused=True`` (default) runs the single-pass megakernel: one
-    pallas_call that cuts its tiles from the NHWC activation in VMEM and
-    whose V and M intermediates stay there, in blocks sized to
-    ``vmem_budget`` (None: the chip's VMEM).
-    ``fused=False`` runs the 3-pass pipeline
-    (input transform -> tuple multiply -> output transform), each stage a
-    separate kernel with (64, T, C)-shaped HBM intermediates — kept for
-    measure-mode comparison and as the reference realization of the paper's
-    decomposition.
+    Runs the megakernel: one pallas_call that cuts its tiles from the NHWC
+    activation in VMEM and whose V and M intermediates stay there, in blocks
+    sized to ``vmem_budget`` (None: the chip's VMEM).
 
     ``bias`` (O,) and ``activation`` form the fused epilogue, applied on the
     fp32 accumulator after the inverse transform, before the store."""
@@ -296,7 +211,7 @@ def conv2d_winograd_pallas(
     nth, ntw = -(-oh // OUT_TILE), -(-ow // OUT_TILE)
     t = b * nth * ntw
     bt, bc, bo = blocks or pick_blocks(
-        t, c, o, vmem_budget=vmem_budget, fused=fused,
+        t, c, o, vmem_budget=vmem_budget,
         dtype_bytes=jnp.dtype(x.dtype).itemsize,
     )
     cp, op = ceil_to(c, bc), ceil_to(o, bo)
@@ -310,7 +225,7 @@ def conv2d_winograd_pallas(
 
     y = conv2d_winograd_padded_call(
         x, u, oh, ow, (bt, bc, bo), interpret=interpret,
-        bias_p=bias_p, activation=activation, fused=fused,
+        bias_p=bias_p, activation=activation,
         padding=spec.padding, vmem_budget=vmem_budget,
     )
     return y[:, :, :, :o]
@@ -318,99 +233,48 @@ def conv2d_winograd_pallas(
 
 def winograd_call_descriptors(
     b: int, oh: int, ow: int, cp: int, op: int,
-    blocks: Tuple[int, int, int], bias: bool = True, fused: bool = True,
-    dtype_bytes: int = 4, vmem_budget: Optional[int] = None,
+    blocks: Tuple[int, int, int], bias: bool = True,
+    vmem_budget: Optional[int] = None,
 ) -> list:
-    """Static description of the pallas_call(s) ``conv2d_winograd_padded_call``
-    emits for a (b, oh, ow) output on (cp, op)-channel-padded operands.
-
-    One descriptor for the fused megakernel (tiled in VMEM, at the blocks
-    ``winograd_tiling`` picks under ``vmem_budget``; fp32 whatever
-    ``dtype_bytes``, see ``winograd_vmem_tiled_call``), three (input
-    transform, tuple multiply, output transform) for the 3-pass pipeline.
+    """Static description of the pallas_call ``conv2d_winograd_padded_call``
+    emits for a (b, oh, ow) output on (cp, op)-channel-padded operands: the
+    megakernel, tiled in VMEM at the blocks ``winograd_tiling`` picks under
+    ``vmem_budget``, fp32 whatever the activation dtype (see
+    ``winograd_vmem_tiled_call``).
     Traffic follows the verifier's fetch algebra (an operand re-fetches once
     per step of the grid prefix its index map depends on).
     ``model_vmem_bytes`` is ``winograd_kernel_vmem_bytes`` over the buffers
-    alone (the compiler's internal scratch is not visible in a trace),
-    which for the 3-pass pipeline is the *max* over stages and for the
-    fused kernel an upper bound (its input window is smaller than an
-    (8, 8, bt, bc) tile block) — so actuals are compared one-sided
-    (``vmem_one_sided``).
+    alone (the compiler's internal scratch is not visible in a trace), an
+    upper bound (the input window is smaller than an (8, 8, bt, bc) tile
+    block) — so actuals are compared one-sided (``vmem_one_sided``).
     """
-    from repro.core.vmem_model import ACC_BYTES, winograd_kernel_vmem_bytes
-
-    bt, bc, bo = blocks
-    tiling = winograd_tiling(b, oh, ow, blocks, fused=fused,
-                             vmem_budget=vmem_budget)
+    _, bc, bo = blocks
+    tiling = winograd_tiling(b, oh, ow, blocks, vmem_budget=vmem_budget)
     nc, no = cp // bc, op // bo
-    if fused:
-        # Kernel-interior contract: the Cin grid axis (innermost) is the
-        # reduction, accumulated in the (8, 8, bb*k*ntw, bo) fp32 M scratch.
-        # Winograd never runs int8 (quantization policy), so no k_elems.
-        d = 4
-        bb, k, nw = tiling.nhwc
-        te = bb * k * nw
-        nhw = tiling.computed // te                   # image x row x col blocks
-        window = bb * (6 * k + 2) * (6 * nw + 8) * bc
-        traffic = (
-            d * nhw * no * nc * (window + 64 * bc * bo)   # x + U
-            + (ACC_BYTES * nhw * no * bo if bias else 0)  # bias rows
-            + d * nhw * no * 36 * te * bo                 # output
-        )
-        name = ("_fused_winograd_nhwc_bias_kernel" if bias
-                else "_fused_winograd_nhwc_kernel")
-        return [{
-            "family": "winograd",
-            "name": name,
-            "grid": (b // bb, ceil_to(-(-oh // 6), k) // k,
-                     ceil_to(-(-ow // 6), nw) // nw, no, nc),
-            "model_vmem_bytes": winograd_kernel_vmem_bytes(
-                te, bc, bo, dtype_bytes=d, internal=False),
-            "traffic_bytes": traffic,
-            "vmem_one_sided": True,
-            "reduction_axes": (4,),
-            "k_elems": None,
-        }]
-    tp = tiling.computed
-    nt = tp // bt
-    model = winograd_kernel_vmem_bytes(
-        bt, bc, bo, fused=False, dtype_bytes=dtype_bytes, internal=False,
+    # Kernel-interior contract: the Cin grid axis (innermost) is the
+    # reduction, accumulated in the (8, 8, bb*k*ntw, bo) fp32 M scratch.
+    # Winograd never runs int8 (quantization policy), so no k_elems.
+    d = 4
+    bb, k, nw = tiling.nhwc
+    te = bb * k * nw
+    nhw = tiling.computed // te                   # image x row x col blocks
+    window = bb * (6 * k + 2) * (6 * nw + 8) * bc
+    traffic = (
+        d * nhw * no * nc * (window + 64 * bc * bo)   # x + U
+        + (ACC_BYTES * nhw * no * bo if bias else 0)  # bias rows
+        + d * nhw * no * 36 * te * bo                 # output
     )
-    input_tf = {
+    name = ("_fused_winograd_nhwc_bias_kernel" if bias
+            else "_fused_winograd_nhwc_kernel")
+    return [{
         "family": "winograd",
-        "name": "_input_transform_kernel",
-        "grid": (nt, nc),
-        "model_vmem_bytes": model,
-        "traffic_bytes": dtype_bytes * 2 * nt * nc * 64 * bt * bc,
+        "name": name,
+        "grid": (b // bb, ceil_to(-(-oh // 6), k) // k,
+                 ceil_to(-(-ow // 6), nw) // nw, no, nc),
+        "model_vmem_bytes": winograd_kernel_vmem_bytes(
+            te, bc, bo, dtype_bytes=d, internal=False),
+        "traffic_bytes": traffic,
         "vmem_one_sided": True,
-        "reduction_axes": (),
+        "reduction_axes": (4,),
         "k_elems": None,
-    }
-    tuple_mul = {
-        "family": "winograd",
-        "name": "_tuple_multiply_kernel",
-        "grid": (64, nt, no, nc),
-        "model_vmem_bytes": model,
-        "traffic_bytes": dtype_bytes * 64 * nt * no * nc * bc * (bt + bo)
-        + dtype_bytes * 64 * nt * no * bt * bo,
-        "vmem_one_sided": True,
-        # The per-position GEMM reduces over the in-channel grid axis
-        # (innermost) into the (bt, bo) fp32 scratch.
-        "reduction_axes": (3,),
-        "k_elems": None,
-    }
-    output_tf = {
-        "family": "winograd",
-        "name": (
-            "_output_transform_bias_kernel" if bias
-            else "_output_transform_kernel"
-        ),
-        "grid": (nt, no),
-        "model_vmem_bytes": model,
-        "traffic_bytes": dtype_bytes * nt * no * (64 + 36) * bt * bo
-        + (ACC_BYTES * nt * no * bo if bias else 0),
-        "vmem_one_sided": True,
-        "reduction_axes": (),
-        "k_elems": None,
-    }
-    return [input_tf, tuple_mul, output_tf]
+    }]

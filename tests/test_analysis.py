@@ -503,26 +503,29 @@ def test_declared_k_drift_flags_overflow():
     assert report.by_pass("overflow")[0].expected == 512
 
 
-def test_three_pass_winograd_kernels_analyze_clean():
-    """The non-fused Winograd path (input transform / tuple multiply /
-    output transform) — three pallas_calls the zoo's planner rarely picks —
-    still certifies clean under all four interior passes."""
-    from repro.core.conv_spec import ConvSpec
-    from repro.kernels.winograd.ops import conv2d_winograd_pallas
+def test_multi_image_winograd_block_kernel_level_clean():
+    """A Winograd step whose block holds several whole images (bb > 1, as at
+    ResNet-50's 7x7 layers at batch 64): ResNet-50's first four layers at
+    48x48, batch 4, put two 12x12 images (2 x 2 tiles, padded to 2 x 8)
+    in each block of layer 3.  The kernel rung certifies it clean, and the
+    traced grid is the descriptor's."""
+    from repro.analysis.descriptors import step_descriptors
+    from repro.configs import resnet50
+    from repro.core.netplan import winograd_step_tiling
 
-    spec = ConvSpec(64, 64)
-    recs = _records(
-        lambda x, w, b: conv2d_winograd_pallas(
-            x, w, spec, fused=False, interpret=True, bias=b
-        ),
-        jnp.zeros((1, 32, 32, 64), jnp.float32),
-        jnp.zeros((3, 3, 64, 64), jnp.float32),
-        jnp.zeros((64,), jnp.float32),
-    )
-    assert len(recs) == 3
-    pairs = [(r, {"step": i}) for i, r in enumerate(recs)]
-    report = _interior_report(pairs)
-    assert report.clean, report.findings
+    layers = resnet50.LAYERS[:4]
+    planner = Planner(impl="pallas", cache_path=None)
+    netplan = plan_network(layers, 48, 48, planner, batch=4)
+    step = netplan.steps[3]
+    assert _algo(step) is ConvAlgorithm.WINOGRAD
+    assert winograd_step_tiling(netplan, step).nhwc == (2, 2, 8)
+    (desc,) = step_descriptors(netplan, step)
+    assert desc["grid"][0] == 2                 # 4 images, 2 per block
+    params = init_cnn(jax.random.PRNGKey(0), layers)
+    prepared = prepare_net_params(netplan, params, pretransform=True)
+    report = verify_network(netplan, prepared, level="kernel")
+    assert report.ok and not report.findings, report.findings
+    assert tuple(desc["grid"]) in [tuple(r["grid"]) for r in report.kernels]
 
 
 # ---------------------------------------------------------------------------

@@ -60,151 +60,6 @@ def test_conv_conformance(algo, impl, stride, pad, k, fused):
     np.testing.assert_allclose(got, ref, rtol=5e-4, atol=5e-4)
 
 
-# ---------------------------------------------------------------------------
-# Winograd edge cases: the fused single-pass megakernel and the 3-pass
-# pipeline against the oracle on every awkward shape class.
-
-
-@pytest.mark.parametrize("fused", [True, False], ids=["megakernel", "3pass"])
-@pytest.mark.parametrize("h,w", [(10, 14), (13, 7), (9, 16), (11, 23)])
-def test_winograd_crop_path(h, w, fused):
-    """Output sizes not divisible by 6: the tile grid over-covers and the
-    final crop must discard exactly the padded rows/cols."""
-    spec = ConvSpec(4, 8, (3, 3), (1, 1), (1, 1),
-                    algorithm=ConvAlgorithm.WINOGRAD)
-    oh, ow = spec.out_hw(h, w)
-    assert oh % 6 != 0 or ow % 6 != 0
-    from repro.kernels.winograd import conv2d_winograd_pallas
-
-    x = _rand((2, h, w, 4), seed=h * 31 + w)
-    wt = _rand((3, 3, 4, 8), seed=3)
-    got = conv2d_winograd_pallas(x, wt, spec, interpret=True, fused=fused)
-    ref = conv2d_reference(x, wt, spec)
-    np.testing.assert_allclose(got, ref, rtol=5e-4, atol=5e-4)
-
-
-@pytest.mark.parametrize("fused", [True, False], ids=["megakernel", "3pass"])
-@pytest.mark.parametrize("blocks", [(8, 128, 128), (16, 128, 128),
-                                    (8, 8, 8), (32, 16, 8)])
-def test_winograd_block_padding_path(blocks, fused):
-    """T/C/O not divisible by the block tuple: tiles (2*2*3=12), channels (5)
-    and out-channels (7) all need zero-padding to block multiples, and the
-    padded rows must not leak into the cropped result."""
-    spec = ConvSpec(5, 7, (3, 3), (1, 1), (1, 1),
-                    algorithm=ConvAlgorithm.WINOGRAD)
-    from repro.kernels.winograd import conv2d_winograd_pallas
-
-    x = _rand((2, 12, 12, 5), seed=sum(blocks))
-    wt = _rand((3, 3, 5, 7), seed=5)
-    got = conv2d_winograd_pallas(
-        x, wt, spec, blocks=blocks, interpret=True, fused=fused
-    )
-    ref = conv2d_reference(x, wt, spec)
-    np.testing.assert_allclose(got, ref, rtol=5e-4, atol=5e-4)
-
-
-@pytest.mark.parametrize("fused", [True, False], ids=["megakernel", "3pass"])
-def test_winograd_pretransformed_weights(fused):
-    """Offline weight transform (inference mode): (8, 8, C, O) weights skip
-    the in-graph G g G^T and must produce identical results."""
-    from repro.core.winograd import transform_weights
-    from repro.kernels.winograd import conv2d_winograd_pallas
-
-    spec = ConvSpec(4, 6, (3, 3), (1, 1), (1, 1))
-    x = _rand((1, 13, 17, 4), seed=41)
-    wt = _rand((3, 3, 4, 6), seed=42)
-    u = transform_weights(wt)
-    got = conv2d_winograd_pallas(
-        x, u, spec, pretransformed=True, interpret=True, fused=fused
-    )
-    ref = conv2d_reference(x, wt, spec)
-    np.testing.assert_allclose(got, ref, rtol=5e-4, atol=5e-4)
-
-
-@pytest.mark.parametrize("activation", ["linear", "relu", "leaky"])
-@pytest.mark.parametrize("with_bias", [False, True], ids=["nobias", "bias"])
-def test_winograd_fused_epilogue_cross_product(activation, with_bias):
-    """The megakernel's in-VMEM epilogue (bias + activation on the fp32
-    inverse-transform result) across the full cross-product, on a shape that
-    exercises the crop and channel-padding paths at once."""
-    from repro.kernels.winograd import conv2d_winograd_pallas
-
-    spec = ConvSpec(5, 9, (3, 3), (1, 1), (1, 1))
-    x = _rand((2, 10, 13, 5), seed=51)
-    wt = _rand((3, 3, 5, 9), seed=52)
-    bias = _rand((9,), seed=53) if with_bias else None
-    got = conv2d_winograd_pallas(
-        x, wt, spec, interpret=True, fused=True,
-        bias=bias, activation=activation,
-    )
-    epi = Epilogue(bias=bias, activation=activation)
-    ref = apply_epilogue(conv2d_reference(x, wt, spec), epi)
-    np.testing.assert_allclose(got, ref, rtol=5e-4, atol=5e-4)
-
-
-def test_winograd_fused_matches_3pass_bitwise_shape():
-    """Both realizations are the same math at the same blocking — they must
-    agree far tighter than either agrees with the oracle."""
-    from repro.kernels.winograd import conv2d_winograd_pallas
-
-    spec = ConvSpec(4, 8, (3, 3), (1, 1), (1, 1))
-    x = _rand((1, 18, 18, 4), seed=61)
-    wt = _rand((3, 3, 4, 8), seed=62)
-    a = conv2d_winograd_pallas(x, wt, spec, blocks=(8, 128, 128),
-                               interpret=True, fused=True)
-    b = conv2d_winograd_pallas(x, wt, spec, blocks=(8, 128, 128),
-                               interpret=True, fused=False)
-    np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
-
-
-def test_winograd_fused_traffic_model_2x():
-    """Acceptance: the megakernel's modeled HBM bytes are >= 2x lower than
-    the 3-pass pipeline's over the VGG-16 + YOLOv3 3x3 stride-1 layer set
-    (the eliminated V/M round-trips are 2*tiles*64*(Cin+Cout) elements)."""
-    from benchmarks.common import vgg16_gemms, yolov3_20_gemms
-    from repro.core.vmem_model import winograd_traffic_bytes
-
-    unfused_total = fused_total = 0
-    n_layers = 0
-    for dims in (vgg16_gemms(), yolov3_20_gemms()):
-        for d in dims:
-            if d["kernel"] != 3 or d["stride"] != 1:
-                continue
-            spec = ConvSpec(d["cin"], d["cout"], (3, 3), (1, 1), (1, 1))
-            oh, ow = spec.out_hw(d["h"], d["w"])
-            unfused_total += winograd_traffic_bytes(
-                oh, ow, d["cin"], d["cout"], fused=False
-            )
-            fused_total += winograd_traffic_bytes(
-                oh, ow, d["cin"], d["cout"], fused=True
-            )
-            n_layers += 1
-    assert n_layers >= 15  # both networks actually contributed layers
-    assert fused_total > 0
-    assert unfused_total / fused_total >= 2.0
-
-
-def test_winograd_pick_blocks_budgets_full_footprint():
-    """Satellite: pick_blocks must budget the whole kernel footprint (weight
-    block + M scratch + output block), not just the input-transform block."""
-    from repro.core.vmem_model import winograd_kernel_vmem_bytes
-    from repro.kernels.winograd.ops import pick_blocks
-
-    for fused in (True, False):
-        for t, c, o in ((4096, 512, 512), (4096, 384, 384), (20, 512, 512)):
-            for budget in (1 << 20, 4 << 20, 10 << 20, 16 << 20, 64 << 20):
-                bt, bc, bo = pick_blocks(
-                    t, c, o, vmem_budget=budget, fused=fused
-                )
-                # Never below the (sublane, lane) granularity floor, even
-                # when shrinking from a non-power-of-two start (384, 24...).
-                assert bt % 8 == 0 and bc % 128 == 0 and bo % 128 == 0
-                footprint = winograd_kernel_vmem_bytes(bt, bc, bo, fused=fused)
-                # Either the footprint fits, or we are at the floor and
-                # cannot shrink further.
-                assert footprint <= budget or (bt, bc, bo) == (8, 128, 128)
-
-
 def test_im2col_pick_blocks_budgets_full_footprint():
     """Satellite: the im2col pick_blocks must budget the whole per-program
     footprint — the (kh, kw, bc, bo) weight block and the bias row on top

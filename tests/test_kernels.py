@@ -10,16 +10,6 @@ from repro.core.conv2d import conv2d, conv2d_reference
 from repro.kernels.gemm import blocked_matmul, matmul_ref
 from repro.kernels.im2col_gemm import conv2d_pallas_im2col
 from repro.kernels.winograd import conv2d_winograd_pallas
-from repro.kernels.winograd.kernel import (
-    input_transform_pallas,
-    output_transform_pallas,
-    tuple_multiply_pallas,
-)
-from repro.kernels.winograd.ref import (
-    input_transform_ref,
-    output_transform_ref,
-    tuple_multiply_ref,
-)
 
 
 def _rand(shape, seed=0, dtype=jnp.float32):
@@ -92,28 +82,7 @@ def test_im2col_gemm_explicit_blocks():
 
 
 # ---------------------------------------------------------------------------
-# Winograd kernels (per-stage + end-to-end)
-
-
-def test_winograd_input_transform_kernel():
-    tiles = _rand((8, 8, 16, 8), 21)
-    got = input_transform_pallas(tiles, bt=8, bc=8, interpret=True)
-    np.testing.assert_allclose(got, input_transform_ref(tiles), rtol=1e-4,
-                               atol=1e-4)
-
-
-def test_winograd_tuple_multiply_kernel():
-    v, u = _rand((64, 16, 8), 22), _rand((64, 8, 12), 23)
-    got = tuple_multiply_pallas(v, u, bt=8, bc=8, bo=4, interpret=True)
-    np.testing.assert_allclose(got, tuple_multiply_ref(v, u), rtol=1e-4,
-                               atol=1e-4)
-
-
-def test_winograd_output_transform_kernel():
-    m = _rand((8, 8, 16, 8), 24)
-    got = output_transform_pallas(m, bt=8, bo=8, interpret=True)
-    np.testing.assert_allclose(got, output_transform_ref(m), rtol=1e-4,
-                               atol=1e-4)
+# Winograd kernel (end-to-end)
 
 
 @pytest.mark.parametrize("case", [

@@ -10,7 +10,12 @@ import pytest
 from repro.core.codesign import select_algorithm_by_cost
 from repro.core.conv_spec import ConvAlgorithm, ConvSpec
 from repro.core.conv2d import conv2d, conv2d_reference
-from repro.core.planner import ConvPlan, Planner, plan_key
+from repro.core.planner import (
+    PLAN_CACHE_VERSION,
+    ConvPlan,
+    Planner,
+    plan_key,
+)
 
 # The three layer classes the selector distinguishes (paper §VII.A).
 LAYER_CASES = [
@@ -188,9 +193,8 @@ def test_plan_records_fused_epilogue(tmp_path):
 
 
 def test_winograd_plan_records_fused_megakernel(tmp_path):
-    """Cache v3: Winograd plans record the single-pass megakernel decision,
-    autotune (bt, bc, bo) against the fused footprint, persist it, and a warm
-    planner re-tunes nothing."""
+    """Winograd plans autotune (bt, bc, bo) against the megakernel's
+    footprint and persist them, and a warm planner re-tunes nothing."""
     from repro.core.vmem_model import winograd_kernel_vmem_bytes
 
     cache = os.path.join(tmp_path, "wino.json")
@@ -198,57 +202,113 @@ def test_winograd_plan_records_fused_megakernel(tmp_path):
     planner = Planner(cache_path=cache)
     plan = planner.plan(spec, 152, 152)
     assert plan.algorithm is ConvAlgorithm.WINOGRAD
-    assert plan.winograd_fused          # model: fused never loses
     bt, bc, bo = plan.kernel_blocks
-    assert winograd_kernel_vmem_bytes(bt, bc, bo, fused=True) \
-        <= planner.vmem_budget
+    assert winograd_kernel_vmem_bytes(bt, bc, bo) <= planner.vmem_budget
 
     # Round-trips through the JSON cache, zero re-tunes on a warm planner.
     warm = Planner(cache_path=cache)
     replan = warm.plan(spec, 152, 152)
     assert warm.stats == {"hits": 1, "tunes": 0}
-    assert replan == plan and replan.winograd_fused
+    assert replan == plan
 
     data = json.load(open(cache))
-    assert data["version"] == 8   # v8: tap folding (v7: tile-padded VMEM)
+    assert data["version"] == 9   # v9: one Winograd kernel (v8: tap folding)
     (record,) = data["plans"].values()
-    assert record["winograd_fused"] is True
+    assert set(record) == {"algorithm", "impl", "block", "kernel_blocks",
+                           "predicted_s", "source", "fused_epilogue", "dtype",
+                           "taps_folded"}
 
 
-def test_winograd_fused_policy_keys_separately():
-    """The wf policy (auto / forced-on / forced-off) is part of the cache
-    key, and forcing the 3-pass pipeline changes the plan."""
+def test_plan_cache_from_before_one_winograd_kernel_is_planned_again(
+        tmp_path):
+    """A version-8 cache keys its plans with the Winograd policy token
+    (``wfa``) and records the realization on each plan.  It loads without
+    error, the layer is planned again to the same plan, and the rewritten
+    cache holds neither the old key nor the field."""
     spec = ConvSpec(64, 128, (3, 3), (1, 1), (1, 1))
-    base = plan_key(spec, 152, 152, 1, "tpu_v5e", "float32", "jax")
-    assert plan_key(spec, 152, 152, 1, "tpu_v5e", "float32", "jax",
-                    winograd_fused=True) != base
-    assert plan_key(spec, 152, 152, 1, "tpu_v5e", "float32", "jax",
-                    winograd_fused=False) != base
-
-    forced_off = Planner(cache_path=None, winograd_fused=False)
-    plan = forced_off.plan(spec, 152, 152)
-    assert not plan.winograd_fused
-    # The 3-pass pipeline pays the V/M round-trips in the model.
-    auto = Planner(cache_path=None).plan(spec, 152, 152)
-    assert auto.predicted_s <= plan.predicted_s
-
-
-def test_measure_mode_times_both_winograd_realizations():
-    """On the pallas impl, measure mode times the megakernel against the
-    3-pass pipeline; whichever wins, the plan stays numerically correct."""
-    spec = ConvSpec(4, 8, (3, 3), (1, 1), (1, 1),
-                    algorithm=ConvAlgorithm.WINOGRAD)
-    planner = Planner(cache_path=None, mode="measure", impl="pallas",
-                      measure_reps=1)
-    plan = planner.plan(spec, 12, 12)
-    assert plan.source == "measured"
+    plan = Planner(cache_path=None).plan(spec, 152, 152)
     assert plan.algorithm is ConvAlgorithm.WINOGRAD
+    key = plan_key(spec, 152, 152, 1, "tpu_v5e", "float32", "jax", "cost",
+                   Planner(cache_path=None).vmem_budget)
+    old_key = key.replace("|e0|", "|e0|wfa|")
+    assert old_key != key
+    record = dict(plan.to_json(), winograd_fused=True)
+    assert ConvPlan.from_json(record) == plan
+    cache = os.path.join(tmp_path, "plans.json")
+    with open(cache, "w") as f:
+        json.dump({"version": 8, "chip": "tpu_v5e",
+                   "plans": {old_key: record}, "networks": {},
+                   "pipelines": {}}, f)
+    planner = Planner(cache_path=cache)
+    assert planner.plan(spec, 152, 152) == plan
+    assert planner.stats == {"hits": 0, "tunes": 1}
+    data = json.load(open(cache))
+    assert data["version"] == PLAN_CACHE_VERSION
+    assert list(data["plans"]) == [key]
+    assert "winograd_fused" not in data["plans"][key]
+
+
+def test_measure_mode_times_each_algorithm_once(monkeypatch):
+    """Measure mode on the Pallas path times each eligible realization of a
+    narrow 3x3 layer exactly once (Winograd, im2col, im2col with its taps
+    folded) and keeps the fastest, which then runs correctly."""
+    import importlib
+    import time
+
+    conv2d_mod = importlib.import_module("repro.core.conv2d")
+
+    # Seconds per run on a fake clock; the fastest is not the first timed.
+    costs = {(ConvAlgorithm.WINOGRAD, False): 2.0,
+             (ConvAlgorithm.IM2COL_GEMM, False): 3.0,
+             (ConvAlgorithm.IM2COL_GEMM, True): 1.0}
+
+    real_conv2d = conv2d_mod.conv2d
+    traced = []
+
+    def traced_conv2d(x, w, spec, plan=None, **kw):
+        # Runs at trace time, once per jitted candidate.
+        traced.append((plan.algorithm, plan.taps_folded))
+        b, h, ww, _ = x.shape
+        return jnp.zeros((b, *spec.out_hw(h, ww), spec.out_channels),
+                         x.dtype)
+
+    clock = [0.0]
+
+    def perf_counter():
+        clock[0] += costs[traced[-1]] if traced else 0.0
+        return clock[0]
+
+    monkeypatch.setattr(conv2d_mod, "conv2d", traced_conv2d)
+    monkeypatch.setattr(time, "perf_counter", perf_counter)
+    spec = ConvSpec(4, 8, (3, 3), (1, 1), (1, 1))
+    planner = Planner(cache_path=None, mode="measure", impl="pallas",
+                      measure_reps=3)
+    plan = planner.plan(spec, 12, 12)
+    monkeypatch.undo()
+    assert sorted(traced, key=str) == sorted(costs, key=str)
+    best = min(costs, key=costs.get)
+    assert (plan.algorithm, plan.taps_folded) == best
+    assert plan.source == "measured" and plan.predicted_s == costs[best]
     x, wt = _rand((1, 12, 12, 4), 15), _rand((3, 3, 4, 8), 16)
     np.testing.assert_allclose(
-        conv2d(x, wt, spec, plan=plan, interpret=True),
+        real_conv2d(x, wt, spec, plan=plan, interpret=True),
         conv2d_reference(x, wt, spec),
         rtol=5e-4, atol=5e-4,
     )
+
+
+def test_options_from_before_one_winograd_kernel_load():
+    """A model saved with the removed ``winograd_fused`` option loads with
+    the same options as one saved without it."""
+    from repro import ExecutionOptions
+
+    opts = ExecutionOptions(impl="pallas", batch=8)
+    saved = opts.to_json()
+    assert "winograd_fused" not in saved
+    legacy = dict(saved, winograd_fused=None)
+    assert ExecutionOptions.from_json(legacy) == opts
+    assert ExecutionOptions.from_json(dict(saved, winograd_fused=True)) \
+        == ExecutionOptions.from_json(saved)
 
 
 def test_fused_plan_drives_cnn_forward_fusion():

@@ -143,7 +143,6 @@ def test_planner_int8_deep_3x3_is_im2col():
     assert p.algorithm is ConvAlgorithm.IM2COL_GEMM, (
         "int8 3x3 must not route to Winograd"
     )
-    assert not p.winograd_fused
 
 
 def test_planner_int8_1x1_is_direct():

@@ -164,18 +164,18 @@ def _cells():
     }
 
 
-W = ("winograd", (32, 128, 128), True)
+W = ("winograd", (32, 128, 128))
 
 
 def D(bn, bk):
-    return ("direct", (512, bn, bk), False)
+    return ("direct", (512, bn, bk))
 
 
 def I(toh, bo):
-    return ("im2col_gemm", (toh, 128, bo), False)
+    return ("im2col_gemm", (toh, 128, bo))
 
 
-# (algorithm, kernel blocks, winograd_fused) of every conv step that does
+# (algorithm, kernel blocks) of every conv step that does
 # not fold, as the planner chose them before tap folding existed.
 UNFOLDED = {
     "vgg16_b8": {
@@ -234,8 +234,7 @@ def test_cost_model_folds_exactly_the_narrow_stems(cell):
                                tuple(s.plan.kernel_blocks))
         else:
             unfolded[s.index] = (s.plan.algorithm.value,
-                                 tuple(s.plan.kernel_blocks),
-                                 s.plan.winograd_fused)
+                                 tuple(s.plan.kernel_blocks))
     assert folded == FOLDED[cell]
     assert unfolded == UNFOLDED[cell]
 
@@ -246,12 +245,12 @@ def test_plan_cache_from_before_folding_is_tuned_again(tmp_path):
     must tune the stem again rather than read it back."""
     spec = ConvSpec(3, 64, (7, 7), (2, 2), (3, 3))
     key = plan_key(spec, 224, 224, 64, "tpu_v5e", "float32", "pallas",
-                   "cost", 16 * 1024 * 1024, True, None)
+                   "cost", 16 * 1024 * 1024, True)
     old = {
         "algorithm": "im2col_gemm", "impl": "pallas",
         "block": [512, 128, 256], "kernel_blocks": [8, 128, 128],
         "predicted_s": 0.0131, "source": "cost_model",
-        "fused_epilogue": True, "winograd_fused": False, "dtype": "float32",
+        "fused_epilogue": True, "dtype": "float32",
     }
     cache = os.path.join(tmp_path, "plans.json")
     with open(cache, "w") as f:

@@ -9,9 +9,10 @@ Every compile sets ``vmem_limit_bytes`` to the footprint ``core/vmem_model``
 predicts for the kernel's blocks, so a pass also proves the model covers
 what the compiler allocates.  Two sets of kernels are compiled: fixed cases,
 each a layer the compiler once refused or a block shape the Winograd
-kernel tiled in VMEM takes (YOLOv3-20's stem, several images per block),
-among them four of ResNet-50's 1x1 convs at batch 64 and its 7x7/2 stem
-with the taps folded into one GEMM, and every distinct kernel call the planner picks for the benchmark's
+kernel tiled in VMEM takes (YOLOv3-20's stem, several images per block,
+ResNet-50's 7x7 layers at batch 64), among them four of ResNet-50's 1x1
+convs at batch 64 and its 7x7/2 stem with the taps folded into one GEMM,
+and every distinct kernel call the planner picks for the benchmark's
 networks (VGG-16@224 batch 8, YOLOv3-tiny@416 and YOLOv3-20@608 batch 1,
 ResNet-50@224 batch 64; fp32 and int8).
 
@@ -147,25 +148,6 @@ def _winograd_case(b, hw, cin, cout, channel_blocks=None):
     return fn, args, winograd_kernel_vmem_bytes(math.prod(nhwc), bc, bo)
 
 
-def _winograd_3pass_case(b, hw, cin, cout):
-    """Input transform, tuple multiply and output transform (the 3-pass
-    pipeline) in one program; each kernel's own footprint is at most the
-    modeled pipeline maximum."""
-    from repro.kernels.winograd.ops import conv2d_winograd_padded_call, pick_blocks
-
-    t = b * (-(-hw // 6)) ** 2
-    bt, bc, bo = blocks = pick_blocks(t, cin, cout, fused=False)
-    cp, op = ceil_to(cin, bc), ceil_to(cout, bo)
-
-    def fn(x_sp, u, bias):
-        return conv2d_winograd_padded_call(x_sp, u, hw, hw, blocks, bias_p=bias,
-                                           activation="relu", fused=False)
-
-    args = [((b, hw + 2, hw + 2, cp), jnp.float32),
-            ((8, 8, cp, op), jnp.float32), ((1, op), jnp.float32)]
-    return fn, args, winograd_kernel_vmem_bytes(bt, bc, bo, fused=False)
-
-
 def _gemm_case(m, k, n, dtype):
     from repro.kernels.gemm.ops import default_block, matmul_padded_call
 
@@ -202,7 +184,7 @@ def _single_conv_case(b, hw, cin, cout, kernel, stride, block, dtype,
                      (hw, hw), cin)
     ((key, call),) = _planned_calls(model, b, dtype,
                                     blocks={0: block}).items()
-    assert key[2] is folded, key
+    assert key[1] is folded, key
     _, fn, args, modeled = call
     return fn, args, modeled
 
@@ -211,8 +193,11 @@ CASES = {
     # VGG-16's first 3x3 layer plans onto the fused megakernel, which cuts
     # its tiles in VMEM, 40 tile columns (a 248-column window) at a time.
     "winograd_fused_224x64-64": lambda: _winograd_case(8, 224, 64, 64),
-    # The 3-pass pipeline the planner can pin instead, at the same layer.
-    "winograd_3pass_224x64-64": lambda: _winograd_3pass_case(8, 224, 64, 64),
+    # ResNet-50's 7x7 3x3 layers (67 and 71), 512 -> 512 at batch 64, the
+    # cell's top two device ops: two whole images per block, 2 x 8 tiles
+    # each (2 of 8 tile columns real), over 4 Cin and 4 Cout blocks.
+    "winograd_nhwc_resnet50_l067_7x512-512": lambda: _winograd_case(
+        64, 7, 512, 512, (128, 128)),
     # Tiled in VMEM: YOLOv3-20's stem as the Winograd kernel ran it before
     # the stem folded its taps, a 610 x 610 x 128 padded window (3 channels
     # padded to 128) cut 6 tile rows x 8 tile columns at a time.
@@ -274,7 +259,9 @@ CASES = {
 #: 0.48 and 1.97 MiB (layer 66 at bk = 1024: 13893632) more than their
 #: buffers and the fixed allowance; of the dot's values, A + B + the fp32
 #: result (2.0 MiB at layer 34) is the least sum that covers layer 34.
-#: ResNet-50's folded stem is the GEMM kernel at (512, 128, 147).
+#: ResNet-50's folded stem is the GEMM kernel at (512, 128, 147).  Its
+#: 7x7 Winograd layer needs 1.19 MiB less than the model gives its
+#: (2, 2, 8) blocks.
 COMPILER_MIN_VMEM = {
     "direct_resnet50_l004_56x64-256": 2883584,
     "direct_resnet50_l034_28x512-256": 7077888,
@@ -290,9 +277,9 @@ COMPILER_MIN_VMEM = {
     "im2col_int8_224x3-64": 7340032,
     "im2col_int8_56x256-256": 6094848,
     "im2col_yolov3_20_l1_608s2_32-64": 10551296,
-    "winograd_3pass_224x64-64": 8650752,
     "winograd_fused_224x64-64": 5570560,
     "winograd_nhwc_b8_18x512-512": 14417920,
+    "winograd_nhwc_resnet50_l067_7x512-512": 12517376,
     "winograd_nhwc_yolov3_20_l0_608x3-32": 6422528,
 }
 
@@ -364,10 +351,9 @@ def _modeled_vmem(netplan, step) -> int:
     plan, spec = step.plan, step.spec
     d = itemsize(plan.dtype)
     if plan.algorithm is ConvAlgorithm.WINOGRAD:
-        bt, bc, bo = plan.kernel_blocks
+        _, bc, bo = plan.kernel_blocks
         nhwc = winograd_step_tiling(netplan, step).nhwc
-        return winograd_kernel_vmem_bytes(math.prod(nhwc) if nhwc else bt,
-                                          bc, bo, fused=plan.winograd_fused,
+        return winograd_kernel_vmem_bytes(math.prod(nhwc), bc, bo,
                                           dtype_bytes=d)
     if plan.algorithm is ConvAlgorithm.DIRECT or plan.taps_folded:
         bm, bn, bk = plan.kernel_blocks
@@ -411,7 +397,7 @@ def _planned_calls(model, batch, dtype, blocks=None):
         p = params[s.index]
         xs = jax.ShapeDtypeStruct((batch, *s.in_hw, s.in_layout.phys_c),
                                   jnp.float32)
-        key = (s.plan.algorithm.value, s.plan.winograd_fused,
+        key = (s.plan.algorithm.value,
                s.plan.taps_folded, s.plan.dtype,
                s.spec.kernel_size, s.spec.stride, xs.shape,
                tuple(p["w"].shape), tuple(s.plan.kernel_blocks),

@@ -2,14 +2,14 @@
 
   - interpret mode: the NHWC kernel (tiles cut with loads strided by 6,
     written back with stores strided by 6) against the lax reference and, far
-    tighter, against the 3-pass pipeline on tiles gathered in HBM, at the
-    same tiles per block and channel blocks — on the awkward shapes, and on
+    tighter, against itself at another block decomposition (one tile row
+    per block, or every tile in one block) — on the awkward shapes, and on
     the shape of each other Winograd test;
-  - the wrapper runs the fused kernel with no tile gather around it, and
-    the 3-pass pipeline on gathered tiles (traced, not run);
-  - the layer table says where the layers of the benchmark networks cut
-    their tiles, and how many they compute over the real ones, also with
-    the batch split over four devices.
+  - the wrapper runs the fused kernel with no tile gather around it
+    (traced, not run);
+  - the layer table says how many tiles the Winograd layers of the
+    benchmark networks compute over the real ones, also with the batch
+    split over four devices.
 """
 import json
 
@@ -19,14 +19,13 @@ import numpy as np
 import pytest
 
 import repro
-from repro.configs import vgg16, yolov3
+from repro.configs import resnet50, vgg16, yolov3
 from repro.core.conv_spec import ConvSpec, Epilogue, apply_epilogue
 from repro.core.conv2d import conv2d_reference
 from repro.core.netplan import layer_table, plan_network
 from repro.core.winograd import transform_weights
 from repro.kernels.winograd.ops import (
     conv2d_winograd_padded_call,
-    winograd_3pass_call,
     winograd_tiling,
     winograd_vmem_tiled_call,
 )
@@ -56,7 +55,7 @@ CASES = {
     # Two Cin blocks (the reduction axis), two Cout blocks, two tile-column
     # blocks per row.
     "multi_block": _case(1, 12, 90, 200, 130, nhwc=(1, 1, 8)),
-    # The shape of each HBM-tiled Winograd test, one tile row per block.
+    # The shape of each other Winograd test, one tile row per block.
     "end_to_end": _case(2, 12, 14, 5, 7, nhwc=(1, 1, 8)),
     "pretransformed_weights": _case(1, 12, 12, 4, 6, nhwc=(1, 1, 8)),
     "crop_path": _case(2, 11, 23, 4, 8, nhwc=(1, 1, 8)),
@@ -64,7 +63,6 @@ CASES = {
     "pretransformed_weights_13x17": _case(1, 13, 17, 4, 6, nhwc=(1, 1, 8)),
     "fused_epilogue": _case(2, 10, 13, 5, 9, nhwc=(1, 1, 8), bias=True,
                             activation="leaky"),
-    "fused_matches_3pass": _case(1, 18, 18, 4, 8, nhwc=(1, 1, 8)),
 }
 
 
@@ -83,17 +81,18 @@ def test_winograd_vmem_tiling(name):
                                         (0, op - o)))
     bias_p = pad_bias_row(bias, op)
     nhwc = cs["nhwc"]
-    bb, k, nw = nhwc
-    got = winograd_vmem_tiled_call(
-        x_sp, u, h, w, nhwc, (bc, bo), interpret=True, bias_p=bias_p,
-        activation=cs["activation"],
+    # Each tile's arithmetic does not depend on the block it falls in.
+    other = ((1, 1, 8) if nhwc != (1, 1, 8)
+             else (b, -(-h // 6), ceil_to(-(-w // 6), 8)))
+    got, alt = (
+        winograd_vmem_tiled_call(
+            x_sp, u, h, w, blocks, (bc, bo), interpret=True, bias_p=bias_p,
+            activation=cs["activation"],
+        )
+        for blocks in (nhwc, other)
     )
-    hbm = winograd_3pass_call(
-        x_sp, u, h, w, (bb * k * nw, bc, bo), interpret=True, bias_p=bias_p,
-        activation=cs["activation"],
-    )
-    assert got.shape == hbm.shape == (b, h, w, op)
-    np.testing.assert_allclose(got, hbm, rtol=1e-5, atol=1e-5)
+    assert got.shape == alt.shape == (b, h, w, op)
+    np.testing.assert_allclose(got, alt, rtol=1e-5, atol=1e-5)
     ref = apply_epilogue(conv2d_reference(x, wt, spec),
                          Epilogue(bias=bias, activation=cs["activation"]))
     np.testing.assert_allclose(got[..., :o], ref, rtol=5e-4, atol=5e-4)
@@ -117,28 +116,20 @@ def _pallas_names(fn, *args):
 ])
 def test_padded_call_dispatches_by_shape(b, hw, c, o, nhwc):
     """The fused kernel cuts its tiles in VMEM at the blocks
-    ``winograd_tiling`` names, with no gather or transpose around it; the
-    3-pass pipeline runs on tiles XLA gathers in HBM."""
+    ``winograd_tiling`` names, with no gather or transpose around it."""
     blocks = (32, 128, 128)
     assert winograd_tiling(b, hw, hw, blocks).nhwc == nhwc
-    assert winograd_tiling(b, hw, hw, blocks, fused=False).name == "hbm"
     x = jax.ShapeDtypeStruct((b, hw + 2, hw + 2, c), jnp.float32)
     u = jax.ShapeDtypeStruct((8, 8, c, o), jnp.float32)
     bias = jax.ShapeDtypeStruct((1, o), jnp.float32)
-    for fused in (True, False):
-        names, prims = _pallas_names(
-            lambda x, u, bias: conv2d_winograd_padded_call(
-                x, u, hw, hw, blocks, interpret=True, bias_p=bias,
-                activation="relu", fused=fused),
-            x, u, bias,
-        )
-        if fused:
-            assert names == ["_fused_winograd_nhwc_bias_kernel"]
-            assert "gather" not in prims and "transpose" not in prims
-        else:
-            assert names == ["_input_transform_kernel",
-                             "_tuple_multiply_kernel",
-                             "_output_transform_bias_kernel"]
+    names, prims = _pallas_names(
+        lambda x, u, bias: conv2d_winograd_padded_call(
+            x, u, hw, hw, blocks, interpret=True, bias_p=bias,
+            activation="relu"),
+        x, u, bias,
+    )
+    assert names == ["_fused_winograd_nhwc_bias_kernel"]
+    assert "gather" not in prims and "transpose" not in prims
 
 
 def _table(model, batch, shards=1):
@@ -156,17 +147,21 @@ def _table(model, batch, shards=1):
     (vgg16.MODEL, 8, (1, 3, 4, 6, 7, 8, 10, 11, 12)),
     # YOLOv3-20@608 b1; layer 0 folds its taps too.
     (yolov3.MODEL_20, 1, (3, 7, 10, 14, 17)),
+    # ResNet-50@224 b64: every 3x3 stride-1 layer, from 56² to 7²; the
+    # 3x3/2s that open stages 2 to 4 (17, 35, 61) run im2col.
+    pytest.param(resnet50.MODEL, 64, (3, 9, 13, 23, 27, 31, 41, 45, 49, 53,
+                                      57, 67, 71), id="resnet50"),
 ])
 def test_layer_table_records_tiling(model, batch, wino):
     netplan, layers = _table(model, batch)
     tiled = {l["index"]: l for l in layers if l["algorithm"] == "winograd"}
     assert sorted(tiled) == list(wino)
     for l in layers:
+        assert "tiling" not in l
         if l["algorithm"] != "winograd":
-            assert l["tiling"] is None and l["tile_ratio"] is None
+            assert l["tile_ratio"] is None
     for i, l in tiled.items():
-        assert l["tiling"] == "vmem", (i, l)
-        assert l["tile_ratio"] >= 1.0
+        assert l["tile_ratio"] >= 1.0, (i, l)
         if netplan.steps[i].out_hw[0] in (224, 608):
             assert l["tile_ratio"] <= 1.10, (i, l)
 
@@ -175,14 +170,11 @@ def test_sharded_executor_tiles_at_per_device_batch():
     """Batch 4 over four CPU devices: each kernel sees one image.  The
     executor's layer table and the analysis descriptors describe the
     Winograd kernels at that batch, and the sharded forward's kernels have
-    exactly the descriptors' grids — for the fused kernel, whose blocks at
-    32² hold every tile of as many images as fit (6 x 8 tiles each), and for
-    the 3-pass pipeline, whose tile count pads to the bt multiple (so its
-    ``tile_ratio`` at one image is not the one at four)."""
+    exactly the descriptors' grids: the fused kernel's blocks at 32² hold
+    every tile of as many images as fit (6 x 8 tiles each)."""
     from conftest import run_with_devices
 
     out = run_with_devices(4, """
-        import dataclasses
         import json
         import jax, jax.numpy as jnp
         from repro import spans
@@ -197,39 +189,25 @@ def test_sharded_executor_tiles_at_per_device_batch():
         layers = vgg16.LAYERS
         params = init_cnn(jax.random.PRNGKey(0), layers)
         x = jnp.zeros((4, 32, 32, 3), jnp.float32)
-        fused_plan = plan_network(layers, 32, 32,
-                                  Planner(impl="pallas", cache_path=None),
-                                  batch=4)
-        # The same layers with each Winograd plan pinned to the 3-pass
-        # pipeline (the cost model would move them to im2col).
-        three_pass = dataclasses.replace(fused_plan, steps=tuple(
-            dataclasses.replace(
-                s, plan=dataclasses.replace(s.plan, winograd_fused=False))
-            if s.plan is not None and s.plan.winograd_fused else s
-            for s in fused_plan.steps))
-        got = {}
-        for fused, netplan in ((True, fused_plan), (False, three_pass)):
-            ex = NetworkExecutor(netplan, params, interpret=True)
-            assert ex.mesh is not None and len(jax.devices()) == 4
-            table = spans.RECORD.layer_tables()[-1]["layers"]
-            _, recs = trace_forward(ex._fn, ex.params, x)
-            want = [tuple(d["grid"]) for s in netplan.steps
-                    for d in step_descriptors(netplan, s, batch=1)]
-            whole = [tuple(d["grid"]) for s in netplan.steps
-                     for d in step_descriptors(netplan, s)]
-            got[str(fused)] = {
-                "table": table == layer_table(netplan, 4)["layers"],
-                "table_differs": table != layer_table(netplan)["layers"],
-                "grids": [r.grid for r in recs] == want,
-                "grids_differ": want != whole,
-                "tilings": sorted({l["tiling"] for l in table
-                                   if l["tiling"]}),
-            }
-        print(json.dumps(got))
+        netplan = plan_network(layers, 32, 32,
+                               Planner(impl="pallas", cache_path=None),
+                               batch=4)
+        ex = NetworkExecutor(netplan, params, interpret=True)
+        assert ex.mesh is not None and len(jax.devices()) == 4
+        table = spans.RECORD.layer_tables()[-1]["layers"]
+        _, recs = trace_forward(ex._fn, ex.params, x)
+        want = [tuple(d["grid"]) for s in netplan.steps
+                for d in step_descriptors(netplan, s, batch=1)]
+        whole = [tuple(d["grid"]) for s in netplan.steps
+                 for d in step_descriptors(netplan, s)]
+        print(json.dumps({
+            "table": table == layer_table(netplan, 4)["layers"],
+            "grids": [r.grid for r in recs] == want,
+            "grids_differ": want != whole,
+            "ratios": [l["tile_ratio"] for l in table
+                       if l["algorithm"] == "winograd"],
+        }))
     """)
     got = json.loads(out.strip().splitlines()[-1])
-    fused, three_pass = got["True"], got["False"]
-    assert fused["table"] and fused["grids"] and fused["grids_differ"], got
-    assert fused["tilings"] == ["vmem"], got
-    assert three_pass["table"] and three_pass["grids"], got
-    assert three_pass["table_differs"] and three_pass["tilings"] == ["hbm"], got
+    assert got["table"] and got["grids"] and got["grids_differ"], got
+    assert got["ratios"] and all(r >= 1.0 for r in got["ratios"]), got
